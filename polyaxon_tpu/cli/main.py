@@ -1083,6 +1083,7 @@ def serve(uid, host, port, mesh, max_batch, max_wait_ms, buckets, no_batching,
     (GET /healthz, GET /readyz, GET /statsz, POST /generate)."""
     from ..serving import ModelServer
     from ..serving.server import ServingError
+    from ..utils.jax_platform import PlatformMismatchError
 
     mesh_axes = None
     if mesh:
@@ -1256,7 +1257,7 @@ def serve(uid, host, port, mesh, max_batch, max_wait_ms, buckets, no_batching,
         server = ModelServer.from_run(uid, mesh_axes=mesh_axes,
                                       config_overrides=overrides or None,
                                       expected_devices=expected_devices)
-    except (ServingError, KeyError, ValueError) as e:
+    except (ServingError, KeyError, ValueError, PlatformMismatchError) as e:
         # ValueError: mesh-vs-device/model mismatch from the mesh builder
         raise click.ClickException(str(e.args[0]) if e.args else str(e))
     bound = server.start(host=host, port=port)
@@ -1271,9 +1272,12 @@ def serve(uid, host, port, mesh, max_batch, max_wait_ms, buckets, no_batching,
             f" kv_pool={server.config.kv_pool_pages}x"
             f"{server.config.kv_page_tokens}tok"
         )
+    dev = server.device_info()
     click.echo(
         f"serving {server.model_name} (step {server.step}) "
-        f"on http://{host}:{bound} [{mode}] — "
+        f"on http://{host}:{bound} [{mode}] "
+        f"[{dev['platform']}:{dev['device_kind']} ids={dev['device_ids']} "
+        f"attention={dev.get('attention_backend')}] — "
         "POST /generate, GET /healthz, GET /readyz, GET /statsz, "
         "GET /tracez, GET /sloz"
     )
@@ -1396,7 +1400,12 @@ def _serve_fleet(uid, host, port, *, replicas, mesh_axes, overrides,
     """`polyaxon serve --replicas N --route`: N single-replica children
     as a fleet-placed gang, fronted by the JSQ/P2C router."""
     from ..scheduler.fleet import Fleet
-    from ..serving.replicas import ReplicaSetManager, SubprocessReplica
+    from ..serving.replicas import (
+        ReplicaSetManager,
+        SubprocessReplica,
+        host_tpu_chips,
+        replica_chip_env,
+    )
     from ..serving.router import AutoscalePolicy, Router
     from ..telemetry import MetricsRegistry
 
@@ -1440,6 +1449,19 @@ def _serve_fleet(uid, host, port, *, replicas, mesh_axes, overrides,
 
         chips = _math.prod(sizes) if sizes else 1
 
+    # one process for each chip: every slot's child sees only its own
+    # chips. This parent never opens a chip itself — the count comes from
+    # the fleet's inventory where one is configured, else a short child.
+    fleet = Fleet(store)
+    try:
+        host_chips = (
+            fleet.inventory().total if fleet.configured else host_tpu_chips()
+        )
+        replica_chip_env(n - 1, chips, host_chips)  # refuse before any child
+    except (RuntimeError, ValueError) as e:
+        raise click.ClickException(str(e))
+    log_dir = store.outputs_dir(uuid) / "serving"
+
     def factory(i):
         slot_overrides = overrides
         if pools is not None:
@@ -1450,10 +1472,13 @@ def _serve_fleet(uid, host, port, *, replicas, mesh_axes, overrides,
         return SubprocessReplica(
             lambda p: _serve_child_argv(
                 uuid, p, mesh_axes, slot_overrides, expected_devices
-            )
+            ),
+            env=replica_chip_env(i, chips, host_chips),
+            # a chip-sized checkpoint takes minutes, not seconds, to read
+            ready_timeout_s=600.0,
+            stderr_path=str(log_dir / f"replica-{i}.stderr"),
         )
 
-    fleet = Fleet(store)
     # one registry for manager + router so restart counters land on the
     # same /metricsz scrape as the router_* series
     registry = MetricsRegistry()

@@ -1,8 +1,8 @@
 """Native components (C++): the gang launcher/supervisor.
 
 Parity slot for the reference's Go operator (SURVEY.md §2 native census).
-`launcher_path()` returns the binary, building it with the in-tree
-Makefile on first use (g++ is in the base image; no pip deps).
+`launcher_path()` returns the binary, built by the in-tree
+Makefile (g++ is in the base image; no pip deps).
 """
 
 from __future__ import annotations
@@ -18,18 +18,20 @@ class NativeBuildError(RuntimeError):
     pass
 
 
-def launcher_path(rebuild: bool = False) -> str:
-    """Path to the compiled launcher; builds it if missing."""
-    if rebuild or not _BINARY.exists():
-        proc = subprocess.run(
-            ["make", "-C", str(_DIR)],
-            capture_output=True,
-            text=True,
+def launcher_path() -> str:
+    """Path to the compiled launcher. Always goes through make: its
+    launcher.cpp dependency makes this a no-op when the binary is fresh and
+    a rebuild when the source changed — building only when the binary is
+    missing would let a stale one win over changed source."""
+    proc = subprocess.run(
+        ["make", "-C", str(_DIR), _BINARY.name],
+        capture_output=True,
+        text=True,
+    )
+    if proc.returncode != 0 or not _BINARY.exists():
+        raise NativeBuildError(
+            f"building polyaxon-launcher failed:\n{proc.stdout}\n{proc.stderr}"
         )
-        if proc.returncode != 0 or not _BINARY.exists():
-            raise NativeBuildError(
-                f"building polyaxon-launcher failed:\n{proc.stdout}\n{proc.stderr}"
-            )
     return str(_BINARY)
 
 

@@ -73,16 +73,16 @@ def build_mesh(
     sizes = resolve_axis_sizes(spec_sizes, len(devices))
     if slices > 1:
         return _build_hybrid_mesh(sizes, devices, slices)
-    try:
-        # mesh_utils knows the physical ICI topology (it reads device coords)
-        # and lays logical axes onto it to keep inner axes on adjacent chips
-        from jax.experimental import mesh_utils
+    # mesh_utils knows the physical ICI topology (it reads device coords)
+    # and lays logical axes onto it to keep inner axes on adjacent chips. A
+    # shape it cannot lay out raises: reshaping the device list in
+    # enumeration order instead would put tensor-parallel neighbours on
+    # non-adjacent chips without a word.
+    from jax.experimental import mesh_utils
 
-        dev_array = mesh_utils.create_device_mesh(
-            tuple(sizes.values()), devices=devices
-        )
-    except Exception:
-        dev_array = np.asarray(devices).reshape(tuple(sizes.values()))
+    dev_array = mesh_utils.create_device_mesh(
+        tuple(sizes.values()), devices=devices
+    )
     return Mesh(dev_array, tuple(sizes.keys()))
 
 

@@ -70,20 +70,10 @@ def _spec_for(path: str, shape, rules, mesh: Mesh) -> P:
 
 
 def shard_map_nocheck(body, **kwargs):
-    """shard_map with the replication check disabled across jax versions
-    (kwarg renamed check_rep → check_vma) — Pallas kernels inside the body
-    don't declare varying mesh axes, so the check must be skipped."""
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
-    try:
-        return shard_map(body, check_vma=False, **kwargs)
-    except TypeError:
-        try:
-            return shard_map(body, check_rep=False, **kwargs)
-        except TypeError:  # oldest: neither kwarg
-            return shard_map(body, **kwargs)
+    """shard_map with the varying-axes check disabled — Pallas kernels
+    inside the body don't declare varying mesh axes, so the check must be
+    skipped."""
+    return jax.shard_map(body, check_vma=False, **kwargs)
 
 
 def param_shardings(params, rules: Sequence, mesh: Mesh):

@@ -158,9 +158,9 @@ class Trainer:
             )
         )
 
-        from ..utils.jax_platform import apply_compilation_cache
+        from ..utils.jax_platform import apply_compilation_cache, device_report
 
-        apply_compilation_cache()  # 20-40s chip compiles amortize across runs
+        cache_dir = apply_compilation_cache()
         self.bundle = build_model(program.model.name, program.model.config)
         dspec = program.data
         data_name = dspec.name if dspec else "synthetic"
@@ -189,6 +189,22 @@ class Trainer:
         from ..parallel.ring import set_current_mesh
 
         set_current_mesh(self.mesh)
+        # where this trainer runs, on the run store before any step does:
+        # a run that landed on the wrong device must be readable as such
+        # from outside the process
+        self._event(
+            "device",
+            {
+                **device_report(
+                    list(self.mesh.devices.flat),
+                    getattr(self.bundle.module, "cfg", None),
+                    self.data.meta.get("seq_len"),
+                ),
+                "mesh": {k: int(v) for k, v in self.mesh.shape.items()},
+                "process_count": jax.process_count(),
+                "compile_cache_dir": cache_dir,
+            },
+        )
         self.compute_dtype = _compute_dtype(tspec.precision)
         self.param_dtype = param_dtype_for(tspec.precision)
         self._build_step()
@@ -296,8 +312,12 @@ class Trainer:
             init_rng
         )
         opt_state = jax.jit(self.tx.init, out_shardings=o_shard)(params)
+        rep = replicated(mesh)
         self.state = TrainState(
-            step=jnp.zeros((), jnp.int32),
+            # placed like every later step's: an array that is not on the
+            # mesh has another type, and the second call would trace and
+            # compile the whole step again
+            step=jax.device_put(jnp.zeros((), jnp.int32), rep),
             params=params,
             opt_state=opt_state,
             extra=extra,
@@ -308,7 +328,6 @@ class Trainer:
         if bundle.task in ("lm", "mlm") and mesh.shape.get("context", 1) > 1:
             extra_axes = {"1": "context"}
         self.b_shard = batch_sharding(mesh, extra_axes)
-        rep = replicated(mesh)
         state_shardings = TrainState(
             step=rep, params=self.p_shard, opt_state=o_shard, extra=e_shard
         )
@@ -670,7 +689,15 @@ class Trainer:
                     with self.tracer.span(
                         "checkpoint", step=step + 1
                     ) as ckpt_span:
-                        self.save(step + 1)
+                        try:
+                            self.save(step + 1)
+                        except Exception:
+                            # a fault that surfaces in the save (a failed
+                            # upload of an earlier boundary) must not cost
+                            # the run the log point still held back
+                            if pending is not None:
+                                self._emit(history, *pending)
+                            raise
                     stall_hist.observe(ckpt_span.dur_s * 1000.0)
             step_hist.observe(step_span.dur_s)
             wait_hist.observe(wait_span.dur_s)
@@ -794,8 +821,8 @@ class Trainer:
         if self._flops_per_step:
             mfu = _mfu_of(
                 sps * self._flops_per_step,
-                jax.devices()[0].device_kind,
-                jax.device_count(),
+                self.mesh.devices.flat[0].device_kind,
+                self.mesh.devices.size,
             )
             if mfu is not None:
                 out["mfu"] = mfu

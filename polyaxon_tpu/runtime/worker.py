@@ -30,7 +30,11 @@ def main() -> int:
     # gang worker grabs the one real TPU chip and deadlocks in rendezvous.
     # The executor injects these for local gangs; the k8s converter leaves
     # them unset on real TPU pods.
-    from ..utils.jax_platform import apply_platform_env, enable_cpu_collectives
+    from ..utils.jax_platform import (
+        apply_platform_env,
+        device_memory,
+        enable_cpu_collectives,
+    )
 
     platform = apply_platform_env()
 
@@ -118,6 +122,7 @@ def main() -> int:
                 "steps_per_sec": result.steps_per_sec,
                 "final_metrics": result.final_metrics,
                 "num_processes": num_processes,
+                "device_memory": device_memory(trainer.mesh.local_devices),
             },
         )
     return 0

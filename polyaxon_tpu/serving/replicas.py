@@ -30,8 +30,11 @@ r1, ...) never migrate between replicas mid-incident.
 from __future__ import annotations
 
 import json
+import os
 import socket
 import subprocess
+import sys
+import tempfile
 import threading
 from typing import Callable, Optional
 from urllib import request as urlrequest
@@ -82,10 +85,64 @@ class InProcessReplica:
             srv._httpd.server_close()
 
 
+def host_tpu_chips() -> Optional[int]:
+    """How many TPU chips this host has, or None when JAX finds no TPU
+    here (children then run wherever JAX puts them). Asked of a short-lived
+    child: a parent that opens the chips itself keeps them from its
+    replica children. No child is needed where the environment names the
+    CPU."""
+    from ..utils.jax_platform import env_names_cpu
+
+    if env_names_cpu():
+        return None
+    proc = subprocess.run(
+        [
+            sys.executable,
+            "-c",
+            "import jax; d = jax.devices(); print(d[0].platform, len(d))",
+        ],
+        capture_output=True,
+        text=True,
+        timeout=300,
+    )
+    if proc.returncode != 0:
+        raise RuntimeError(
+            f"device census child exited rc={proc.returncode}:\n"
+            f"{proc.stderr[-2000:]}"
+        )
+    platform, count = proc.stdout.split()[-2:]
+    return int(count) if platform == "tpu" else None
+
+
+def replica_chip_env(
+    slot: int, chips_per_replica: int, host_chips: Optional[int]
+) -> Optional[dict]:
+    """The `chip_env` of replica `slot` — its own chips of this host,
+    disjoint from every other slot's — or None when the host has no TPU.
+    Refuses a slot past the host's last chip: one process owns a chip at a
+    time."""
+    if host_chips is None:
+        return None
+    from ..utils.jax_platform import chip_env
+
+    if (slot + 1) * chips_per_replica > host_chips:
+        raise ValueError(
+            f"replica slot {slot} x {chips_per_replica} chip(s) needs "
+            f"{(slot + 1) * chips_per_replica} chips, this host has "
+            f"{host_chips}: one process owns a chip at a time"
+        )
+    return chip_env(slot, chips_per_replica)
+
+
 class SubprocessReplica:
     """A replica child process. `argv_factory(port)` returns the command
     line (the manager picks a free port); readiness is probed over HTTP
-    so `start()` returns only once the replica can actually serve."""
+    so `start()` returns only once the replica can actually serve.
+
+    `env` is laid over this process's environment (a slot's chip
+    assignment). The child's stderr goes to `stderr_path` (an anonymous
+    temp file when not given), and its tail is in the error when the child
+    dies or stalls before it is ready."""
 
     def __init__(
         self,
@@ -93,27 +150,49 @@ class SubprocessReplica:
         *,
         env: Optional[dict] = None,
         ready_timeout_s: float = 120.0,
+        stderr_path: Optional[str] = None,
     ):
         self._argv_factory = argv_factory
         self._env = env
         self._ready_timeout_s = float(ready_timeout_s)
+        self._stderr_path = stderr_path
+        self._stderr = None
         self.proc: Optional[subprocess.Popen] = None
         self.url: Optional[str] = None
 
+    def _stderr_tail(self, n: int = 2000) -> str:
+        size = self._stderr.seek(0, os.SEEK_END)
+        self._stderr.seek(max(0, size - n))
+        where = f" ({self._stderr_path})" if self._stderr_path else ""
+        text = self._stderr.read().decode(errors="replace").strip()
+        return f"stderr{where} ends:\n{text}"
+
+    def _close_stderr(self) -> None:
+        if self._stderr is not None:
+            self._stderr.close()
+            self._stderr = None
+
     def start(self) -> str:
         port = _free_port()
+        self._close_stderr()
+        if self._stderr_path is None:
+            self._stderr = tempfile.TemporaryFile()
+        else:
+            os.makedirs(os.path.dirname(self._stderr_path) or ".", exist_ok=True)
+            self._stderr = open(self._stderr_path, "ab+")
         self.proc = subprocess.Popen(
             self._argv_factory(port),
-            env=self._env,
+            env={**os.environ, **self._env} if self._env else None,
             stdout=subprocess.DEVNULL,
-            stderr=subprocess.DEVNULL,
+            stderr=self._stderr,
         )
         self.url = f"http://127.0.0.1:{port}"
         deadline = _now() + self._ready_timeout_s
         while _now() < deadline:
             if self.proc.poll() is not None:
                 raise RuntimeError(
-                    f"replica exited rc={self.proc.returncode} before ready"
+                    f"replica exited rc={self.proc.returncode} before ready; "
+                    + self._stderr_tail()
                 )
             try:
                 with urlrequest.urlopen(self.url + "/readyz", timeout=2.0) as r:
@@ -122,8 +201,12 @@ class SubprocessReplica:
             except Exception:
                 pass
             threading.Event().wait(0.1)
+        tail = self._stderr_tail()
         self.kill()
-        raise TimeoutError(f"replica on {self.url} not ready in time")
+        raise TimeoutError(
+            f"replica on {self.url} not ready in "
+            f"{self._ready_timeout_s:.0f}s; {tail}"
+        )
 
     def alive(self) -> bool:
         return self.proc is not None and self.proc.poll() is None
@@ -138,12 +221,14 @@ class SubprocessReplica:
             self.proc.kill()
             self.proc.wait(timeout=10.0)
         self.proc = None
+        self._close_stderr()
 
     def kill(self) -> None:
         if self.proc is not None:
             self.proc.kill()
             self.proc.wait(timeout=10.0)
             self.proc = None
+        self._close_stderr()
 
 
 class ReplicaSetManager:

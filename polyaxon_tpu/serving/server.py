@@ -171,21 +171,11 @@ def _restore_params_subtree(ckpt_dir: str, abstract_params):
                 abstract_params
             )
         }
-        try:
-            args = ocp.args.PyTreeRestore(
-                {"params": abstract_params},
-                restore_args=restore_args,
-                partial_restore=True,
-            )
-        except TypeError:
-            # orbax < 0.11 has no partial_restore kwarg; the same "restore
-            # only the keys present in item, drop the rest of the saved
-            # tree" semantics are spelled as empty transforms there
-            args = ocp.args.PyTreeRestore(
-                {"params": abstract_params},
-                restore_args=restore_args,
-                transforms={},
-            )
+        args = ocp.args.PyTreeRestore(
+            {"params": abstract_params},
+            restore_args=restore_args,
+            partial_restore=True,
+        )
         out = mgr.restore(step, args=args)
         return out["params"], step
     finally:
@@ -340,6 +330,18 @@ class ModelServer:
             )
         self.module = module
         self.params = params
+        # the devices this server decodes on (no mesh = device 0, the
+        # single-chip path) — /statsz and the startup line name them
+        import jax as _jax
+
+        from ..utils.jax_platform import device_report
+
+        self._devices = (
+            list(self._mesh.devices.flat)
+            if self._mesh is not None
+            else _jax.devices()[:1]
+        )
+        self._device_report = device_report(self._devices, module.cfg)
         # adaptive speculation (ISSUE 15): an optional real draft model
         # (weights derived by layer truncation of the SERVED tree — after
         # quantize/mesh, so the draft rides the same int8/sharded params)
@@ -1379,6 +1381,11 @@ class ModelServer:
                 f"run {uuid[:8]} is not a native jaxjob program run"
             )
         run_spec = V1JAXJob.model_validate(run)
+        from ..utils.jax_platform import require_declared_tpu
+
+        # before the restore: a TPU run served from a silent CPU fallback
+        # fails here, not after reading back a chip-sized checkpoint
+        require_declared_tpu(run_spec, jax.devices()[0].platform)
         program = run_spec.program
         if program.model.name not in ("transformer_lm",):
             raise ServingError(
@@ -3016,6 +3023,7 @@ class ModelServer:
             "leases": self._lease_table.stats(),
         }
         return {
+            "device": self.device_info(),
             "tenancy": tenancy,
             "handoff": handoff,
             "mesh": mesh,
@@ -3048,6 +3056,13 @@ class ModelServer:
             "tracing": tracing,
             "slo": slo,
         }
+
+    def device_info(self) -> dict:
+        """Platform, device kind and ids, the attention backend the model
+        resolves to here, and the devices' live memory counters."""
+        from ..utils.jax_platform import device_memory
+
+        return {**self._device_report, "memory": device_memory(self._devices)}
 
     # ------------------------------------------------------------ http
     def start(self, host: str = "127.0.0.1", port: int = 0) -> int:

@@ -1,7 +1,7 @@
-"""The driver-facing evidence scripts must always emit parseable output:
-bench.py one JSON line with the contract fields, decode/attention benches
-one JSON object per config. These are the round's scorecard inputs — a
-regression here silently voids the perf evidence."""
+"""The driver-facing evidence scripts must emit parseable output: bench.py
+one JSON line with the contract fields, decode/attention benches one JSON
+object per config. They run here on the CPU because the CPU is asked for by
+name (`POLYAXON_JAX_PLATFORM=cpu`); bench.py refuses a CPU it fell back to."""
 
 import json
 import subprocess
@@ -44,6 +44,25 @@ def test_bench_emits_contract_line(tmp_home):
     assert rec["value"] > 0
     assert rec["vs_baseline"] > 0
     assert "device_kind" in rec and "bare_tokens_per_sec" in rec
+
+
+def test_bench_refuses_a_cpu_nobody_asked_for(tmp_home):
+    """No accelerator and no `POLYAXON_JAX_PLATFORM=cpu`: non-zero exit and
+    no record — a CPU number must not appear under a device metric's name."""
+    import os
+
+    env = {
+        k: v for k, v in os.environ.items()
+        if k not in ("JAX_PLATFORMS", "POLYAXON_JAX_PLATFORM")
+    }
+    proc = subprocess.run(
+        [sys.executable, str(REPO / "bench.py")],
+        env=dict(env, POLYAXON_BENCH_TIMEOUT="120"),
+        capture_output=True, text=True, timeout=300,
+    )
+    assert proc.returncode != 0
+    assert not [l for l in proc.stdout.splitlines() if l.strip().startswith("{")]
+    assert "refusing to report a CPU number" in proc.stderr
 
 
 def test_decode_bench_emits_json(tmp_home):

@@ -1,0 +1,128 @@
+"""Compile the main path's kernels for a TPU v5e that is described, not
+attached: the chip's own compiler runs here and refuses what the chip would
+refuse (tiling, VMEM, HBM), which interpret mode on the CPU never shows.
+Nothing executes, so these say nothing about results or speed.
+
+The shapes are those of `chip_smoke.py` (Llama-3.2-1B widths: 32 query / 8
+kv heads of 64, batch 4, seq 2048) plus head width 128.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or libtpu logs under /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.experimental import topologies
+from jax.experimental.compilation_cache import compilation_cache
+from jax.sharding import SingleDeviceSharding
+
+from polyaxon_tpu.ops import flash_attention as fa
+from polyaxon_tpu.parallel import ring
+
+
+@pytest.fixture(scope="module")
+def chip():
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler in this installation
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def for_the_chip(monkeypatch):
+    """Lower the Pallas kernels for Mosaic although the backend is the CPU,
+    and keep these compiles out of the persistent cache: an entry written
+    for a described chip cannot be read back without one."""
+    monkeypatch.setattr(fa, "_interpret", lambda: False)
+    # an earlier test's trainer or server may have left its CPU mesh bound;
+    # model code would then constrain shardings onto CPU devices
+    mesh_was = ring.current_mesh()
+    ring.set_current_mesh(None)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+    ring.set_current_mesh(mesh_was)
+
+
+def _qkv(chip, heads, kv_heads, head_dim, batch=4, seq=2048):
+    def sds(h):
+        return jax.ShapeDtypeStruct(
+            (batch, seq, h, head_dim), jnp.bfloat16, sharding=chip
+        )
+
+    return sds(heads), sds(kv_heads), sds(kv_heads)
+
+
+@pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd+bwd"])
+@pytest.mark.parametrize(
+    "heads,kv_heads,head_dim,block_kv",
+    [(32, 8, 64, 512), (32, 8, 64, 128), (16, 4, 128, 512)],
+    ids=["D64-kv512", "D64-kv128", "D128-kv512"],
+)
+def test_flash_kernel_compiles_for_v5e(chip, heads, kv_heads, head_dim, block_kv, backward):
+    def attend(q, k, v):
+        return fa.flash_attention(
+            q, k, v, causal=True, block_q=128, block_kv=block_kv
+        )
+
+    fn = attend
+    if backward:
+        def fn(q, k, v):
+            return jax.grad(
+                lambda *a: attend(*a).astype(jnp.float32).sum(), argnums=(0, 1, 2)
+            )(q, k, v)
+
+    compiled = jax.jit(fn).lower(*_qkv(chip, heads, kv_heads, head_dim)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def _dense_decode(chip, batch, cache_len=8192, n_layers=1):
+    """The dense `generate` program at Llama-3.2-1B widths, the prompt
+    filling half of an 8,192-token cache. Depth is cut to one layer: what
+    decides the compile is one layer's attention, not how many follow."""
+    from polyaxon_tpu.models import build_model
+    from polyaxon_tpu.models.generate import generate
+    from polyaxon_tpu.runtime.trainer import make_param_init
+
+    bundle = build_model(
+        "transformer_lm",
+        {"preset": "llama3-1b", "seq_len": cache_len, "n_layers": n_layers},
+    )
+    abstract, _ = jax.eval_shape(
+        make_param_init(bundle, jnp.bfloat16, bundle.example_inputs(1)),
+        jax.random.PRNGKey(0),
+    )
+    params = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), abstract
+    )
+    prompt = jax.ShapeDtypeStruct((batch, cache_len // 2), jnp.int32, sharding=chip)
+    rows = jax.ShapeDtypeStruct((batch,), jnp.int32, sharding=chip)
+    fn = jax.jit(
+        lambda p, prompt, lengths, seeds: generate(
+            bundle.module, p, prompt, max_new_tokens=256, seed=seeds,
+            prompt_lengths=lengths,
+        )
+    )
+    return fn.lower(params, prompt, rows, rows).compile()
+
+
+@pytest.mark.slow  # 6 s; the refusal below is what tier-1 pins
+def test_dense_decode_at_cache_8192_compiles_for_one_row(chip):
+    _dense_decode(chip, batch=1)
+
+
+@pytest.mark.xfail(
+    strict=True,
+    raises=jax.errors.JaxRuntimeError,
+    reason="the one-shot dense prefill scores the whole prompt against the "
+    "whole cache: f32[8, 32, 4096, 8192] is 32 GiB on a 16 GiB chip "
+    "(RESOURCE_EXHAUSTED; ROADMAP Speed item 3)",
+)
+def test_dense_decode_at_cache_8192_batch_8(chip):
+    _dense_decode(chip, batch=8)
