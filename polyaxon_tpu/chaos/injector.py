@@ -69,17 +69,11 @@ def inject(point: str, **ctx) -> None:
     if fault is not None:
         # record BEFORE performing: several actions raise/kill, and an
         # injection that took the process down must still be visible
-        from ..telemetry import get_registry, get_tracer
+        from ..telemetry import get_registry
 
         get_registry().counter(
             "chaos.injections", help="Chaos faults actually fired"
         ).inc()
-        get_tracer().event(
-            "chaos.injection",
-            point=point,
-            action=fault.action,
-            step=ctx.get("step"),
-        )
         _perform(fault, point, ctx)
 
 

@@ -152,7 +152,7 @@ def jit_draft_prefill(module):
     One batched forward filling the draft's dense cache; the first
     sampled token comes from the TARGET's prefill, never from here."""
 
-    def run(params, prompt, pad):
+    def draft_prefill(params, prompt, pad):
         B = prompt.shape[0]
         _, init_vars = module.apply(
             {"params": params},
@@ -171,7 +171,7 @@ def jit_draft_prefill(module):
         )
         return vars1["cache"]
 
-    return jax.jit(run)
+    return jax.jit(draft_prefill)
 
 
 def jit_draft_propose(module, *, steps: int, temperature: float,
@@ -186,7 +186,7 @@ def jit_draft_propose(module, *, steps: int, temperature: float,
     is DONATED; pos/start_g are traced per-row vectors, so every window
     of every group reuses one compile per (batch, steps) shape."""
 
-    def run(params, cache, tok, pad, seeds, pos, start_g):
+    def spec_draft(params, cache, tok, pad, seeds, pos, start_g):
         row_keys = jax.vmap(jax.random.PRNGKey)(jnp.asarray(seeds, jnp.int32))
         pos = jnp.asarray(pos, jnp.int32)
         start_g = jnp.asarray(start_g, jnp.int32)
@@ -229,7 +229,7 @@ def jit_draft_propose(module, *, steps: int, temperature: float,
         )
         return vars1["cache"], drafts.T  # [B, steps]
 
-    return jax.jit(run, donate_argnums=(1,))
+    return jax.jit(spec_draft, donate_argnums=(1,))
 
 
 # ------------------------------------------------------------------ host driver

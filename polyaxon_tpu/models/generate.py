@@ -357,7 +357,8 @@ def jit_paged_prefill(
     in place, never duplicated (on backends without donation support,
     e.g. CPU, jax falls back to a copy with a warning)."""
 
-    def run(params, cache, prompt, pad, pages, seeds, adapter_ix=None):
+    def prefill_paged(params, cache, prompt, pad, pages, seeds,
+                      adapter_ix=None):
         return paged_prefill(
             module, params, cache, prompt,
             pad=pad, pages=pages, kv_layout=kv_layout,
@@ -365,7 +366,7 @@ def jit_paged_prefill(
             seeds=seeds, adapter_ix=adapter_ix,
         )
 
-    return jax.jit(run, donate_argnums=(1,))
+    return jax.jit(prefill_paged, donate_argnums=(1,))
 
 
 def jit_paged_chunk(
@@ -383,8 +384,8 @@ def jit_paged_chunk(
     DONATED (see jit_paged_prefill); pos/start_g are traced scalars so
     successive chunks reuse one compile."""
 
-    def run(params, cache, tok, done, pad, pages, seeds, pos, start_g,
-            adapter_ix=None):
+    def decode_chunk_paged(params, cache, tok, done, pad, pages, seeds, pos,
+                           start_g, adapter_ix=None):
         return paged_decode_chunk(
             module, params, cache, tok, done,
             steps=steps, pos=pos, start_g=start_g, pad=pad, pages=pages,
@@ -393,7 +394,7 @@ def jit_paged_chunk(
             seeds=seeds, adapter_ix=adapter_ix,
         )
 
-    return jax.jit(run, donate_argnums=(1,))
+    return jax.jit(decode_chunk_paged, donate_argnums=(1,))
 
 
 # ----------------------------------------------------- chunked prefill (ISSUE 14)
@@ -491,8 +492,8 @@ def jit_paged_prefill_chunk(
     vector, so every slice of every row — whatever its cached-prefix
     width — reuses one compile per (B, C, n_pages) shape."""
 
-    def run(params, cache, chunk, pad, prefix_lens, pages, seeds, pos,
-            adapter_ix=None):
+    def prefill_slice(params, cache, chunk, pad, prefix_lens, pages, seeds,
+                      pos, adapter_ix=None):
         return paged_prefill_chunk(
             module, params, cache, chunk,
             pad=pad, pages=pages, kv_layout=kv_layout,
@@ -501,7 +502,9 @@ def jit_paged_prefill_chunk(
             adapter_ix=adapter_ix,
         )
 
-    return jax.jit(run, donate_argnums=(1,))
+    if final:  # a program of its own (it samples): its own name in a trace
+        prefill_slice.__name__ = prefill_slice.__qualname__ = "prefill_slice_final"
+    return jax.jit(prefill_slice, donate_argnums=(1,))
 
 
 def paged_step(
@@ -568,8 +571,8 @@ def jit_paged_step(
     compile per (B, n_pages, sampling) signature serves the whole mixed
     step stream."""
 
-    def run(params, cache, tok, done, pad, prefix_lens, pages, seeds, pos, g,
-            adapter_ix=None):
+    def decode_step(params, cache, tok, done, pad, prefix_lens, pages, seeds,
+                    pos, g, adapter_ix=None):
         return paged_step(
             module, params, cache, tok, done,
             pad=pad, prefix_lens=prefix_lens, pages=pages,
@@ -578,7 +581,7 @@ def jit_paged_step(
             adapter_ix=adapter_ix,
         )
 
-    return jax.jit(run, donate_argnums=(1,))
+    return jax.jit(decode_step, donate_argnums=(1,))
 
 
 def beam_search(

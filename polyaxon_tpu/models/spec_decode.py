@@ -158,7 +158,7 @@ def jit_spec_prefill(module, *, temperature: float, top_k: Optional[int]):
     index 0 sampled from the last-position logits."""
     from .generate import _adapter_kw, _row_rngs
 
-    def run(params, prompt, pad, seeds, adapter_ix=None):
+    def spec_prefill(params, prompt, pad, seeds, adapter_ix=None):
         B = prompt.shape[0]
         _, init_vars = module.apply(
             {"params": params},
@@ -185,7 +185,7 @@ def jit_spec_prefill(module, *, temperature: float, top_k: Optional[int]):
         )
         return vars1["cache"], first
 
-    return jax.jit(run)
+    return jax.jit(spec_prefill)
 
 
 def jit_spec_verify(
@@ -201,8 +201,8 @@ def jit_spec_verify(
     vectors, so every window of every group reuses one compile per
     (batch, K+1) shape."""
 
-    def run(params, cache, fed, done, pad, seeds, pos, start_g,
-            adapter_ix=None):
+    def spec_verify(params, cache, fed, done, pad, seeds, pos, start_g,
+                    adapter_ix=None):
         from .generate import _adapter_kw
 
         row_keys = jax.vmap(jax.random.PRNGKey)(jnp.asarray(seeds, jnp.int32))
@@ -222,7 +222,7 @@ def jit_spec_verify(
         )
         return vars1["cache"], targets, accept
 
-    return jax.jit(run, donate_argnums=(1,))
+    return jax.jit(spec_verify, donate_argnums=(1,))
 
 
 def jit_spec_verify_paged(
@@ -240,8 +240,8 @@ def jit_spec_verify_paged(
     DONATED and written in place through the page tables; writes past a
     row's table span (rejected-tail overflow) drop in the scatter."""
 
-    def run(params, cache, fed, done, pad, pages, seeds, pos, start_g,
-            adapter_ix=None):
+    def spec_verify_paged(params, cache, fed, done, pad, pages, seeds, pos,
+                          start_g, adapter_ix=None):
         from .generate import _adapter_kw
 
         row_keys = jax.vmap(jax.random.PRNGKey)(jnp.asarray(seeds, jnp.int32))
@@ -264,7 +264,7 @@ def jit_spec_verify_paged(
         )
         return vars1["cache"], targets, accept
 
-    return jax.jit(run, donate_argnums=(1,))
+    return jax.jit(spec_verify_paged, donate_argnums=(1,))
 
 
 # ------------------------------------------------------------------- host side

@@ -13,6 +13,10 @@ The hot op of every transformer in the zoo. Design (pallas_guide.md):
   (kv-block, q-steps), each recomputing p from the saved logsumexp —
   the standard FlashAttention-2 recipe.
 - Off-TPU (CPU tests) the same kernels run with interpret=True.
+- Each `pallas_call` has a `name=` (`flash_attention_fwd`, `_dq`, `_dkv`):
+  it becomes the stem of the custom call's HLO instruction, which is what a
+  device trace calls the kernel's events (`%flash_attention_dq.7 = ...`);
+  the benchmark's `flash_attn_roofline.train` finds them by it.
 """
 
 from __future__ import annotations
@@ -120,6 +124,7 @@ def _fwd(q, k, v, causal, scale, block_q, block_kv, group=1):
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
         interpret=_interpret(),
+        name="flash_attention_fwd",
     )(q, k, v)
     return o, lse
 
@@ -270,6 +275,7 @@ def _bwd_impl(q, k, v, o, lse, do, delta, causal, scale, block_q, block_kv, grou
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         interpret=_interpret(),
+        name="flash_attention_dq",
     )(q, k, v, do, lse, delta)
 
     dk, dv = pl.pallas_call(
@@ -300,6 +306,7 @@ def _bwd_impl(q, k, v, o, lse, do, delta, causal, scale, block_q, block_kv, grou
             pltpu.VMEM((block_kv, D), jnp.float32),
         ],
         interpret=_interpret(),
+        name="flash_attention_dkv",
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 

@@ -41,9 +41,9 @@ from ..parallel.sharding import (
 )
 from ..retry import Preempted
 from ..schemas.run_kinds import V1Program
-from ..telemetry import MetricsRegistry, SpanTracer, now as _now
+from ..telemetry import MetricsRegistry, SpanTracer, compiles, now as _now
 from ..telemetry import mfu as _mfu_of
-from ..telemetry import train_step_flops
+from ..telemetry import required_train_step_flops
 from . import preemption
 
 
@@ -155,8 +155,10 @@ class Trainer:
                 str(Path(artifacts_dir) / "telemetry" / "spans.jsonl")
                 if (artifacts_dir and trace)
                 else None
-            )
+            ),
+            prefix="polyaxon.train.",
         )
+        compiles.install()
 
         from ..utils.jax_platform import apply_compilation_cache, device_report
 
@@ -287,6 +289,7 @@ class Trainer:
         mutable = tuple(bundle.mutable)
         init_fn = make_param_init(bundle, self.param_dtype, example)
         abstract_params, abstract_extra = jax.eval_shape(init_fn, init_rng)
+        self._train_labels = None  # all parameters train
         if bundle.trainable_patterns:
             # LoRA-style fine-tune: non-matching params get zero updates.
             # multi_transform (not optax.masked — masked passes raw grads
@@ -305,6 +308,7 @@ class Trainer:
             self.tx = optax.multi_transform(
                 {"train": self.tx, "freeze": optax.set_to_zero()}, labels
             )
+            self._train_labels = labels
         self.p_shard = param_shardings(abstract_params, bundle.sharding_rules, mesh)
         e_shard = param_shardings(abstract_extra, bundle.sharding_rules, mesh)
         o_shard = _opt_state_shardings(self.tx, abstract_params, self.p_shard, mesh)
@@ -563,6 +567,12 @@ class Trainer:
 
     # -------------------------------------------------------------- loop
     def run(self) -> TrainResult:
+        try:
+            return self._run()
+        finally:
+            self.tracer.flush()  # a run that raises leaves its spans too
+
+    def _run(self) -> TrainResult:
         from ..parallel.ring import set_current_mesh
 
         set_current_mesh(self.mesh)  # re-bind: another Trainer may have traced
@@ -642,6 +652,7 @@ class Trainer:
         )
         t0 = _now()
         self._win = {"t0": t0, "steps": 0, "wait": 0.0, "busy": 0.0}
+        self._xla_logged = None
         for step in range(start_step, self.steps):
             # two-level span tree per iteration: data_wait + compute cover
             # the whole step body, so their durations sum to the step span
@@ -656,11 +667,18 @@ class Trainer:
                     batch = feed.get()
                 if isinstance(batch, BaseException):
                     raise batch
+                # compute's children name where its time goes, as the
+                # benchmark's own spans do: the call that enqueues the
+                # step (tracing and compiling it the first time), the
+                # wait that keeps max_inflight steps queued, the blocking
+                # read of a log point's metrics, and evaluation
                 with self.tracer.span("compute") as busy_span:
-                    self.state, metrics = self.train_step(self.state, batch)
+                    with self.tracer.span("dispatch"):
+                        self.state, metrics = self.train_step(self.state, batch)
                     inflight.append(metrics["loss"])
                     if len(inflight) > max_inflight:
-                        inflight.popleft().block_until_ready()
+                        with self.tracer.span("backpressure"):
+                            inflight.popleft().block_until_ready()
                     if (
                         self._profiling
                         and prof_stop is not None
@@ -672,16 +690,19 @@ class Trainer:
                         # flush the previous log point first: keeps one step
                         # of pipelining so logging never stalls the device
                         if pending is not None:
-                            self._emit(history, *pending)
+                            with self.tracer.span("emit"):
+                                self._emit(history, *pending)
                         pending = (step + 1, metrics)
                     if eval_every and (
                         (step + 1) % eval_every == 0 or step + 1 == self.steps
                     ):
-                        eval_metrics = self._evaluate(eval_steps)
-                        if pending is not None:
-                            self._emit(history, *pending)
-                            pending = None
-                        self._emit(history, step + 1, eval_metrics)
+                        with self.tracer.span("eval"):
+                            eval_metrics = self._evaluate(eval_steps)
+                        with self.tracer.span("emit"):
+                            if pending is not None:
+                                self._emit(history, *pending)
+                                pending = None
+                            self._emit(history, step + 1, eval_metrics)
                 if ckpt_every and (step + 1) % ckpt_every == 0:
                     # the save is async (Orbax snapshots on device, writes in
                     # the background) — this span measures the REAL stall the
@@ -792,12 +813,40 @@ class Trainer:
         global_batch = self.data.batch_size * jax.process_count()
         self._tokens_per_step = global_batch * int(seq)
         try:
-            n_params = sum(
-                x.size for x in jax.tree.leaves(self.state.params)
+            from ..parallel.sharding import _path_str
+
+            flat = jax.tree_util.tree_flatten_with_path(self.state.params)[0]
+            labels = (
+                jax.tree.leaves(self._train_labels)
+                if self._train_labels is not None
+                else ["train"] * len(flat)
             )
-            self._flops_per_step = train_step_flops(
-                n_params, cfg.n_layers, cfg.dim, cfg.seq_len,
-                self._tokens_per_step,
+            frozen = trainable = 0
+            for (path, x), label in zip(flat, labels):
+                # weights that enter a product: not norm scales or biases
+                # (by a layer's own rank: `scan_layers` stacks one axis
+                # before it, pipeline stages two), and not a table that is
+                # only looked up
+                where = _path_str(path)
+                stacked = (
+                    2 if where.startswith("pipeline/")
+                    else 1 if where.startswith("layers/")
+                    else 0
+                )
+                looked_up = where.endswith("embedding") and not getattr(
+                    cfg, "tie_embeddings", False
+                )
+                if x.ndim - stacked < 2 or looked_up:
+                    continue
+                if label == "train":
+                    trainable += x.size
+                else:
+                    frozen += x.size
+            head_dim = getattr(cfg, "head_dim", None) or cfg.dim // cfg.n_heads
+            self._flops_per_step = required_train_step_flops(
+                frozen, trainable, cfg.n_layers, cfg.n_heads * head_dim,
+                int(seq), self._tokens_per_step,
+                causal=self.bundle.task == "lm",
             )
         except (AttributeError, TypeError):
             pass
@@ -842,9 +891,18 @@ class Trainer:
         vals.update(self._drain_window())
         for k, v in vals.items():
             self.telemetry.gauge(f"train.{k}").set(v)
+        # what the process has traced, lowered and compiled (or loaded from
+        # the compile cache) so far: the same series /statsz `xla` shows,
+        # and in the log only where it moved (the first log point, a first
+        # evaluation)
+        xla = compiles.mirror(self.telemetry)
+        if xla != self._xla_logged:
+            self._xla_logged = xla
+            vals.update((f"xla_{k}", float(v)) for k, v in xla.items())
         self._hbm_gauges()
         history.append({"step": step, **vals})
         self.log_fn(step, vals)
+        self.tracer.flush()
 
     def _event(self, kind: str, body: dict):
         """Lifecycle events (preempted/resumed/checkpoint_fallback) to the
@@ -882,6 +940,7 @@ class Trainer:
         self.data.shutdown()
         if hasattr(self, "_eval_data"):
             self._eval_data.shutdown()
+        self.tracer.close()
 
     # -------------------------------------------------------------- ckpt
     def _ckpt_keep(self) -> Optional[int]:

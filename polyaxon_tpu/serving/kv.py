@@ -389,7 +389,7 @@ class KVCacheManager:
         # whose scanned form is 4-dim, so an ndim test misclassifies them
         scanned = bool(getattr(self.module.cfg, "scan_layers", False))
 
-        def run(cache, table_row, start, new_ids):
+        def kv_harvest(cache, table_row, start, new_ids):
             return jax.tree.map(
                 lambda p: (
                     jax.vmap(lambda lp: leaf4(lp, table_row, start, new_ids))(p)
@@ -399,7 +399,7 @@ class KVCacheManager:
                 cache,
             )
 
-        fn = jax.jit(run, donate_argnums=(0,))
+        fn = jax.jit(kv_harvest, donate_argnums=(0,))
         self._harvest_fns[key] = fn
         return fn
 
@@ -667,7 +667,7 @@ class KVCacheManager:
 
         scanned = bool(getattr(self.module.cfg, "scan_layers", False))
 
-        def run(cache, ids, vals):
+        def kv_restore(cache, ids, vals):
             leaves, treedef = jax.tree.flatten(cache)
             out = [
                 (leaf.at[:, ids].set(v) if scanned else leaf.at[ids].set(v))
@@ -675,7 +675,7 @@ class KVCacheManager:
             ]
             return jax.tree.unflatten(treedef, out)
 
-        fn = jax.jit(run, donate_argnums=(0,))
+        fn = jax.jit(kv_restore, donate_argnums=(0,))
         self._restore_fns[n_new] = fn
         return fn
 

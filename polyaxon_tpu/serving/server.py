@@ -57,6 +57,7 @@ at startup.
 from __future__ import annotations
 
 import dataclasses
+import contextlib
 import json
 import threading
 import time
@@ -82,9 +83,11 @@ from ..telemetry import (
     RegressionSentinel,
     RequestTrace,
     SLOEngine,
+    SpanTracer,
     TraceRing,
     build_objectives,
     build_rules,
+    compiles,
     new_trace_id,
     now as _now,
     queryz_payload,
@@ -103,6 +106,22 @@ from .batching import (
     choose_buckets,
 )
 from .kv import KVCacheManager
+
+
+#: phases of one scheduler step, in order (`/statsz` `chunked.phase_s`):
+#: the span's name and what the phase's seconds are spent on
+_STEP_PHASES = {
+    "intake": ("sched.intake", "draining the queue, deadline sweeps, "
+               "admission and composing the step"),
+    "prepare": ("step.prepare", "assembling a program's host arguments "
+                "(numpy, KV pages and tables)"),
+    "dispatch": ("step.dispatch", "the server lock, host-to-device copies "
+                 "and the program call (tracing and compiling it when it "
+                 "is new)"),
+    "fetch": ("step.fetch", "the blocking read of the program's result"),
+    "emit": ("step.emit", "frames to clients, finished rows and the prefix "
+             "cache's harvest"),
+}
 
 
 def _trace_status(error: Optional[BaseException]) -> str:
@@ -600,6 +619,22 @@ class ModelServer:
             help="Rows admitted but not yet past prefill (pending + "
             "mid-prefill), refreshed at scrape time",
         )
+        # where a scheduler step's wall time goes (ISSUE 27): one span a
+        # phase in the ring below (`polyaxon.sched.intake`,
+        # `polyaxon.step.<phase>` in a profiler capture) and cumulative
+        # seconds beside the step count, so /statsz `chunked.phase_s`
+        # over `chunked.steps` is the host's own time per step
+        self.spans = SpanTracer(prefix="polyaxon.", capacity=2048)
+        self._m_phase = {
+            phase: self.telemetry.counter(
+                f"serving.step_phase_seconds.{phase}",
+                help=f"Cumulative seconds of scheduler steps spent in {what}",
+            )
+            for phase, (_, what) in _STEP_PHASES.items()
+        }
+        # XLA programs as JAX counts them, process-wide (compile_count
+        # below counts misses of this server's own LRU of callables)
+        compiles.install()
         # per-request tracing (ISSUE 9): HTTP-level availability counters
         # (request attempts and 5xx-class failures — the SLO engine's
         # availability numerator/denominator), the tail-sampling trace
@@ -1002,6 +1037,26 @@ class ModelServer:
                 self._m_occupancy.observe(rows)
             self._m_batches.inc()
 
+    @contextlib.contextmanager
+    def _phase(self, phase: str, **attrs):
+        """One phase of a scheduler step: a span and its seconds. A span
+        inside which this thread had XLA build (or load) a program says
+        so, and which: a new lane width in `dispatch`, a new harvest shape
+        in `emit`."""
+        span = self.spans.span(_STEP_PHASES[phase][0], **attrs)
+        before = compiles.mine()
+        try:
+            with span:
+                yield span
+                built = compiles.mine() - before
+                if built:
+                    span.set(
+                        compiled=True,
+                        programs=compiles.recent(built, mine=True),
+                    )
+        finally:
+            self._m_phase[phase].inc(span.dur_s or 0.0)
+
     def _kv_observe(self, event: str, **ctx) -> None:
         """KVCacheManager → registry bridge (same pipeline as _observe)."""
         if event == "kv_pages":
@@ -1256,31 +1311,31 @@ class ModelServer:
             num_beams, length_penalty,
         )
 
-        def build():
-            if num_beams > 1:
-                return jax.jit(
-                    lambda params, prompt, seed: beam_search(
-                        self.module,
-                        params,
-                        prompt,
-                        max_new_tokens=max_new,
-                        num_beams=num_beams,
-                        length_penalty=length_penalty,
-                        eos_id=eos_id,
-                    )
-                )
-            return jax.jit(
-                lambda params, prompt, seed: generate(
-                    self.module,
-                    params,
-                    prompt,
-                    max_new_tokens=max_new,
-                    temperature=temperature,
-                    top_k=top_k,
-                    eos_id=eos_id,
-                    seed=seed,
-                )
+        def generate_beam(params, prompt, seed):
+            return beam_search(
+                self.module,
+                params,
+                prompt,
+                max_new_tokens=max_new,
+                num_beams=num_beams,
+                length_penalty=length_penalty,
+                eos_id=eos_id,
             )
+
+        def generate_exact(params, prompt, seed):
+            return generate(
+                self.module,
+                params,
+                prompt,
+                max_new_tokens=max_new,
+                temperature=temperature,
+                top_k=top_k,
+                eos_id=eos_id,
+                seed=seed,
+            )
+
+        def build():
+            return jax.jit(generate_beam if num_beams > 1 else generate_exact)
 
         return self._cached(key, build)
 
@@ -1297,37 +1352,22 @@ class ModelServer:
             eos_id, self._adapter_slots_active,
         )
 
-        def build():
-            if self._adapter_slots_active:
-                return jax.jit(
-                    lambda params, prompt, lengths, seeds, adapter_ix: (
-                        generate(
-                            self.module,
-                            params,
-                            prompt,
-                            max_new_tokens=new_bucket,
-                            temperature=temperature,
-                            top_k=top_k,
-                            eos_id=eos_id,
-                            seed=seeds,
-                            prompt_lengths=lengths,
-                            adapter_ix=adapter_ix,
-                        )
-                    )
-                )
-            return jax.jit(
-                lambda params, prompt, lengths, seeds: generate(
-                    self.module,
-                    params,
-                    prompt,
-                    max_new_tokens=new_bucket,
-                    temperature=temperature,
-                    top_k=top_k,
-                    eos_id=eos_id,
-                    seed=seeds,
-                    prompt_lengths=lengths,
-                )
+        def generate_bucketed(params, prompt, lengths, seeds, adapter_ix=None):
+            return generate(
+                self.module,
+                params,
+                prompt,
+                max_new_tokens=new_bucket,
+                temperature=temperature,
+                top_k=top_k,
+                eos_id=eos_id,
+                seed=seeds,
+                prompt_lengths=lengths,
+                adapter_ix=adapter_ix,
             )
+
+        def build():
+            return jax.jit(generate_bucketed)
 
         return self._cached(key, build)
 
@@ -2974,6 +3014,12 @@ class ModelServer:
                 "prefill_chunk_tokens": int(self.config.prefill_chunk_tokens),
                 "max_step_tokens": int(self.config.max_step_tokens),
                 "steps": c.steps_run,
+                # cumulative seconds of those steps by phase (telemetry
+                # clock); all but `fetch` is the host's own time
+                "phase_s": {
+                    phase: round(float(m.value), 6)
+                    for phase, m in self._m_phase.items()
+                },
                 "prefill_only_steps": c.prefill_only_steps,
                 "classic_forced_steps": c.classic_forced_steps,
                 "prefill_chunks": int(self._m_prefill_chunks.value),
@@ -3022,6 +3068,7 @@ class ModelServer:
             "fallbacks": int(self._m_handoff_fallbacks.value),
             "leases": self._lease_table.stats(),
         }
+        xla = compiles.mirror(self.telemetry)
         return {
             "device": self.device_info(),
             "tenancy": tenancy,
@@ -3034,6 +3081,9 @@ class ModelServer:
             **resilience,
             "batching": bool(self.config.batching),
             "compile_count": self.compile_count,
+            # XLA programs as JAX reports them (this process, ever):
+            # traced, lowered, and compiled or loaded from the cache
+            "xla": {**xla, "recent": compiles.recent()},
             "compile_cache": {
                 "hits": int(self._m_cache_hits.value),
                 "misses": int(self._m_cache_misses.value),
@@ -3140,6 +3190,7 @@ class ModelServer:
                         )
                         if pq is not None:
                             server._m_prefill_queue.set(pq)
+                    compiles.mirror(server.telemetry)
                     self._send_raw(
                         200,
                         server.telemetry.render_prometheus().encode(),
@@ -3511,6 +3562,10 @@ class _StepEngine:
         self._s = server
 
     # --------------------------------------------------------------- protocol
+    def phase(self, name: str):
+        """The scheduler's own phases (it reads no clock): timed here."""
+        return self._s._phase(name)
+
     def supports(self, r: PendingRequest) -> bool:
         return (
             self._s._kv is not None
@@ -3582,15 +3637,17 @@ class _StepEngine:
         # row fails with its page table half-built, and on_finish must
         # return every page (tests/test_serving_chunked.py chaos case)
         inject("serving.prefill_chunk", row=r.row, off=st.off)
-        kv.ensure_pages(
-            [r.kv_plan], upto_slot=st.L + st.off + width, traces=[r.trace]
-        )
-        table = kv.tables([r.kv_plan], 1, st.wt)
-        chunk = st.arr[:, st.off : st.off + width]
-        pads = np.asarray([st.pad], np.int32)
-        pls = np.asarray([st.L], np.int32)
-        seeds = np.asarray([r.seed], np.int32)
-        with s._lock:
+        with s._phase("prepare", lane="prefill_chunk"):
+            kv.ensure_pages(
+                [r.kv_plan], upto_slot=st.L + st.off + width, traces=[r.trace]
+            )
+            table = kv.tables([r.kv_plan], 1, st.wt)
+            chunk = st.arr[:, st.off : st.off + width]
+            pads = np.asarray([st.pad], np.int32)
+            pls = np.asarray([st.L], np.int32)
+            seeds = np.asarray([r.seed], np.int32)
+        program = "prefill_slice_final" if final else "prefill_slice"
+        with s._phase("dispatch", program=program, tokens=width), s._lock:
             # land any queued spill restores before the chunk reads
             # restored prefix pages (ISSUE 17)
             kv.flush_restores()
@@ -3635,50 +3692,52 @@ class _StepEngine:
             return width
         # prefill boundary: the first sampled token leaves NOW — TTFT no
         # longer waits for co-resident prompts (the whole point)
-        first_i = int(np.asarray(first)[0])
-        r.first_token_at = tnow
-        if r.t0 is not None:
-            s._m_ttft.observe((tnow - r.t0) * 1e3)
-        st.gen = [first_i]
-        st.decode_t0 = tnow
-        self._emit(r, [first_i])
-        if key.eos_id is not None and first_i == key.eos_id:
-            # everything after a generated eos is pinned: finish host-side
-            fill = [int(key.eos_id)] * (r.max_new - 1)
-            st.gen.extend(fill)
-            self._emit(r, fill)
-            self._finish_row(r)
-        elif r.max_new <= 1:
-            self._finish_row(r)
-        elif not self._maybe_handoff(r, first_i):
-            st.tok = first_i
-            st.done = False
-            st.pos = st.L + st.pb
-            st.g = 1
-            if key.speculate:
-                # step lanes recompose every step, so a batched draft
-                # cache cannot follow a row between lanes: each row gets
-                # its own B=1 drafter (prompt padded to the bucketed
-                # width, so draft compiles stay ladder-bounded)
-                if s._draft_module is not None:
-                    import numpy as _np
+        with s._phase("fetch"):
+            first_i = int(np.asarray(first)[0])
+        with s._phase("emit"):
+            r.first_token_at = tnow
+            if r.t0 is not None:
+                s._m_ttft.observe((tnow - r.t0) * 1e3)
+            st.gen = [first_i]
+            st.decode_t0 = tnow
+            self._emit(r, [first_i])
+            if key.eos_id is not None and first_i == key.eos_id:
+                # everything after a generated eos is pinned: finish host-side
+                fill = [int(key.eos_id)] * (r.max_new - 1)
+                st.gen.extend(fill)
+                self._emit(r, fill)
+                self._finish_row(r)
+            elif r.max_new <= 1:
+                self._finish_row(r)
+            elif not self._maybe_handoff(r, first_i):
+                st.tok = first_i
+                st.done = False
+                st.pos = st.L + st.pb
+                st.g = 1
+                if key.speculate:
+                    # step lanes recompose every step, so a batched draft
+                    # cache cannot follow a row between lanes: each row gets
+                    # its own B=1 drafter (prompt padded to the bucketed
+                    # width, so draft compiles stay ladder-bounded)
+                    if s._draft_module is not None:
+                        import numpy as _np
 
-                    dP = st.L + st.pb
-                    dprompt = _np.zeros((1, dP), _np.int32)
-                    dprompt[0, dP - len(r.tokens):] = r.tokens
-                    with s._lock:
-                        st.drafter = s._make_drafter(
-                            dprompt, [len(r.tokens)], [r.seed],
-                            temperature=key.temperature, top_k=key.top_k,
-                        )
-                    st.model_draft = True
-                else:
-                    from ..models.spec_decode import NgramDrafter
+                        dP = st.L + st.pb
+                        dprompt = _np.zeros((1, dP), _np.int32)
+                        dprompt[0, dP - len(r.tokens):] = r.tokens
+                        with s._lock:
+                            st.drafter = s._make_drafter(
+                                dprompt, [len(r.tokens)], [r.seed],
+                                temperature=key.temperature, top_k=key.top_k,
+                            )
+                        st.model_draft = True
+                    else:
+                        from ..models.spec_decode import NgramDrafter
 
-                    st.drafter = NgramDrafter(r.tokens + [first_i])
-                    st.model_draft = False
-                st.remaining = r.max_new - 1
-            st.phase = "decode"
+                        st.drafter = NgramDrafter(r.tokens + [first_i])
+                        st.model_draft = False
+                    st.remaining = r.max_new - 1
+                st.phase = "decode"
         return width
 
     def _maybe_handoff(self, r: PendingRequest, first_i: int) -> bool:
@@ -3808,33 +3867,34 @@ class _StepEngine:
         n = len(lane)
         inject("serving.slow", rows=n)
         inject("serving.decode", rows=n)
-        bb = batch_bucket(n, max(n, s.config.max_batch))
-        wt = max(r.step.wt for r in lane)
-        tok = np.zeros((bb,), np.int32)
-        done = np.ones((bb,), bool)  # dummy rows: latched done
-        pads = np.zeros((bb,), np.int32)
-        pls = np.zeros((bb,), np.int32)
-        seeds = np.zeros((bb,), np.int32)
-        pos = np.zeros((bb,), np.int64)
-        g = np.ones((bb,), np.int64)
-        plans = [r.kv_plan for r in lane] + [None] * (bb - n)
-        for i, r in enumerate(lane):
-            st = r.step
-            tok[i] = st.tok
-            done[i] = st.done
-            pads[i] = st.pad
-            pls[i] = st.L
-            seeds[i] = r.seed
-            pos[i] = st.pos
-            g[i] = st.g
-        kv.ensure_pages(
-            plans[:n],
-            upto_slot=int(pos[:n].max()) + 1,
-            traces=[r.trace for r in lane],
-        )
-        tables = kv.tables(plans, bb, wt)
-        ix = s._adapter_ix(lane, bb)
-        with s._lock:
+        with s._phase("prepare", lane="decode", rows=n):
+            bb = batch_bucket(n, max(n, s.config.max_batch))
+            wt = max(r.step.wt for r in lane)
+            tok = np.zeros((bb,), np.int32)
+            done = np.ones((bb,), bool)  # dummy rows: latched done
+            pads = np.zeros((bb,), np.int32)
+            pls = np.zeros((bb,), np.int32)
+            seeds = np.zeros((bb,), np.int32)
+            pos = np.zeros((bb,), np.int64)
+            g = np.ones((bb,), np.int64)
+            plans = [r.kv_plan for r in lane] + [None] * (bb - n)
+            for i, r in enumerate(lane):
+                st = r.step
+                tok[i] = st.tok
+                done[i] = st.done
+                pads[i] = st.pad
+                pls[i] = st.L
+                seeds[i] = r.seed
+                pos[i] = st.pos
+                g[i] = st.g
+            kv.ensure_pages(
+                plans[:n],
+                upto_slot=int(pos[:n].max()) + 1,
+                traces=[r.trace for r in lane],
+            )
+            tables = kv.tables(plans, bb, wt)
+            ix = s._adapter_ix(lane, bb)
+        with s._phase("dispatch", program="decode_step", rows=n), s._lock:
             fn = s._paged_step_fn(key0.temperature, key0.top_k, key0.eos_id)
             step_args = [
                 s.params,
@@ -3845,40 +3905,42 @@ class _StepEngine:
                 jnp.asarray(pls),
                 jnp.asarray(tables),
                 jnp.asarray(seeds),
-                jnp.asarray(pos, jnp.int32),
-                jnp.asarray(g, jnp.int32),
+                jnp.asarray(pos.astype(np.int32)),
+                jnp.asarray(g.astype(np.int32)),
             ]
             if ix is not None:
                 step_args.append(jnp.asarray(ix))
             kv.cache, nxt, done_out = fn(*step_args)
-        nxt = np.asarray(nxt)
-        done_out = np.asarray(done_out)
-        chunk_cap = max(1, int(s.config.stream_chunk_tokens))
-        for i, r in enumerate(lane):
-            st = r.step
-            t = int(nxt[i])
-            st.gen.append(t)
-            st.buf.append(t)
-            st.tok = t
-            st.done = bool(done_out[i])
-            st.pos += 1
-            st.g += 1
-            if key0.eos_id is not None and t == key0.eos_id:
-                fill = [int(key0.eos_id)] * (r.max_new - len(st.gen))
-                st.gen.extend(fill)
-                st.buf.extend(fill)
-                self._emit(r, st.buf)
-                st.buf = []
-                self._finish_row(r)
-            elif len(st.gen) >= r.max_new:
-                self._emit(r, st.buf)
-                st.buf = []
-                self._finish_row(r)
-            elif len(st.buf) >= chunk_cap:
-                # same emission cadence as the classic chunk loop: one
-                # event per stream_chunk_tokens decoded tokens
-                self._emit(r, st.buf)
-                st.buf = []
+        with s._phase("fetch"):
+            nxt = np.asarray(nxt)
+            done_out = np.asarray(done_out)
+        with s._phase("emit"):
+            chunk_cap = max(1, int(s.config.stream_chunk_tokens))
+            for i, r in enumerate(lane):
+                st = r.step
+                t = int(nxt[i])
+                st.gen.append(t)
+                st.buf.append(t)
+                st.tok = t
+                st.done = bool(done_out[i])
+                st.pos += 1
+                st.g += 1
+                if key0.eos_id is not None and t == key0.eos_id:
+                    fill = [int(key0.eos_id)] * (r.max_new - len(st.gen))
+                    st.gen.extend(fill)
+                    st.buf.extend(fill)
+                    self._emit(r, st.buf)
+                    st.buf = []
+                    self._finish_row(r)
+                elif len(st.gen) >= r.max_new:
+                    self._emit(r, st.buf)
+                    st.buf = []
+                    self._finish_row(r)
+                elif len(st.buf) >= chunk_cap:
+                    # same emission cadence as the classic chunk loop: one
+                    # event per stream_chunk_tokens decoded tokens
+                    self._emit(r, st.buf)
+                    st.buf = []
         s._spec_tick_plain(1)
         return n
 
@@ -3896,39 +3958,42 @@ class _StepEngine:
         L = int(key0.prefix_len)
         inject("serving.slow", rows=n)
         inject("serving.decode", rows=n)
-        bb = batch_bucket(n, max(n, s.config.max_batch))
-        wt = max(r.step.wt for r in lane)
-        fed = np.zeros((bb, K + 1), np.int32)
-        pads = np.zeros((bb,), np.int32)
-        seeds = np.zeros((bb,), np.int32)
-        pos = np.zeros((bb,), np.int64)
-        start_g = np.ones((bb,), np.int64)
-        done = np.zeros((bb,), bool)
-        remaining = np.zeros((bb,), np.int64)
-        plans = [r.kv_plan for r in lane] + [None] * (bb - n)
-        for i, r in enumerate(lane):
-            st = r.step
-            fed[i, 0] = st.tok
-            if st.remaining <= 0:
-                fed[i, 1:] = st.tok
-            elif getattr(st, "model_draft", False):
-                with s._lock:
-                    fed[i, 1:] = st.drafter.propose([st.tok], [st.g], K)[0]
-            else:
-                fed[i, 1:] = st.drafter.propose(K)
-            pads[i] = st.pad
-            seeds[i] = r.seed
-            pos[i] = st.pos
-            start_g[i] = st.g
-            done[i] = st.done
-            remaining[i] = st.remaining
-        frontier = int(pos[:n].max()) + K + 1
-        kv.ensure_pages(
-            plans[:n], upto_slot=frontier, traces=[r.trace for r in lane]
-        )
-        tables = kv.tables(plans, bb, wt)
-        ix = s._adapter_ix(lane, bb)
-        with s._lock:
+        with s._phase("prepare", lane="spec_decode", rows=n):
+            bb = batch_bucket(n, max(n, s.config.max_batch))
+            wt = max(r.step.wt for r in lane)
+            fed = np.zeros((bb, K + 1), np.int32)
+            pads = np.zeros((bb,), np.int32)
+            seeds = np.zeros((bb,), np.int32)
+            pos = np.zeros((bb,), np.int64)
+            start_g = np.ones((bb,), np.int64)
+            done = np.zeros((bb,), bool)
+            remaining = np.zeros((bb,), np.int64)
+            plans = [r.kv_plan for r in lane] + [None] * (bb - n)
+            for i, r in enumerate(lane):
+                st = r.step
+                fed[i, 0] = st.tok
+                if st.remaining <= 0:
+                    fed[i, 1:] = st.tok
+                elif getattr(st, "model_draft", False):
+                    with s._lock:
+                        fed[i, 1:] = st.drafter.propose([st.tok], [st.g], K)[0]
+                else:
+                    fed[i, 1:] = st.drafter.propose(K)
+                pads[i] = st.pad
+                seeds[i] = r.seed
+                pos[i] = st.pos
+                start_g[i] = st.g
+                done[i] = st.done
+                remaining[i] = st.remaining
+            frontier = int(pos[:n].max()) + K + 1
+            kv.ensure_pages(
+                plans[:n], upto_slot=frontier, traces=[r.trace for r in lane]
+            )
+            tables = kv.tables(plans, bb, wt)
+            ix = s._adapter_ix(lane, bb)
+        with s._phase(
+            "dispatch", program="spec_verify_paged", rows=n
+        ), s._lock:
             fn = s._spec_verify_paged_fn(
                 bb, K, L, wt, key0.temperature, key0.top_k, key0.eos_id
             )
@@ -3940,53 +4005,57 @@ class _StepEngine:
                 jnp.asarray(pads),
                 jnp.asarray(tables),
                 jnp.asarray(seeds),
-                jnp.asarray(pos, jnp.int32),
-                jnp.asarray(start_g, jnp.int32),
+                jnp.asarray(pos.astype(np.int32)),
+                jnp.asarray(start_g.astype(np.int32)),
             ]
             if ix is not None:
                 sv_args.append(jnp.asarray(ix))
             kv.cache, targets, accept = fn(*sv_args)
-        committed, done2, remaining2, eos_hit, delta = commit_window(
-            fed, targets, accept, remaining, done, key0.eos_id
-        )
-        s._spec_observe(delta)
-        tnow = _now()
-        for i, r in enumerate(lane):
-            st = r.step
-            if r.trace is not None:
-                r.trace.add(
-                    "verify",
-                    start=st.t_prev,
-                    dur_s=tnow - st.t_prev,
-                    group=st.gid,
-                    row=r.row,
-                    window=st.window,
-                    proposed=delta["proposed"],
-                    accepted=delta["accepted"],
-                    rollback=delta["rollback"],
-                )
-            st.t_prev = tnow
-            st.window += 1
-            toks = committed[i]
-            if len(toks):
-                # classic spec cadence: each window's committed tokens
-                # are one streamed event
-                st.gen.extend(int(t) for t in toks)
-                self._emit(r, toks)
-                if not getattr(st, "model_draft", False):
-                    # the ModelDrafter's cache frontier is a function of
-                    # st.g alone; only the n-gram index needs the text
-                    st.drafter.extend(toks)
-                st.tok = int(toks[-1])
-                st.pos += len(toks)
-                st.g += len(toks)
-            st.done = bool(done2[i])
-            st.remaining = int(remaining2[i])
-            if eos_hit[i] and st.remaining > 0:
-                fill = [int(key0.eos_id)] * st.remaining
-                st.gen.extend(fill)
-                self._emit(r, fill)
-                st.remaining = 0
-            if st.remaining <= 0:
-                self._finish_row(r)
+        # commit_window reads targets and accept on the host: the wait
+        # for the device is here
+        with s._phase("fetch"):
+            committed, done2, remaining2, eos_hit, delta = commit_window(
+                fed, targets, accept, remaining, done, key0.eos_id
+            )
+        with s._phase("emit"):
+            s._spec_observe(delta)
+            tnow = _now()
+            for i, r in enumerate(lane):
+                st = r.step
+                if r.trace is not None:
+                    r.trace.add(
+                        "verify",
+                        start=st.t_prev,
+                        dur_s=tnow - st.t_prev,
+                        group=st.gid,
+                        row=r.row,
+                        window=st.window,
+                        proposed=delta["proposed"],
+                        accepted=delta["accepted"],
+                        rollback=delta["rollback"],
+                    )
+                st.t_prev = tnow
+                st.window += 1
+                toks = committed[i]
+                if len(toks):
+                    # classic spec cadence: each window's committed tokens
+                    # are one streamed event
+                    st.gen.extend(int(t) for t in toks)
+                    self._emit(r, toks)
+                    if not getattr(st, "model_draft", False):
+                        # the ModelDrafter's cache frontier is a function of
+                        # st.g alone; only the n-gram index needs the text
+                        st.drafter.extend(toks)
+                    st.tok = int(toks[-1])
+                    st.pos += len(toks)
+                    st.g += len(toks)
+                st.done = bool(done2[i])
+                st.remaining = int(remaining2[i])
+                if eos_hit[i] and st.remaining > 0:
+                    fill = [int(key0.eos_id)] * st.remaining
+                    st.gen.extend(fill)
+                    self._emit(r, fill)
+                    st.remaining = 0
+                if st.remaining <= 0:
+                    self._finish_row(r)
         return n * (K + 1)

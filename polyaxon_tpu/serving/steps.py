@@ -39,6 +39,7 @@ reads.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from collections import deque
 from typing import Callable, Optional
@@ -90,6 +91,12 @@ class StepEngine:
         """Run ONE decode step for a lane; returns tokens consumed.
         Finishes rows that complete (phase = "done" + req.finish)."""
         raise NotImplementedError
+
+    def phase(self, name: str):
+        """Context manager around one of the scheduler's own phases
+        (`intake`). The scheduler reads no clock; an engine that times
+        its steps times this too."""
+        return contextlib.nullcontext()
 
 
 class StepScheduler(DecodeCoalescer):
@@ -280,6 +287,46 @@ class StepScheduler(DecodeCoalescer):
         self._inflight = None
         self._resolve(len(batch))
 
+    def _compose_step(self):
+        """What the next step runs: None (nothing is active), "classic"
+        (an exclusive classic group), or (decode rows, the row whose
+        prefill slice runs or waits, whether it runs)."""
+        if not (self._prefilling or self._decoding or self._classic):
+            return None
+        # 4. classic fallback groups run as exclusive steps:
+        # immediately when nothing is steppable, and FORCED after
+        # CLASSIC_STARVE_STEPS consecutive steppable steps so beam
+        # rows cannot starve under sustained steppable load
+        if self._classic:
+            forced = self._classic_waits >= self.CLASSIC_STARVE_STEPS
+            if forced or not (self._prefilling or self._decoding):
+                if forced and (self._prefilling or self._decoding):
+                    self.classic_forced_steps += 1
+                self._classic_waits = 0
+                return "classic"
+            self._classic_waits += 1
+        else:
+            self._classic_waits = 0
+        # 5. compose the step: all decode lanes + at most one prefill
+        # slice, within max_step_tokens
+        decode_rows = list(self._decoding)
+        decode_cost = sum(r.step.cost for r in decode_rows)
+        pf = self._prefilling[0] if self._prefilling else None
+        run_prefill = False
+        if pf is not None:
+            chunk = max(1, pf.step.next_chunk)
+            if not decode_rows or decode_cost + chunk <= self.max_step_tokens:
+                run_prefill = True
+            elif self._starved:
+                # anti-starvation: budget excluded prefill last step
+                # too — run a prefill-only step so prefill always
+                # makes progress under sustained decode load
+                decode_rows = []
+                run_prefill = True
+                self.prefill_only_steps += 1
+        self._starved = pf is not None and not run_prefill
+        return decode_rows, pf, run_prefill
+
     # ------------------------------------------------------------ worker loop
     def _loop(self):
         alive = True
@@ -300,53 +347,30 @@ class StepScheduler(DecodeCoalescer):
             active = self._prefilling or self._decoding or self._classic
             if not alive and not self._pending and not active:
                 break
-            # 1. intake — never block while there is device work to do
-            if alive:
-                block = not (active or self._pending)
-                alive = self._drain_into_pending(
-                    timeout=0.05 if block else None
-                )
-            # 2. deadline sweeps: pending (before a slot is spent) and
-            # mid-flight (between steps) both 504 on expiry
-            self._purge_expired()
-            self._evict_expired_active()
-            # 3. continuous admission under the token budget
-            self._admit_active()
-            if not (self._prefilling or self._decoding or self._classic):
-                continue
-            # 4. classic fallback groups run as exclusive steps:
-            # immediately when nothing is steppable, and FORCED after
-            # CLASSIC_STARVE_STEPS consecutive steppable steps so beam
-            # rows cannot starve under sustained steppable load
-            if self._classic:
-                forced = self._classic_waits >= self.CLASSIC_STARVE_STEPS
-                if forced or not (self._prefilling or self._decoding):
-                    if forced and (self._prefilling or self._decoding):
-                        self.classic_forced_steps += 1
-                    self._classic_waits = 0
-                    self._run_classic_step()
+            # 1. intake — never block while there is device work to do.
+            # Waiting for a request is no phase of a step: the timed
+            # intake starts once there is something to schedule.
+            waited = alive and not (active or self._pending)
+            if waited:
+                alive = self._drain_into_pending(timeout=0.05)
+                if not self._pending:
                     continue
-                self._classic_waits += 1
-            else:
-                self._classic_waits = 0
-            # 5. compose the step: all decode lanes + at most one prefill
-            # slice, within max_step_tokens
-            decode_rows = list(self._decoding)
-            decode_cost = sum(r.step.cost for r in decode_rows)
-            pf = self._prefilling[0] if self._prefilling else None
-            run_prefill = False
-            if pf is not None:
-                chunk = max(1, pf.step.next_chunk)
-                if not decode_rows or decode_cost + chunk <= self.max_step_tokens:
-                    run_prefill = True
-                elif self._starved:
-                    # anti-starvation: budget excluded prefill last step
-                    # too — run a prefill-only step so prefill always
-                    # makes progress under sustained decode load
-                    decode_rows = []
-                    run_prefill = True
-                    self.prefill_only_steps += 1
-            self._starved = pf is not None and not run_prefill
+            with self._engine.phase("intake"):
+                if alive and not waited:
+                    alive = self._drain_into_pending(timeout=None)
+                # 2. deadline sweeps: pending (before a slot is spent) and
+                # mid-flight (between steps) both 504 on expiry
+                self._purge_expired()
+                self._evict_expired_active()
+                # 3. continuous admission under the token budget
+                self._admit_active()
+                plan = self._compose_step()
+            if plan is None:
+                continue
+            if plan == "classic":
+                self._run_classic_step()
+                continue
+            decode_rows, pf, run_prefill = plan
             # 6. execute — the chaos kill point sits OUTSIDE the per-lane
             # try so a "serving.worker" fault takes the thread down and
             # exercises the watchdog, exactly like the classic loop

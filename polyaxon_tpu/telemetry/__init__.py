@@ -11,8 +11,9 @@ monitor wrote straight to the store. Everything now flows through:
   (`/statsz`) or Prometheus text exposition (`/metricsz`). Both surfaces
   read the SAME registry, so they cannot drift.
 - `SpanTracer` — context-manager spans with parent/child nesting,
-  exported as JSONL into the run's artifacts dir next to the
-  jax.profiler trace.
+  exported in batches as JSONL into the run's artifacts dir next to the
+  jax.profiler trace, and each one a `polyaxon.*` TraceAnnotation inside
+  any such trace, on the device events' clock.
 - `RequestTrace`/`TraceRing` (tracing.py) — the serving-side trace
   builder: explicit-parent spans that survive thread hops, plus a
   tail-sampling ring that always keeps errors/sheds/deadline-exceeded
@@ -20,6 +21,9 @@ monitor wrote straight to the store. Everything now flows through:
 - `SLOEngine`/`FlightRecorder` (slo.py) — multi-window burn rates over
   registry counters/histograms, `slo_burn_rate`/`slo_breached` gauges,
   and the breach-triggered post-mortem bundle under `<outputs>/debug/`.
+- `compiles` — the one listener on JAX's compile events: `xla.programs`,
+  `xla.traces`, `xla.lowerings` and their seconds, for `/statsz` `xla`
+  and the trainer's log.
 - `quantile`/`summarize` — the one exact-percentile implementation
   (benchmarks used to each carry their own).
 - `now()` — the sanctioned monotonic clock for metrics timing. No other
@@ -61,6 +65,7 @@ from .registry import (
     get_registry,
     now,
 )
+from . import compiles
 from .slo import (
     AvailabilityObjective,
     FlightRecorder,
@@ -69,7 +74,13 @@ from .slo import (
     build_objectives,
 )
 from .spans import SpanTracer, get_tracer
-from .stats import mfu, quantile, summarize, train_step_flops
+from .stats import (
+    mfu,
+    quantile,
+    required_train_step_flops,
+    summarize,
+    train_step_flops,
+)
 from .tracing import RequestTrace, TraceRing, new_trace_id, tracez_payload
 
 __all__ = [
@@ -104,6 +115,7 @@ __all__ = [
     "mfu",
     "now",
     "quantile",
+    "required_train_step_flops",
     "summarize",
     "train_step_flops",
 ]

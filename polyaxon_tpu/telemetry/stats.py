@@ -56,6 +56,29 @@ def train_step_flops(
     )
 
 
+def required_train_step_flops(
+    frozen: int,
+    trainable: int,
+    n_layers: int,
+    attn_width: int,
+    seq_len: int,
+    tokens: int,
+    causal: bool = True,
+) -> float:
+    """What one optimizer step on `tokens` tokens REQUIRES, as the
+    benchmark counts it (`cellbench/flops.py::train_step_flops`; a test
+    pins the two equal): a frozen weight needs its forward product and
+    the gradient of its input (4 per weight and token), a trainable one
+    its own gradient too (6); attention's QK^T and PV over the causal
+    triangle (diagonal included), backward twice the forward.
+    `frozen`/`trainable` count weights that enter a product; `attn_width`
+    is heads x head size. 6 per weight for all of a LoRA model, as
+    `train_step_flops` has it, overstates the requirement by half."""
+    pairs = seq_len * (seq_len + 1) / 2 if causal else seq_len * seq_len
+    attn_fwd = n_layers * (tokens / seq_len) * 2 * 2 * pairs * attn_width
+    return float(4.0 * frozen * tokens + 6.0 * trainable * tokens + 3.0 * attn_fwd)
+
+
 def mfu(flops_per_sec: float, device_kind: str, n_devices: int = 1) -> Optional[float]:
     """Model FLOPs utilization against the device generation's peak bf16
     throughput; None when the peak is unknown (CPU, unrecognized chip) —

@@ -162,6 +162,8 @@ def test_span_nesting_and_jsonl_export(tmp_path):
         with tr.span("compute") as inner:
             inner.set(tokens=128)
         tr.event("checkpoint", step=3)
+    assert not path.exists()  # batched: nothing is written per span
+    tr.close()
     recs = [json.loads(line) for line in path.read_text().splitlines()]
     by_name = {r["name"]: r for r in recs}
     assert [r["name"] for r in recs] == [
@@ -497,6 +499,7 @@ def test_stats_cli_renders_run(tmp_home, tmp_path):
     with tr.span("step", step=5):
         with tr.span("compute"):
             pass
+    tr.close()
     store.set_status(uuid, "succeeded")
 
     res = CliRunner().invoke(cli, ["stats", uuid])
