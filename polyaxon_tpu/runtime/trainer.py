@@ -290,6 +290,7 @@ class Trainer:
         init_fn = make_param_init(bundle, self.param_dtype, example)
         abstract_params, abstract_extra = jax.eval_shape(init_fn, init_rng)
         self._train_labels = None  # all parameters train
+        labels = jax.tree.map(lambda _: "train", abstract_params)
         if bundle.trainable_patterns:
             # LoRA-style fine-tune: non-matching params get zero updates.
             # multi_transform (not optax.masked — masked passes raw grads
@@ -309,6 +310,7 @@ class Trainer:
                 {"train": self.tx, "freeze": optax.set_to_zero()}, labels
             )
             self._train_labels = labels
+        self._report_differentiated(abstract_params, labels)
         self.p_shard = param_shardings(abstract_params, bundle.sharding_rules, mesh)
         e_shard = param_shardings(abstract_extra, bundle.sharding_rules, mesh)
         o_shard = _opt_state_shardings(self.tx, abstract_params, self.p_shard, mesh)
@@ -421,10 +423,35 @@ class Trainer:
             )
         self.grad_accum = grad_accum
 
-        def grads_of(params, extra, batch, rng):
-            """One microbatch: (loss, grads, new_extra, logits)."""
+        # The step differentiates the leaves labelled `train` only (every
+        # leaf, when the bundle names no `trainable_patterns`). Both halves
+        # keep the tree's shape, with `None` (an empty subtree) where the
+        # other half's leaves sit: a frozen kernel enters `loss_of` as a
+        # value, and no weight gradient of it is ever asked of XLA.
+        def split(params):
+            return (
+                jax.tree.map(
+                    lambda label, x: x if label == "train" else None,
+                    labels, params,
+                ),
+                jax.tree.map(
+                    lambda label, x: None if label == "train" else x,
+                    labels, params,
+                ),
+            )
 
-            def loss_of(p):
+        def merge(trained, frozen):
+            return jax.tree.map(
+                lambda label, t, f: t if label == "train" else f,
+                labels, trained, frozen,
+            )
+
+        def grads_of(trained, frozen, extra, batch, rng):
+            """One microbatch: (loss, grads, new_extra, logits); `grads`
+            mirrors `trained`, the differentiated half of the parameters."""
+
+            def loss_of(t):
+                p = merge(t, frozen)
                 compute_params = (
                     _cast_floats(p, compute_dtype)
                     if compute_dtype != param_dtype
@@ -443,15 +470,16 @@ class Trainer:
 
             (loss, (logits, new_extra)), grads = jax.value_and_grad(
                 loss_of, has_aux=True
-            )(params)
+            )(trained)
             return loss, grads, new_extra, logits
 
         def step_fn(state: TrainState, batch):
             rng = jax.random.fold_in(jax.random.PRNGKey(seed), state.step)
+            trained, frozen = split(state.params)
 
             if grad_accum == 1:
                 loss, grads, new_extra, logits = grads_of(
-                    state.params, state.extra, batch, rng
+                    trained, frozen, state.extra, batch, rng
                 )
                 acc_metric = (
                     accuracy_metric(logits, batch) if is_classification else None
@@ -471,7 +499,7 @@ class Trainer:
                 def one(carry, mb):
                     extra_c, grads_c, loss_c, acc_c, i = carry
                     loss, grads, new_extra, logits = grads_of(
-                        state.params, extra_c, mb, jax.random.fold_in(rng, i)
+                        trained, frozen, extra_c, mb, jax.random.fold_in(rng, i)
                     )
                     grads = _cast_floats(grads, param_dtype)
                     grads_c = jax.tree.map(jnp.add, grads_c, grads)
@@ -486,7 +514,7 @@ class Trainer:
                     lambda x: jnp.zeros(x.shape, param_dtype)
                     if jnp.issubdtype(x.dtype, jnp.floating)
                     else jnp.zeros_like(x),
-                    state.params,
+                    trained,
                 )
                 carry, _ = jax.lax.scan(
                     one,
@@ -505,12 +533,19 @@ class Trainer:
                 acc_metric = acc_sum / grad_accum if is_classification else None
             # grads come out in compute dtype; update math runs in param dtype
             grads = _cast_floats(grads, param_dtype)
+            # the norm of the gradient the optimizer is given: under
+            # `trainable_patterns` the adapters' (what `grad_clip_norm`
+            # clips on), never a frozen kernel's
+            grad_norm = optax.global_norm(grads).astype(jnp.float32)
+            # `tx` takes the whole tree; a frozen leaf's gradient is a zero
+            # that `set_to_zero` drops and XLA folds away
+            grads = merge(grads, jax.tree.map(jnp.zeros_like, frozen))
             updates, opt_state = tx.update(grads, state.opt_state, state.params)
             params = optax.apply_updates(state.params, updates)
             metrics = {
                 "loss": loss.astype(jnp.float32),
                 "learning_rate": jnp.asarray(sched(state.step), jnp.float32),
-                "grad_norm": optax.global_norm(grads).astype(jnp.float32),
+                "grad_norm": grad_norm,
             }
             if acc_metric is not None:
                 metrics["accuracy"] = acc_metric
@@ -795,6 +830,21 @@ class Trainer:
         self._event(
             "artifact",
             {"kind": "profile", "path": "profile", "abs_path": str(trace_dir)},
+        )
+
+    def _report_differentiated(self, abstract_params, labels):
+        """How many parameters the step takes a gradient of, and how many
+        enter it as values only: an event and two gauges, at build."""
+        sizes = [int(x.size) for x in jax.tree.leaves(abstract_params)]
+        trainable = sum(
+            n for n, label in zip(sizes, jax.tree.leaves(labels)) if label == "train"
+        )
+        frozen = sum(sizes) - trainable
+        self.telemetry.gauge("train.params_differentiated").set(trainable)
+        self.telemetry.gauge("train.params_frozen").set(frozen)
+        self._event(
+            "differentiated",
+            {"trainable_params": trainable, "frozen_params": frozen},
         )
 
     def _init_throughput_facts(self):

@@ -469,3 +469,242 @@ def test_data_model_shape_mismatch_is_clear():
     p2.model.config = {"input_dim": 784, "num_classes": 10, "hidden": [16]}
     p2.data = p2.data.model_copy(update={"name": "mnist", "config": {"flat": False}})
     Trainer(p2, mesh_axes={"data": 8})  # must not raise
+
+
+# --------------------------------------------------------------------------
+# A LoRA step differentiates its adapters only (PR 28). Widths chosen so that
+# tokens (2 x 24 = 48), dim (64), hidden_dim (96), the KV width (2 x 16 = 32),
+# the vocabulary (160) and the rank (4) all differ: a product's output shape
+# then says whose weight gradient it is.
+
+
+def lora_program(lora=True, rows=2, **train_overrides):
+    cfg = {
+        "dim": 64, "n_layers": 2, "n_heads": 4, "n_kv_heads": 2,
+        "hidden_dim": 96, "vocab_size": 160, "seq_len": 24,
+        "fused_lm_loss": True,
+    }
+    if lora:
+        cfg["lora"] = {
+            "rank": 4, "alpha": 8,
+            "targets": ["q_proj", "k_proj", "v_proj", "o_proj"],
+        }
+    train = {"steps": 3, "logEvery": 1, "precision": "float32", "seed": 0}
+    train.update(train_overrides)
+    return V1Program.model_validate(
+        {
+            "model": {"name": "transformer_lm", "config": cfg},
+            "data": {
+                "name": "synthetic_text", "batchSize": rows,
+                "config": {"seq_len": 24, "vocab_size": 160},
+            },
+            "optimizer": {"name": "adamw", "learningRate": 0.01},
+            "train": train,
+        }
+    )
+
+
+def _lora_trainer(**kw):
+    events = kw.pop("events", None)
+    return Trainer(
+        lora_program(**kw),
+        devices=jax.devices()[:1],
+        event_fn=(lambda kind, body: events.append((kind, body)))
+        if events is not None
+        else None,
+    )
+
+
+def _batches(trainer, n):
+    it = iter(trainer.data.iterator)
+    return [jax.device_put(next(it), trainer.b_shard) for _ in range(n)]
+
+
+def _leaves_by_path(tree):
+    from polyaxon_tpu.parallel.sharding import _path_str
+
+    return {
+        _path_str(p): np.asarray(x)
+        for p, x in jax.tree_util.tree_flatten_with_path(jax.device_get(tree))[0]
+    }
+
+
+def _is_adapter(path):
+    return path.endswith(("lora_a", "lora_b"))
+
+
+def _run_steps(trainer, batches):
+    metrics = []
+    for b in batches:
+        trainer.state, m = trainer.train_step(trainer.state, b)
+        metrics.append(jax.device_get(m))
+    return metrics
+
+
+def _product_shapes(trainer, batch):
+    """Output shapes of every dot and convolution of the compiled step."""
+    import re
+
+    text = trainer.train_step.lower(trainer.state, batch).compile().as_text()
+    return {
+        tuple(int(d) for d in dims.split(","))
+        for dims, _ in re.findall(
+            r"= \w+\[([\d,]+)\]\S* (dot|convolution)\(", text
+        )
+    }
+
+
+def _gradient_of_all(trainer):
+    """The step as it was built before PR 28: the loss differentiated with
+    respect to every leaf, the frozen leaves' gradients handed to the
+    optimizer's `set_to_zero`. Returns (params, opt_state, grads)."""
+    import optax
+
+    bundle = trainer.bundle
+
+    def step(params, opt_state, batch):
+        def loss_of(p):
+            features = bundle.module.apply(
+                {"params": p}, batch["inputs"], train=True, return_features=True,
+                rngs={"dropout": jax.random.PRNGKey(0)},  # rate 0: not drawn
+            )
+            return bundle.fused_loss(p, features, batch)
+
+        grads = jax.grad(loss_of)(params)
+        updates, opt_state = trainer.tx.update(grads, opt_state, params)
+        return optax.apply_updates(params, updates), opt_state, grads
+
+    return jax.jit(step)
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+def test_lora_step_has_no_product_shaped_like_a_frozen_kernel(remat):
+    t = _lora_trainer(remat=remat)
+    leaves = _leaves_by_path(t.state.params)
+    frozen = {
+        shape
+        for path, x in leaves.items()
+        if not _is_adapter(path) and x.ndim == 2
+        for shape in (x.shape, x.shape[::-1])
+    }
+    adapters = {x.shape for path, x in leaves.items() if _is_adapter(path)}
+    assert {(64, 64), (64, 32), (64, 96), (96, 64), (64, 160)} <= frozen
+    assert adapters == {(64, 4), (4, 64), (4, 32)}
+    products = _product_shapes(t, _batches(t, 1)[0])
+    assert not products & frozen, sorted(products & frozen)
+    assert adapters <= products  # the adapters' own weight gradients stay
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+def test_lora_steps_leave_frozen_leaves_bit_equal(remat):
+    t = _lora_trainer(remat=remat)
+    start = _leaves_by_path(t.state.params)
+    _run_steps(t, _batches(t, 3))
+    end = _leaves_by_path(t.state.params)
+    assert any(_is_adapter(p) for p in start)
+    for path, before in start.items():
+        if not _is_adapter(path):
+            np.testing.assert_array_equal(end[path], before, err_msg=path)
+        elif path.endswith("lora_b"):
+            assert np.abs(end[path] - before).max() > 0, path
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+def test_lora_adapters_match_a_step_that_differentiates_every_leaf(remat):
+    t = _lora_trainer(remat=remat)
+    batches = _batches(t, 3)
+    old_step = _gradient_of_all(t)
+    params, opt_state = t.state.params, t.state.opt_state
+    for b in batches:
+        params, opt_state, _ = old_step(params, opt_state, b)
+    want = _leaves_by_path(params)
+    _run_steps(t, batches)
+    got = _leaves_by_path(t.state.params)
+    for path in want:
+        if _is_adapter(path):
+            np.testing.assert_allclose(
+                got[path], want[path], rtol=1e-4, atol=1e-6, err_msg=path
+            )
+    # the moments sit where a checkpoint written before PR 28 has them
+    assert list(_leaves_by_path(t.state.opt_state)) == list(_leaves_by_path(opt_state))
+
+
+def test_lora_grad_norm_is_the_adapters_norm():
+    import optax
+
+    events = []
+    t = _lora_trainer(events=events)
+    (batch,) = _batches(t, 1)
+    _, _, grads = _gradient_of_all(t)(t.state.params, t.state.opt_state, batch)
+    grads = _leaves_by_path(grads)
+    adapters = {p: g for p, g in grads.items() if _is_adapter(p)}
+    (metrics,) = _run_steps(t, [batch])
+    np.testing.assert_allclose(
+        metrics["grad_norm"], optax.global_norm(adapters), rtol=1e-5
+    )
+    assert metrics["grad_norm"] < 0.9 * optax.global_norm(grads)
+    # and the run's log says how many parameters the step differentiates
+    sizes = {p: x.size for p, x in _leaves_by_path(t.state.params).items()}
+    n_train = sum(n for p, n in sizes.items() if _is_adapter(p))
+    assert ("differentiated", {
+        "trainable_params": n_train,
+        "frozen_params": sum(sizes.values()) - n_train,
+    }) in events
+    snap = t.telemetry.snapshot()
+    assert snap["train.params_differentiated"] == n_train
+    assert snap["train.params_frozen"] == sum(sizes.values()) - n_train
+
+
+def test_full_training_differentiates_every_leaf():
+    """No `trainable_patterns`: the branch the step took before PR 28."""
+    import optax
+
+    events = []
+    t = _lora_trainer(lora=False, events=events)
+    assert t._train_labels is None
+    (batch,) = _batches(t, 1)
+    start = _leaves_by_path(t.state.params)
+    _, _, grads = _gradient_of_all(t)(t.state.params, t.state.opt_state, batch)
+    # a kernel's weight gradient is a product of the compiled step here
+    assert {(64, 64), (64, 96)} & _product_shapes(t, batch)
+    (metrics,) = _run_steps(t, [batch])
+    np.testing.assert_allclose(
+        metrics["grad_norm"], optax.global_norm(_leaves_by_path(grads)), rtol=1e-5
+    )
+    end = _leaves_by_path(t.state.params)
+    for path, before in start.items():
+        assert np.abs(end[path] - before).max() > 0, path
+    moments = {
+        p: m for p, m in _leaves_by_path(t.state.opt_state).items() if "mu/" in p
+    }
+    assert len(moments) == len(start)
+    for path, m in moments.items():
+        assert np.abs(m).max() > 0, path
+    n = sum(x.size for x in start.values())
+    assert ("differentiated", {"trainable_params": n, "frozen_params": 0}) in events
+
+
+def test_lora_grad_accum_matches_the_doubled_batch():
+    whole = _lora_trainer(rows=4)
+    halves = _lora_trainer(rows=4, gradAccum=2)
+    assert halves.grad_accum == 2 and whole.grad_accum == 1
+    batches = _batches(whole, 3)
+    m_whole = _run_steps(whole, batches)
+    m_halves = _run_steps(halves, batches)
+    np.testing.assert_allclose(
+        [m["loss"] for m in m_halves], [m["loss"] for m in m_whole], rtol=1e-5
+    )
+    np.testing.assert_allclose(
+        m_halves[0]["grad_norm"], m_whole[0]["grad_norm"], rtol=1e-4
+    )
+    want, got = _leaves_by_path(whole.state.params), _leaves_by_path(halves.state.params)
+    for path in want:
+        if _is_adapter(path):
+            np.testing.assert_allclose(
+                got[path], want[path], rtol=1e-4, atol=1e-6, err_msg=path
+            )
+        else:
+            np.testing.assert_array_equal(got[path], want[path], err_msg=path)
+    # the accumulated gradient holds adapters only: no buffer of a frozen
+    # kernel's shape is carried through the microbatch loop
+    assert not _product_shapes(halves, batches[0]) & {(64, 64), (64, 96), (96, 64)}
