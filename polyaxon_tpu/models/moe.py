@@ -1,96 +1,136 @@
-"""Mixture-of-Experts FFN with expert-axis parallelism.
+"""Mixture-of-Experts FFN: top-k routing over all the experts, grouped
+products over the experts held here, nothing dropped.
 
-Reference parity: EP is absent upstream (SURVEY.md §2 parallelism census —
-an obligation for the rebuild). TPU-shaped Switch/GShard design:
+One routed layer for both uses:
 
-- Expert weights carry a leading E dim sharded over the mesh `expert` axis
-  (sharding rules in transformer.py); the dispatch/combine einsums then
-  partition into all-to-alls by GSPMD — no hand-written collectives.
-- Top-1 (switch) routing with capacity factor: static shapes everywhere
-  (one-hot dispatch masks, capacity-clipped cumsum positions), so XLA can
-  tile the expert matmuls on the MXU with no dynamic gather.
-- Router logits/probs in f32; load-balancing aux loss sown into the
-  `losses` collection — the trainer adds every entry there to the loss
-  (ModelBundle.aux_losses).
-- Overflow tokens (beyond capacity) pass through the residual unchanged —
-  the standard switch-transformer behavior.
+- the whole layer on one process or mesh (`held == n_experts`): the stacked
+  expert kernels carry a leading expert dim that `MOE_RULES` shard over the
+  mesh `expert` axis;
+- one chip's share of an expert-parallel deployment (`held < n_experts`): the
+  router keeps its published width, the top-k and their weights are the
+  published ones, and this process computes the part of the result that
+  experts `[offset, offset + held)` give. What the absent experts would add is
+  left out; no code stands in for the other chips or their exchange.
+
+How: router logits and softmax in f32; the k largest of all `n_experts`;
+`w = p / sum of the k` where `norm_topk`, times `routed_scale`. The
+assignments to held experts are ordered by expert (one stable argsort) into a
+buffer of static length, their tokens' rows gathered, the three SwiGLU
+products taken as grouped products over the held experts
+(`jax.lax.ragged_dot`: on the TPU one grouped-matmul kernel, `ragged-dot` in a
+device trace; no `[B,S,E,C]` mask, no per-expert capacity), and the results
+added back to their tokens with `w`, in f32.
+
+The buffer holds `buffer_factor` times the expected count of local
+assignments and never more than the worst case (`tokens * min(top_k, held)`);
+with every expert held and top-1 it is the worst case. What does not fit is
+counted, not hidden: the layer sows `overflow` into `moe_stats`, with
+`assignments_local` and `load_max_over_mean`, and the Trainer stops on a
+non-zero reading.
+
+The Switch load-balancing loss (`aux_weight * E * sum_e f_e p_e`) is sown
+into `losses` where `aux_weight > 0`; a frozen router asks for none.
 """
 
 from __future__ import annotations
+
+import math
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+_ROW_TILE = 512  # buffer rows are a multiple of it: whole tiles for the kernel
+
+
+def buffer_rows(tokens: int, top_k: int, held: int, n_experts: int,
+                factor: float) -> int:
+    """Rows of the routed layer's buffer for `tokens` tokens."""
+    worst = tokens * min(top_k, held)
+    expected = tokens * top_k * held / n_experts
+    rows = math.ceil(factor * expected / _ROW_TILE) * _ROW_TILE
+    return max(1, min(worst, rows))
+
 
 class MoEFeedForward(nn.Module):
     dim: int
-    ffn_dim: int
-    n_experts: int
-    capacity_factor: float = 1.25
-    router_noise: float = 0.0
+    ffn_dim: int  # one expert's width
+    n_experts: int  # the router's width: the published count
+    held: int = 0  # experts computed here; 0 = all
+    offset: int = 0  # the first of them
+    top_k: int = 1
+    routed_scale: float = 1.0
+    norm_topk: bool = False
     aux_weight: float = 0.01
+    buffer_factor: float = 2.0
+    router_noise: float = 0.0
 
     @nn.compact
     def __call__(self, x, *, train: bool = False):
         B, S, D = x.shape
-        E = self.n_experts
-        C = max(1, int(self.capacity_factor * S / E))  # per-group capacity
+        E, K = self.n_experts, self.top_k
+        held = self.held or E
+        T = B * S
+        m = x.reshape(T, D)
 
-        router = nn.Dense(E, use_bias=False, name="router")
-        logits = router(x).astype(jnp.float32)  # [B,S,E]
+        logits = nn.Dense(E, use_bias=False, name="router")(m).astype(jnp.float32)
         if train and self.router_noise > 0:
             rng = self.make_rng("dropout")
             logits = logits + self.router_noise * jax.random.normal(
                 rng, logits.shape, jnp.float32
             )
-        probs = jax.nn.softmax(logits, axis=-1)
-        expert_idx = jnp.argmax(probs, axis=-1)  # [B,S]
-        onehot = jax.nn.one_hot(expert_idx, E, dtype=jnp.float32)  # [B,S,E]
-        gate = (probs * onehot).sum(-1)  # [B,S] chosen-expert prob
+        probs = jax.nn.softmax(logits, axis=-1)  # [T, E]
+        top_p, top_e = jax.lax.top_k(probs, K)  # [T, K]
+        weight = top_p / jnp.sum(top_p, -1, keepdims=True) if self.norm_topk else top_p
+        weight = weight * self.routed_scale
 
-        # load-balancing aux loss (Switch eq. 4): E * Σ_e f_e · p_e
-        density = onehot.mean(axis=(0, 1))          # fraction routed to e
-        density_proxy = probs.mean(axis=(0, 1))     # mean router prob for e
-        aux = E * jnp.sum(density * density_proxy)
-        self.sow("losses", "moe_aux", self.aux_weight * aux)
+        if self.aux_weight > 0:
+            # Switch eq. 4 over the k choices: E * sum_e f_e * p_e
+            chosen = jnp.zeros((E,), jnp.float32).at[top_e.reshape(-1)].add(1.0)
+            aux = E * jnp.sum(chosen / (T * K) * probs.mean(axis=0))
+            self.sow("losses", "moe_aux", self.aux_weight * aux)
 
-        # capacity: position of each token within its expert's queue
-        position = (jnp.cumsum(onehot, axis=1) - 1.0) * onehot  # [B,S,E]
-        keep = (position < C).astype(jnp.float32) * onehot
-        pos_clipped = jnp.minimum(position, C - 1).astype(jnp.int32)
-        # dispatch mask [B,S,E,C]
-        dispatch = keep[..., None] * jax.nn.one_hot(pos_clipped, C, dtype=jnp.float32)
-        combine = dispatch * gate[:, :, None, None]
+        # ---- the assignments to experts held here, ordered by expert
+        local = top_e - self.offset
+        local = jnp.where((local >= 0) & (local < held), local, held)  # held = elsewhere
+        flat = local.reshape(T * K)
+        rows = buffer_rows(T, K, held, E, self.buffer_factor)
+        order = jnp.argsort(flat, stable=True)
+        by_expert = flat[order]  # ascending; `held` marks the ones elsewhere
+        # where each held expert's run starts in the order: its count, with
+        # no scatter over the assignments
+        starts = jnp.searchsorted(by_expert, jnp.arange(held + 1), side="left")
+        counts = jnp.diff(starts).astype(jnp.int32)
+        order = order[:rows]
+        token = order // K
+        here = by_expert[:rows] < held
+        # what fits: the buffer cuts the last experts' tails, never a middle
+        ends = jnp.minimum(jnp.cumsum(counts), rows)
+        sizes = jnp.diff(ends, prepend=0)
+        total = jnp.sum(counts)
+        self.sow("moe_stats", "assignments_local", total)
+        self.sow(
+            "moe_stats", "load_max_over_mean",
+            jnp.max(counts) * held / jnp.maximum(total, 1),
+        )
+        self.sow("moe_stats", "overflow", total - ends[-1])
 
-        # route tokens to expert buffers: [E, B, C, D]
-        expert_in = jnp.einsum("bsec,bsd->ebcd", dispatch.astype(x.dtype), x)
-
-        # expert FFN (SwiGLU) with stacked weights [E, ...]
-        def ffn(inputs):  # [E,B,C,D]
-            wg = self.param(
-                "gate_kernel",
-                nn.initializers.lecun_normal(batch_axis=(0,)),
-                (E, D, self.ffn_dim),
-            )
-            wu = self.param(
-                "up_kernel",
-                nn.initializers.lecun_normal(batch_axis=(0,)),
-                (E, D, self.ffn_dim),
-            )
-            wd = self.param(
-                "down_kernel",
-                nn.initializers.lecun_normal(batch_axis=(0,)),
-                (E, self.ffn_dim, D),
-            )
-            h = nn.silu(jnp.einsum("ebcd,edf->ebcf", inputs, wg.astype(inputs.dtype)))
-            h = h * jnp.einsum("ebcd,edf->ebcf", inputs, wu.astype(inputs.dtype))
-            return jnp.einsum("ebcf,efd->ebcd", h, wd.astype(inputs.dtype))
-
-        expert_out = ffn(expert_in)
-        # combine back: overflow tokens (empty combine row) get zeros, so the
-        # residual connection outside passes them through unchanged
-        return jnp.einsum("ebcd,bsec->bsd", expert_out, combine.astype(x.dtype))
+        # ---- grouped SwiGLU over the held experts
+        init = nn.initializers.lecun_normal(batch_axis=(0,))
+        wg = self.param("gate_kernel", init, (held, D, self.ffn_dim))
+        wu = self.param("up_kernel", init, (held, D, self.ffn_dim))
+        wd = self.param("down_kernel", init, (held, self.ffn_dim, D))
+        # rows past the groups are no expert's: they go in as zeros and come
+        # out as zeros whatever the kernel leaves there, forward and backward
+        picked = jnp.where(here[:, None], m[token], 0)  # [rows, D]
+        dt = picked.dtype
+        h = nn.silu(jax.lax.ragged_dot(picked, wg.astype(dt), sizes))
+        h = h * jax.lax.ragged_dot(picked, wu.astype(dt), sizes)
+        y = jax.lax.ragged_dot(h, wd.astype(dt), sizes).astype(jnp.float32)
+        w_row = weight.reshape(T * K)[order]
+        y = jnp.where(here[:, None], y * w_row[:, None], 0.0)
+        out = jnp.zeros((T, D), jnp.float32).at[token].add(y)
+        return out.astype(x.dtype).reshape(B, S, D)
 
 
 # sharding rules for stacked expert weights: expert dim over `expert` axis,

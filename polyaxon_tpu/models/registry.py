@@ -51,6 +51,10 @@ class ModelBundle:
     # True if the module sows auxiliary losses into the `losses` collection
     # (e.g. MoE load balancing); the trainer adds them to the total loss.
     aux_losses: bool = False
+    # Per-step readings the module sows into the `moe_stats` collection
+    # (routed layers: local assignments, load, overflow), reduced over the
+    # layers to a flat dict; the trainer reports them as step metrics.
+    step_metrics: Optional[Callable] = None
     # Optional fused head+loss: (params, features, batch) -> scalar. When
     # set, the trainer applies the module with return_features=True and
     # computes the loss from pre-head features — the [B, S, V] logits

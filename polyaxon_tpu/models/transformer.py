@@ -16,6 +16,11 @@ model is in-repo and TPU-shaped:
 - Attention backend selectable: `xla` (einsum softmax, fine for short seq),
   `flash` (Pallas blockwise kernel, ops/flash_attention.py), `ring`, `ulysses`
   (context-parallel blockwise over the `context` axis, parallel/ring.py).
+- Layers that differ (`layers`: heads, window, rotary table, dense or routed
+  MLP by layer), a head width of its own (`head_dim`), a per-head output
+  gate (`attn_gate`) and top-k routing over the experts held here
+  (models/moe.py): what a published config of mixed window/full attention
+  and sparse experts asks of one decoder. Unrolled layers only.
 - Optional LoRA (`lora_rank > 0`): frozen base kernels + trainable A/B
   adapters on all projections; the trainer masks the optimizer to adapter
   params via `ModelBundle.trainable_patterns`.
@@ -35,12 +40,57 @@ from .registry import ModelBundle, i32_tokens, register
 
 
 @dataclasses.dataclass(frozen=True)
+class RopeSpec:
+    """One rotary table. `rotary_factor` is the share of a head's width that
+    rotates (the first `rotary_factor * head_dim` of it; the rest passes).
+    `yarn_factor` > 0 blends each frequency between theta^(-2i/d) and that
+    over `yarn_factor`, by the linear ramp between the correction dims of
+    `beta_fast` and `beta_slow` over `original_len` positions (YaRN as
+    `transformers` computes it); cos and sin are multiplied by
+    `attention_factor`."""
+
+    theta: float = 10000.0
+    rotary_factor: float = 1.0
+    yarn_factor: float = 0.0
+    original_len: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerSpec:
+    """What one layer is, where layers differ: its query heads, its window
+    (0 = causal attention over the whole sequence), its rotary table, and
+    whether its MLP is routed or dense."""
+
+    n_heads: int
+    window: int = 0
+    rope: RopeSpec = RopeSpec()
+    routed: bool = False
+
+    def describe(self, cfg: "TransformerConfig") -> dict:
+        return {
+            "kind": "sliding" if self.window else "full",
+            "window": self.window,
+            "heads": self.n_heads,
+            "rope": "yarn" if self.rope.yarn_factor else "default",
+            "rope_theta": self.rope.theta,
+            "mlp": "routed" if self.routed else "dense",
+            "experts_held": cfg.held if self.routed else 0,
+            "experts_published": cfg.n_experts if self.routed else 0,
+        }
+
+
+@dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     vocab_size: int = 32000
     dim: int = 512
     n_layers: int = 4
     n_heads: int = 8
     n_kv_heads: int = 8
+    # width of one head; None = dim // n_heads (read it as `head_size`)
+    head_dim: Optional[int] = None
     hidden_dim: Optional[int] = None  # default 8/3 * dim rounded up to 128
     seq_len: int = 512
     rope_theta: float = 10000.0
@@ -69,9 +119,34 @@ class TransformerConfig:
     adapter_slots: int = 0
     tie_embeddings: bool = False
     scan_layers: bool = False
-    # MoE: replace the dense FFN with n_experts switch-routed experts
+    # layers that differ from one another: one LayerSpec a layer, built by
+    # _make_config from published-style keys (`layer_types`,
+    # `num_attention_heads_per_layer`, `sliding_window`, `rope_parameters`,
+    # `mlp_only_layers`). Empty = n_layers copies of the layer the fields
+    # above describe.
+    layers: tuple = ()
+    # per-head output gate: sigmoid of a bias-free linear map of the
+    # attention block's input, one scalar a head and token, on the
+    # attention output before o_proj
+    attn_gate: bool = False
+    # MoE (models/moe.py): the router's width (the PUBLISHED count of
+    # experts); 0 = dense MLPs. This process holds experts
+    # [expert_offset, expert_offset + experts_held) of each routed layer
+    # (experts_held 0 = all of them) and computes their part of the result.
     n_experts: int = 0
-    capacity_factor: float = 1.25
+    experts_held: int = 0
+    expert_offset: int = 0
+    experts_per_token: int = 1
+    routed_scale: float = 1.0
+    norm_topk: bool = False
+    expert_dim: Optional[int] = None  # an expert's width; None = ffn_dim
+    shared_expert_dim: int = 0  # > 0: a dense SwiGLU beside the routed ones
+    moe_aux_weight: float = 0.01  # Switch load-balancing loss; 0 = none
+    # rows of the routed layer's buffer, over the expected count of local
+    # assignments (tokens x experts_per_token x held / n_experts); never
+    # more than the worst case. What does not fit is counted (moe.overflow)
+    # and the Trainer stops on it.
+    expert_buffer_factor: float = 2.0
     # pipeline parallelism: stage count (mesh `pipeline` axis size must match)
     pipeline_stages: int = 0
     pipeline_microbatches: int = 0
@@ -90,8 +165,29 @@ class TransformerConfig:
     fused_loss_chunk: int = 8192
 
     @property
-    def head_dim(self) -> int:
-        return self.dim // self.n_heads
+    def head_size(self) -> int:
+        return self.head_dim or self.dim // self.n_heads
+
+    @property
+    def held(self) -> int:
+        return self.experts_held or self.n_experts
+
+    def layer(self, index: Optional[int]) -> LayerSpec:
+        """The spec of layer `index`; without `layers` every layer is the
+        one the uniform fields describe (and a scanned or pipelined block,
+        which has no index, can ask for it)."""
+        if not self.layers:
+            return LayerSpec(
+                n_heads=self.n_heads,
+                rope=RopeSpec(theta=self.rope_theta),
+                routed=self.n_experts > 0,
+            )
+        if index is None:
+            raise ValueError(
+                "layers that differ (heads, window, rope or MLP by layer) "
+                "have no scanned or pipelined form: each needs its own index"
+            )
+        return self.layers[index]
 
     @property
     def ffn_dim(self) -> int:
@@ -110,13 +206,55 @@ def rope_table(seq_len: int, head_dim: int, theta: float):
     return np.cos(ang), np.sin(ang)
 
 
+def rope_table_for(seq_len: int, head_dim: int, spec: RopeSpec):
+    """cos/sin [seq, rot/2] of a RopeSpec, rot = rotary_factor * head_dim.
+    The plain full-width case is `rope_table` itself."""
+    rot = int(head_dim * spec.rotary_factor)
+    if not spec.yarn_factor:
+        cos, sin = rope_table(seq_len, rot, spec.theta)
+    else:
+        half = rot // 2
+        pos_freqs = spec.theta ** (np.arange(0, half, dtype=np.float64) / half)
+        extrapolation, interpolation = 1.0 / pos_freqs, 1.0 / (spec.yarn_factor * pos_freqs)
+
+        def correction_dim(rotations):
+            return (
+                rot * np.log(spec.original_len / (rotations * 2 * np.pi))
+            ) / (2 * np.log(spec.theta))
+
+        low = max(np.floor(correction_dim(spec.beta_fast)), 0)
+        high = min(np.ceil(correction_dim(spec.beta_slow)), rot - 1)
+        if low == high:
+            high += 0.001
+        ramp = np.clip((np.arange(half, dtype=np.float64) - low) / (high - low), 0, 1)
+        freqs = (interpolation * ramp + extrapolation * (1 - ramp)).astype(np.float32)
+        ang = np.outer(np.arange(seq_len, dtype=np.float32), freqs)
+        cos, sin = np.cos(ang), np.sin(ang)
+    if spec.attention_factor != 1.0:
+        f = np.float32(spec.attention_factor)
+        cos, sin = cos * f, sin * f
+    return cos, sin
+
+
+def _rotate(x, c, s):
+    """(first-half, second-half) pairs of the leading 2 * c.shape[-1] of
+    each head rotated; what lies beyond them (partial rotary) passes."""
+    rot = 2 * c.shape[-1]
+    if rot == x.shape[-1]:
+        x1, x2 = jnp.split(x, 2, axis=-1)
+        return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1).astype(x.dtype)
+    x1, x2, rest = x[..., : rot // 2], x[..., rot // 2 : rot], x[..., rot:]
+    return jnp.concatenate(
+        [x1 * c - x2 * s, x2 * c + x1 * s, rest], axis=-1
+    ).astype(x.dtype)
+
+
 def apply_rope(x: jnp.ndarray, cos, sin, offset: int = 0):
     """x: [B, S, H, D]. Rotates the (first-half, second-half) pairs."""
     seq = x.shape[1]
     c = jax.lax.dynamic_slice_in_dim(cos, offset, seq)[None, :, None, :]
     s = jax.lax.dynamic_slice_in_dim(sin, offset, seq)[None, :, None, :]
-    x1, x2 = jnp.split(x, 2, axis=-1)
-    return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1).astype(x.dtype)
+    return _rotate(x, c, s)
 
 
 def apply_rope_at(x: jnp.ndarray, cos, sin, positions: jnp.ndarray):
@@ -127,8 +265,7 @@ def apply_rope_at(x: jnp.ndarray, cos, sin, positions: jnp.ndarray):
     is a gather instead of apply_rope's shared slice."""
     c = jnp.take(cos, positions, axis=0)[:, :, None, :]  # [B, S, 1, half]
     s = jnp.take(sin, positions, axis=0)[:, :, None, :]
-    x1, x2 = jnp.split(x, 2, axis=-1)
-    return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1).astype(x.dtype)
+    return _rotate(x, c, s)
 
 
 class RMSNorm(nn.Module):
@@ -242,6 +379,7 @@ def _run_proj(cfg: TransformerConfig, features: int, name: str, x,
 
 class Attention(nn.Module):
     cfg: TransformerConfig
+    spec: Optional[LayerSpec] = None  # None = the uniform layer of cfg
 
     @nn.compact
     def __call__(
@@ -265,9 +403,19 @@ class Attention(nn.Module):
         # None = slot 0 (the base/resident adapter) for every row
     ):
         cfg = self.cfg
+        spec = self.spec or cfg.layer(None)
         B, S, _ = x.shape
-        hd, nh, nkv = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
+        hd, nh, nkv = cfg.head_size, spec.n_heads, cfg.n_kv_heads
+        window = spec.window or None
         from ..parallel.sharding import constrain
+
+        gate = None
+        if cfg.attn_gate:
+            # one scalar a head and token, from the block's own input; a
+            # plain Dense (never a LoRA target)
+            gate = nn.sigmoid(
+                nn.Dense(nh, use_bias=False, name="gate_proj")(x).astype(jnp.float32)
+            )[..., None].astype(x.dtype)
 
         q = _run_proj(cfg, nh * hd, "q_proj", x, adapter_ix).reshape(B, S, nh, hd)
         k = _run_proj(cfg, nkv * hd, "k_proj", x, adapter_ix).reshape(B, S, nkv, hd)
@@ -276,7 +424,7 @@ class Attention(nn.Module):
         q = constrain(q, BATCH, "context", "model", None)
         k = constrain(k, BATCH, "context", "model", None)
         v = constrain(v, BATCH, "context", "model", None)
-        cos_np, sin_np = rope_table(cfg.seq_len, hd, cfg.rope_theta)
+        cos_np, sin_np = rope_table_for(cfg.seq_len, hd, spec.rope)
         cos, sin = jnp.asarray(cos_np), jnp.asarray(sin_np)
 
         if decode:
@@ -301,6 +449,12 @@ class Attention(nn.Module):
             #    score -1e30, whose exp underflows to exact 0.0).
             is_step = self.has_variable("cache", "cached_key")
             paged = pages is not None
+            if window and (paged or prefix_len or prefix_lens is not None):
+                raise NotImplementedError(
+                    "a windowed layer decodes over the dense cache only: the "
+                    "paged pool and the shared-prefix layouts keep every slot "
+                    "of every layer and their masks know no window"
+                )
             kv_int8 = paged and getattr(kv_layout, "kv_quant", "none") == "int8"
             if paged:
                 pt_sz, pool_sz = kv_layout.page_tokens, kv_layout.pool_pages
@@ -495,6 +649,13 @@ class Attention(nn.Module):
                 live = (
                     jnp.arange(win)[None, None, :] <= row_slots[:, :, None]
                 )
+                if window:
+                    # slots and positions differ by the row's pad alone, so
+                    # a distance in slots is a distance in positions
+                    live = live & (
+                        jnp.arange(win)[None, None, :]
+                        > row_slots[:, :, None] - window
+                    )
                 mask = live[:, None, :, :]
                 if pad is not None:
                     if prefix_lens is not None:
@@ -525,7 +686,10 @@ class Attention(nn.Module):
                     "bkgqs,bskd->bqkgd",
                     probs.reshape(B, nkv, G, S, win),
                     v_all,
-                ).reshape(B, S, nh * hd)
+                ).reshape(B, S, nh, hd)
+                if gate is not None:
+                    out = out * gate
+                out = out.reshape(B, S, nh * hd)
                 return _run_proj(cfg, cfg.dim, "o_proj", out, adapter_ix)
             # cache creation pass (first mutable apply): fall through to the
             # ordinary full-sequence attention so output shapes are normal
@@ -542,8 +706,10 @@ class Attention(nn.Module):
 
         out = dot_product_attention(
             q, k, v, causal=True, backend=cfg.attention,
-            block_kv=cfg.attention_block,
+            block_kv=cfg.attention_block, window=window,
         )
+        if gate is not None:
+            out = out * gate
         out = constrain(out.reshape(B, S, nh * hd), BATCH, "context", "model")
         return _run_proj(cfg, cfg.dim, "o_proj", out, adapter_ix)
 
@@ -556,14 +722,16 @@ BATCH = ("batch", "data", "fsdp")
 
 class FeedForward(nn.Module):
     cfg: TransformerConfig
+    width: Optional[int] = None  # None = cfg.ffn_dim (a shared expert has its own)
 
     @nn.compact
     def __call__(self, x, adapter_ix=None):
         from ..parallel.sharding import constrain
 
         cfg = self.cfg
-        gate = _run_proj(cfg, cfg.ffn_dim, "gate_proj", x, adapter_ix)
-        up = _run_proj(cfg, cfg.ffn_dim, "up_proj", x, adapter_ix)
+        width = self.width or cfg.ffn_dim
+        gate = _run_proj(cfg, width, "gate_proj", x, adapter_ix)
+        up = _run_proj(cfg, width, "up_proj", x, adapter_ix)
         # column-parallel output: hidden dim lives on the model axis until
         # the row-parallel down projection reduces it
         h = constrain(nn.silu(gate) * up, BATCH, "context", "model")
@@ -579,6 +747,7 @@ class Block(nn.Module):
     # table / write position arrive as call arguments
     kv_layout: Optional[Any] = None
     prefix_len: int = 0
+    index: Optional[int] = None  # which layer (only layers that differ ask)
 
     @nn.compact
     def __call__(self, x, pad=None, pages=None, pos=None, prefix_lens=None,
@@ -586,8 +755,9 @@ class Block(nn.Module):
         from ..parallel.sharding import constrain
 
         cfg = self.cfg
+        spec = cfg.layer(self.index)
         x = constrain(x, BATCH, "context", None)
-        h = Attention(cfg, name="attention")(
+        h = Attention(cfg, spec, name="attention")(
             RMSNorm(cfg.norm_eps, name="attention_norm")(x),
             train=self.train,
             decode=self.decode,
@@ -602,16 +772,28 @@ class Block(nn.Module):
         if cfg.dropout_rate:
             h = nn.Dropout(cfg.dropout_rate, deterministic=not self.train)(h)
         x = x + h
-        if cfg.n_experts > 0:
+        if spec.routed:
             from .moe import MoEFeedForward
 
+            normed = RMSNorm(cfg.norm_eps, name="mlp_norm")(x)
             h = MoEFeedForward(
                 cfg.dim,
-                cfg.ffn_dim,
+                cfg.expert_dim or cfg.ffn_dim,
                 cfg.n_experts,
-                capacity_factor=cfg.capacity_factor,
+                held=cfg.held,
+                offset=cfg.expert_offset,
+                top_k=cfg.experts_per_token,
+                routed_scale=cfg.routed_scale,
+                norm_topk=cfg.norm_topk,
+                aux_weight=cfg.moe_aux_weight,
+                buffer_factor=cfg.expert_buffer_factor,
                 name="moe",
-            )(RMSNorm(cfg.norm_eps, name="mlp_norm")(x), train=self.train)
+            )(normed, train=self.train)
+            if cfg.shared_expert_dim:
+                # every chip of the deployment computes it alike, ungated
+                h = h + FeedForward(
+                    cfg, cfg.shared_expert_dim, name="shared_expert"
+                )(normed, adapter_ix)
         else:
             h = FeedForward(cfg, name="mlp")(
                 RMSNorm(cfg.norm_eps, name="mlp_norm")(x), adapter_ix
@@ -839,6 +1021,7 @@ class Transformer(nn.Module):
                 x = Block(
                     cfg, train, decode,
                     kv_layout=kv_layout, prefix_len=prefix_len,
+                    index=i,
                     name=f"layer_{i}",
                 )(x, pad=pad, pages=pages, pos=pos, prefix_lens=prefix_lens,
                   adapter_ix=adapter_ix)
@@ -907,8 +1090,81 @@ PRESETS: dict[str, dict] = {
 }
 
 
+def _rope_spec(published: dict) -> RopeSpec:
+    """A RopeSpec from one published `rope_parameters` group."""
+    kind = published.get("rope_type", "default")
+    base = dict(
+        theta=float(published.get("rope_theta", 10000.0)),
+        rotary_factor=float(published.get("partial_rotary_factor", 1.0)),
+    )
+    if kind == "default":
+        return RopeSpec(**base)
+    if kind != "yarn":
+        raise ValueError(f"unknown rope_type {kind!r} (known: default, yarn)")
+    factor = float(published["factor"])
+    return RopeSpec(
+        **base,
+        yarn_factor=factor,
+        original_len=int(published["original_max_position_embeddings"]),
+        beta_fast=float(published.get("beta_fast", 32.0)),
+        beta_slow=float(published.get("beta_slow", 1.0)),
+        attention_factor=float(
+            published.get("attention_factor") or 0.1 * np.log(factor) + 1.0
+        ),
+    )
+
+
+_LAYER_KEYS = (
+    "layer_types", "num_attention_heads_per_layer", "sliding_window",
+    "rope_parameters", "mlp_only_layers",
+)
+
+
+def _layer_specs(pub: dict, base: dict) -> tuple:
+    """One LayerSpec a layer from the published-style keys in `pub`; what a
+    key leaves unsaid is the uniform layer of `base` (the other fields)."""
+    n = int(base.get("n_layers", TransformerConfig.n_layers))
+
+    def per_layer(key, default):
+        vals = pub.get(key)
+        if vals is None:
+            return [default] * n
+        if len(vals) < n:
+            raise ValueError(f"{key} names {len(vals)} layers, n_layers is {n}")
+        return list(vals[:n])  # a published list may run to the uncut depth
+
+    kinds = per_layer("layer_types", "full_attention")
+    heads = per_layer(
+        "num_attention_heads_per_layer",
+        int(base.get("n_heads", TransformerConfig.n_heads)),
+    )
+    window = int(pub.get("sliding_window") or 0)
+    ropes = pub.get("rope_parameters") or {}
+    if ropes and not all(isinstance(v, dict) for v in ropes.values()):
+        ropes = {kind: ropes for kind in set(kinds)}  # one group for all
+    dense = set(pub.get("mlp_only_layers") or ())
+    routed = int(base.get("n_experts") or 0) > 0
+    specs = []
+    for i, (kind, h) in enumerate(zip(kinds, heads)):
+        if kind not in ("full_attention", "sliding_attention"):
+            raise ValueError(f"unknown layer type {kind!r} at layer {i}")
+        if kind == "sliding_attention" and window < 1:
+            raise ValueError("sliding_attention layers need sliding_window")
+        specs.append(LayerSpec(
+            n_heads=int(h),
+            window=window if kind == "sliding_attention" else 0,
+            rope=(
+                _rope_spec(ropes[kind]) if kind in ropes
+                else RopeSpec(theta=float(base.get("rope_theta", 10000.0)))
+            ),
+            routed=routed and i not in dense,
+        ))
+    return tuple(specs)
+
+
 def _make_config(config: dict) -> TransformerConfig:
     config = dict(config)
+    published = {k: config.pop(k) for k in _LAYER_KEYS if k in config}
     # Polyaxonfile aliases (examples/llama_lora.yaml): variant → preset,
     # max_len → seq_len, lora: {rank, alpha, targets} → lora_* fields
     variant = config.pop("variant", None)
@@ -938,8 +1194,34 @@ def _make_config(config: dict) -> TransformerConfig:
         raise ValueError(f"unknown preset {preset!r}; known: {sorted(PRESETS)}")
     base: dict = dict(PRESETS.get(preset, {}))
     base.update({k: v for k, v in config.items() if v is not None})
+    if published:
+        base["layers"] = _layer_specs(published, base)
     fields = {f.name for f in dataclasses.fields(TransformerConfig)}
     cfg = TransformerConfig(**{k: v for k, v in base.items() if k in fields})
+    if cfg.layers and (cfg.scan_layers or cfg.pipeline_stages > 1):
+        raise ValueError(
+            "scan_layers and pipeline_stages stack one block's parameters "
+            "along a layer axis and cannot hold layers that differ (heads, "
+            "window, rope or dense/routed MLP by layer: layer_types, "
+            "num_attention_heads_per_layer, rope_parameters, mlp_only_layers)"
+        )
+    for spec in cfg.layers or (cfg.layer(None),):
+        if spec.n_heads % cfg.n_kv_heads:
+            raise ValueError(
+                f"{spec.n_heads} query heads do not divide over "
+                f"{cfg.n_kv_heads} kv heads"
+            )
+    if cfg.n_experts > 0:
+        if not 0 < cfg.experts_per_token <= cfg.n_experts:
+            raise ValueError(
+                f"experts_per_token {cfg.experts_per_token} of "
+                f"{cfg.n_experts} experts"
+            )
+        if cfg.expert_offset < 0 or cfg.expert_offset + cfg.held > cfg.n_experts:
+            raise ValueError(
+                f"experts [{cfg.expert_offset}, {cfg.expert_offset + cfg.held}) "
+                f"are not among the router's {cfg.n_experts}"
+            )
     if cfg.pipeline_stages > 1:
         # the pipelined stack applies blocks functionally: no dropout rngs,
         # no mutable collections — reject rather than silently change the
@@ -953,6 +1235,25 @@ def _make_config(config: dict) -> TransformerConfig:
                 "pipelined stack"
             )
     return cfg
+
+
+def moe_step_metrics(sown) -> dict:
+    """What the routed layers sowed into `moe_stats` in one step, over the
+    layers: the mean count of assignments to experts held here, the fullest
+    held expert against the mean one (the worst layer's), and the
+    assignments that did not fit their buffer (0, or the Trainer stops)."""
+    from flax.traverse_util import flatten_dict
+
+    by_name: dict = {}
+    for path, sown_here in flatten_dict(sown).items():  # layer_i/moe/<name>: (value,)
+        by_name.setdefault(path[-1], []).extend(
+            jnp.asarray(v, jnp.float32).reshape(()) for v in sown_here
+        )
+    how = {"assignments_local": jnp.mean, "load_max_over_mean": jnp.max,
+           "overflow": jnp.sum}
+    return {
+        f"moe.{name}": how[name](jnp.stack(vals)) for name, vals in by_name.items()
+    }
 
 
 @register("transformer_lm")
@@ -1017,7 +1318,8 @@ def build_transformer(config: dict) -> ModelBundle:
         sharding_rules=rules,
         task="lm",
         trainable_patterns=trainable,
-        aux_losses=cfg.n_experts > 0,
+        aux_losses=cfg.n_experts > 0 and cfg.moe_aux_weight > 0,
+        step_metrics=moe_step_metrics if cfg.n_experts > 0 else None,
         fused_loss=fused,
     )
 

@@ -70,7 +70,7 @@ def resolve_auto_backend(
     return "flash" if flash_ok else "xla"
 
 
-def _flash_sharded(q, k, v, *, causal: bool, block_kv: int, mesh):
+def _flash_sharded(q, k, v, *, causal: bool, block_kv: int, mesh, window=None):
     """The Pallas flash kernel on a live multi-device mesh.
 
     The kernel has no GSPMD partitioning rule, so partition it manually:
@@ -108,7 +108,7 @@ def _flash_sharded(q, k, v, *, causal: bool, block_kv: int, mesh):
         head = "model"
     q_spec = P(batch or None, None, head, None)
     kv_spec = P(batch or None, None, head, None)
-    body = partial(flash_attention, causal=causal, block_kv=block_kv)
+    body = partial(flash_attention, causal=causal, block_kv=block_kv, window=window)
     fn = shard_map_nocheck(
         body,
         mesh=mesh,
@@ -119,9 +119,14 @@ def _flash_sharded(q, k, v, *, causal: bool, block_kv: int, mesh):
 
 
 def dot_product_attention(
-    q, k, v, *, causal: bool, backend: str = "xla", block_kv: int = 512
+    q, k, v, *, causal: bool, backend: str = "xla", block_kv: int = 512,
+    window: int | None = None,
 ):
     """q: [B, S, H, D]; k/v: [B, S, KV, D] with KV dividing H → [B, S, H, D].
+
+    `window` (causal only): query i attends keys j with 0 <= i - j <
+    window. The einsum masks; the flash kernel skips the kv blocks behind
+    the window; the context-parallel backends have no window and say so.
 
     GQA expansion happens HERE, per backend: the flash kernel consumes
     grouped kv natively (no repeated K/V in HBM); the einsum/ring/ulysses
@@ -132,6 +137,15 @@ def dot_product_attention(
         )
     if backend == "auto":
         backend = resolve_auto_backend(q.shape[1], block_kv, q.shape[-1])
+    if window is not None:
+        if not causal:
+            raise ValueError("a window needs causal attention")
+        if backend in ("ring", "ulysses"):
+            raise ValueError(
+                f"attention backend {backend!r} has no sliding window: the "
+                "context-parallel kernels attend the whole sequence (use "
+                "xla or flash for windowed layers)"
+            )
     # flash consumes grouped kv natively; ring rotates it and ulysses
     # scatters it at kv-head width (4x less fabric traffic at llama
     # ratios), both expanding internally only when shards don't divide.
@@ -148,9 +162,12 @@ def dot_product_attention(
         mesh = current_mesh()
         if mesh is not None and mesh.size > 1 and not constraints_suspended():
             return _flash_sharded(
-                q, k, v, causal=causal, block_kv=block_kv, mesh=mesh
+                q, k, v, causal=causal, block_kv=block_kv, mesh=mesh,
+                window=window,
             )
-        return flash_attention(q, k, v, causal=causal, block_kv=block_kv)
+        return flash_attention(
+            q, k, v, causal=causal, block_kv=block_kv, window=window
+        )
     if backend == "ring":
         from ..parallel.ring import ring_attention
 
@@ -168,6 +185,8 @@ def dot_product_attention(
     if causal:
         S = q.shape[1]
         mask = jnp.tril(jnp.ones((S, S), bool))
+        if window is not None:
+            mask = mask & ~jnp.tril(jnp.ones((S, S), bool), -int(window))
         scores = jnp.where(mask[None, None], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)

@@ -243,7 +243,15 @@ class Trainer:
                         "mesh declares an expert axis but the model has no "
                         "experts (set model.config.n_experts)"
                     )
-                check(exp, n_experts, "n_experts")
+                # the axis splits the stacked kernels, which hold the
+                # experts held here: all the router knows, or this
+                # process's share of them
+                held = getattr(cfg, "held", n_experts)
+                check(
+                    exp, held,
+                    "n_experts" if held == n_experts
+                    else f"experts_held (of {n_experts} published)",
+                )
         self._validate_data_shape()
 
     def _validate_data_shape(self):
@@ -311,6 +319,7 @@ class Trainer:
             )
             self._train_labels = labels
         self._report_differentiated(abstract_params, labels)
+        self._report_layers()
         self.p_shard = param_shardings(abstract_params, bundle.sharding_rules, mesh)
         e_shard = param_shardings(abstract_extra, bundle.sharding_rules, mesh)
         o_shard = _opt_state_shardings(self.tx, abstract_params, self.p_shard, mesh)
@@ -344,7 +353,12 @@ class Trainer:
         is_classification = bundle.task == "classification"
         seed = int(tspec.seed)
 
-        collections = list(mutable) + (["losses"] if bundle.aux_losses else [])
+        step_metrics = bundle.step_metrics
+        collections = (
+            list(mutable)
+            + (["losses"] if bundle.aux_losses else [])
+            + (["moe_stats"] if step_metrics is not None else [])
+        )
 
         fused_loss = bundle.fused_loss
         if fused_loss is not None and (tspec.loss or bundle.loss) not in (
@@ -368,7 +382,7 @@ class Trainer:
                 logits = bundle.module.apply(
                     variables, inputs, train=True, rngs=rngs, **apply_kw
                 )
-                return logits, {}, jnp.zeros((), jnp.float32)
+                return logits, {}, jnp.zeros((), jnp.float32), {}
             logits, updates = bundle.module.apply(
                 variables, inputs, train=True, rngs=rngs, mutable=collections,
                 **apply_kw
@@ -379,7 +393,11 @@ class Trainer:
                 (jnp.sum(jnp.asarray(v)) for v in jax.tree.leaves(sown)),
                 jnp.zeros((), jnp.float32),
             )
-            return logits, updates, aux
+            stats = (
+                step_metrics(updates.pop("moe_stats", {}))
+                if step_metrics is not None else {}
+            )
+            return logits, updates, aux, stats
 
         if use_remat or tspec.remat_policy:
             policies = {
@@ -447,8 +465,10 @@ class Trainer:
             )
 
         def grads_of(trained, frozen, extra, batch, rng):
-            """One microbatch: (loss, grads, new_extra, logits); `grads`
-            mirrors `trained`, the differentiated half of the parameters."""
+            """One microbatch: (loss, grads, new_extra, logits, stats);
+            `grads` mirrors `trained`, the differentiated half of the
+            parameters; `stats` is what the module sowed for the step's
+            metrics (routed layers), empty for most models."""
 
             def loss_of(t):
                 p = merge(t, frozen)
@@ -460,25 +480,27 @@ class Trainer:
                 inputs = batch["inputs"]
                 if jnp.issubdtype(inputs.dtype, jnp.floating):
                     inputs = inputs.astype(compute_dtype)
-                logits, new_extra, aux = apply(compute_params, extra, inputs, rng)
+                logits, new_extra, aux, stats = apply(
+                    compute_params, extra, inputs, rng
+                )
                 if fused_loss is not None:  # `logits` carries features
                     return (
                         fused_loss(compute_params, logits, batch) + aux,
-                        (logits, new_extra),
+                        (logits, new_extra, stats),
                     )
-                return loss_fn(logits, batch) + aux, (logits, new_extra)
+                return loss_fn(logits, batch) + aux, (logits, new_extra, stats)
 
-            (loss, (logits, new_extra)), grads = jax.value_and_grad(
+            (loss, (logits, new_extra, stats)), grads = jax.value_and_grad(
                 loss_of, has_aux=True
             )(trained)
-            return loss, grads, new_extra, logits
+            return loss, grads, new_extra, logits, stats
 
         def step_fn(state: TrainState, batch):
             rng = jax.random.fold_in(jax.random.PRNGKey(seed), state.step)
             trained, frozen = split(state.params)
 
             if grad_accum == 1:
-                loss, grads, new_extra, logits = grads_of(
+                loss, grads, new_extra, logits, stats = grads_of(
                     trained, frozen, state.extra, batch, rng
                 )
                 acc_metric = (
@@ -498,7 +520,9 @@ class Trainer:
 
                 def one(carry, mb):
                     extra_c, grads_c, loss_c, acc_c, i = carry
-                    loss, grads, new_extra, logits = grads_of(
+                    # the sown stats of a microbatch are not carried: a step
+                    # of several reports none
+                    loss, grads, new_extra, logits, _ = grads_of(
                         trained, frozen, extra_c, mb, jax.random.fold_in(rng, i)
                     )
                     grads = _cast_floats(grads, param_dtype)
@@ -531,6 +555,7 @@ class Trainer:
                 grads = jax.tree.map(lambda g: g / grad_accum, grads)
                 loss = loss / grad_accum
                 acc_metric = acc_sum / grad_accum if is_classification else None
+                stats = {}
             # grads come out in compute dtype; update math runs in param dtype
             grads = _cast_floats(grads, param_dtype)
             # the norm of the gradient the optimizer is given: under
@@ -549,6 +574,7 @@ class Trainer:
             }
             if acc_metric is not None:
                 metrics["accuracy"] = acc_metric
+            metrics.update(stats)
             return (
                 TrainState(
                     step=state.step + 1,
@@ -847,6 +873,24 @@ class Trainer:
             {"trainable_params": trainable, "frozen_params": frozen},
         )
 
+    def _report_layers(self):
+        """What each layer of a decoder is (kind, heads, rope, dense or
+        routed, experts held of published): one event at build, on the run
+        store and as `polyaxon.model.layers` in the tracer's ring and any
+        profiler capture."""
+        cfg = getattr(self.bundle.module, "cfg", None)
+        if cfg is None or not hasattr(cfg, "layer"):
+            return
+        import json
+
+        from ..telemetry.spans import get_tracer
+
+        layers = [cfg.layer(i).describe(cfg) for i in range(cfg.n_layers)]
+        get_tracer().event(
+            "model.layers", n_layers=len(layers), layers=json.dumps(layers)
+        )
+        self._event("model_layers", {"layers": layers})
+
     def _init_throughput_facts(self):
         """Static facts behind the tokens/s and MFU gauges: tokens per
         step (token tasks only) and the analytic step FLOPs (transformer
@@ -862,6 +906,11 @@ class Trainer:
             return
         global_batch = self.data.batch_size * jax.process_count()
         self._tokens_per_step = global_batch * int(seq)
+        if getattr(cfg, "layers", ()) or getattr(cfg, "n_experts", 0):
+            # the count below takes every held weight as touched by every
+            # token and one head count for all layers: wrong for routed
+            # experts and for layers that differ, so no `mfu` gauge there
+            return
         try:
             from ..parallel.sharding import _path_str
 
@@ -938,6 +987,15 @@ class Trainer:
 
     def _emit(self, history, step, metrics):
         vals = {k: float(v) for k, v in metrics.items()}
+        if vals.get("moe.overflow", 0.0) > 0:
+            # a routed layer's buffer was too short and assignments were left
+            # out: the step computed another model's loss
+            raise RuntimeError(
+                f"step {step}: {vals['moe.overflow']:.0f} assignments to held "
+                "experts did not fit the routed layers' buffer "
+                "(model.config.expert_buffer_factor is too small for this "
+                "load): nothing may be dropped, so the run stops"
+            )
         vals.update(self._drain_window())
         for k, v in vals.items():
             self.telemetry.gauge(f"train.{k}").set(v)

@@ -168,7 +168,7 @@ def device_report(devices: Sequence, module_cfg=None, seq_len=None) -> dict:
             backend = resolve_auto_backend(
                 int(seq_len or module_cfg.seq_len),
                 module_cfg.attention_block,
-                module_cfg.head_dim,
+                module_cfg.head_size,
             )
         from ..ops.flash_attention import _interpret
 

@@ -82,6 +82,32 @@ def test_flash_kernel_compiles_for_v5e(chip, heads, kv_heads, head_dim, block_kv
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize(
+    "heads,head_dim,block_kv,window,seq",
+    [(72, 128, 512, 512, 4096), (72, 128, 512, 500, 4096), (32, 64, 128, 200, 2048)],
+    ids=["the-cell-72x128-w512", "unaligned-w500", "D64-kv128-w200"],
+)
+def test_windowed_flash_kernels_compile_for_v5e(chip, heads, head_dim, block_kv, window, seq):
+    """Forward and backward with a window: the shorter grids, the index maps
+    that start at the window's first block, and the three kernels' own
+    names, as a sliding layer of the Laguna cell calls them (2 rows x 4,096,
+    72 query heads on 8 kv heads of 128, window 512)."""
+    def grads(q, k, v):
+        return jax.grad(
+            lambda *a: fa.flash_attention(
+                *a, causal=True, block_q=128, block_kv=block_kv, window=window
+            ).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2),
+        )(q, k, v)
+
+    text = jax.jit(grads).lower(
+        *_qkv(chip, heads, 8, head_dim, batch=2, seq=seq)
+    ).compile().as_text()
+    for name in ("flash_window_fwd", "flash_window_dq", "flash_window_dkv"):
+        assert name in text
+    assert "flash_attention_" not in text
+
+
 def _dense_decode(chip, batch, cache_len=8192, n_layers=1):
     """The dense `generate` program at Llama-3.2-1B widths, the prompt
     filling half of an 8,192-token cache. Depth is cut to one layer: what
