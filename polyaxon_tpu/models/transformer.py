@@ -99,7 +99,10 @@ class TransformerConfig:
     # auto = flash on TPU past ~2k tokens (O(S^2) score matrix starts to
     # dominate HBM traffic), xla otherwise; explicit values force a backend
     attention: str = "auto"  # auto | xla | flash | ring | ulysses
-    attention_block: int = 512  # kv block size for flash/ring backends
+    # None = the flash kernels choose their blocks (ops/flash_attention.
+    # choose_blocks) and the ring/ulysses chunk is 512; a number is the kv
+    # block of the flash kernels and that chunk
+    attention_block: Optional[int] = None
     lora_rank: int = 0
     lora_alpha: float = 16.0
     lora_targets: tuple = ()  # projection names; empty = all projections

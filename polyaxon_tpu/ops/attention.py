@@ -17,7 +17,7 @@ import numpy as np
 
 
 def resolve_auto_backend(
-    seq_len: int, block_kv: int, head_dim: int | None = None
+    seq_len: int, block_kv: int | None = None, head_dim: int | None = None
 ) -> str:
     """`auto` policy: the Pallas flash kernel on TPU when the O(S^2) score
     matrix starts to matter and the shapes satisfy the kernel's block
@@ -26,8 +26,9 @@ def resolve_auto_backend(
     Rationale: at short seq the einsum path is a single fused MXU pass and
     XLA's softmax fusion is hard to beat; past ~2k tokens the [B,H,S,S]
     f32 score matrix dominates HBM traffic and the blockwise kernel's
-    O(S) VMEM streaming wins (pallas_guide.md). Shape guards mirror
-    flash_attention's: seq divisible by BOTH block sizes (block_q is 128).
+    O(S) VMEM streaming wins (pallas_guide.md). Shape guards are
+    flash_attention's own (`flash_shapes_ok`): the blocks the kernels would
+    choose, around `block_kv` where the caller fixed it, divide the sequence.
 
     Mesh dispatch: on multi-device meshes where the SEQUENCE dim stays
     whole per device (DP/FSDP/TP — batch and heads shard, not seq) the
@@ -39,11 +40,9 @@ def resolve_auto_backend(
     backend the einsum remains the only partitionable path."""
     if jax.default_backend() != "tpu" or seq_len < 2048:
         return "xla"
-    block_q = 128  # flash_attention's default q block
-    blocks_ok = (
-        seq_len % min(block_kv, seq_len) == 0
-        and seq_len % min(block_q, seq_len) == 0
-    )
+    from .flash_attention import flash_shapes_ok
+
+    blocks_ok = flash_shapes_ok(seq_len, block_kv=block_kv, head_dim=head_dim or 128)
     # unusual head dims must fall back, not surface as Mosaic layout
     # errors: the kernel's VMEM tiles want lane-friendly D (64/128/192/256).
     # Explicit `attention: flash` bypasses this — an opt-in to the kernel.
@@ -70,7 +69,7 @@ def resolve_auto_backend(
     return "flash" if flash_ok else "xla"
 
 
-def _flash_sharded(q, k, v, *, causal: bool, block_kv: int, mesh, window=None):
+def _flash_sharded(q, k, v, *, causal: bool, block_kv: int | None, mesh, window=None):
     """The Pallas flash kernel on a live multi-device mesh.
 
     The kernel has no GSPMD partitioning rule, so partition it manually:
@@ -119,10 +118,13 @@ def _flash_sharded(q, k, v, *, causal: bool, block_kv: int, mesh, window=None):
 
 
 def dot_product_attention(
-    q, k, v, *, causal: bool, backend: str = "xla", block_kv: int = 512,
+    q, k, v, *, causal: bool, backend: str = "xla", block_kv: int | None = None,
     window: int | None = None,
 ):
     """q: [B, S, H, D]; k/v: [B, S, KV, D] with KV dividing H → [B, S, H, D].
+
+    `block_kv`: None lets the flash kernels choose their blocks (and the
+    ring and ulysses chunk stay 512); a number is the kv block of all.
 
     `window` (causal only): query i attends keys j with 0 <= i - j <
     window. The einsum masks; the flash kernel skips the kv blocks behind
@@ -171,11 +173,11 @@ def dot_product_attention(
     if backend == "ring":
         from ..parallel.ring import ring_attention
 
-        return ring_attention(q, k, v, block_kv=block_kv, causal=causal)
+        return ring_attention(q, k, v, block_kv=block_kv or 512, causal=causal)
     if backend == "ulysses":
         from ..parallel.ulysses import ulysses_attention
 
-        return ulysses_attention(q, k, v, block_kv=block_kv, causal=causal)
+        return ulysses_attention(q, k, v, block_kv=block_kv or 512, causal=causal)
     if backend != "xla":
         raise ValueError(f"unknown attention backend {backend!r}")
     hd = q.shape[-1]

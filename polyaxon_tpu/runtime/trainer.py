@@ -890,6 +890,41 @@ class Trainer:
             "model.layers", n_layers=len(layers), layers=json.dumps(layers)
         )
         self._event("model_layers", {"layers": layers})
+        self._report_flash_tiles(cfg)
+
+    def _report_flash_tiles(self, cfg):
+        """What the flash kernels of this model run, per distinct call shape
+        (sequence, head width, GQA group, window) and kernel: the blocks the
+        kernel chose (or was given) and its walk's counts of one head (grid
+        steps, live steps, steps that mask, executed over required pairs).
+        One event at build, on the run store (`flash_tiles`) and as
+        `polyaxon.kernels.flash_tiles` beside `polyaxon.model.layers`; none
+        where attention does not run the flash kernels."""
+        import json
+
+        from ..ops.attention import resolve_auto_backend
+        from ..ops.flash_attention import tile_report
+        from ..telemetry.spans import get_tracer
+
+        seq = int(self.data.meta.get("seq_len") or cfg.seq_len)
+        backend = cfg.attention
+        if backend == "auto":
+            backend = resolve_auto_backend(seq, cfg.attention_block, cfg.head_size)
+        if backend != "flash":
+            return
+        shapes = sorted(
+            {(s.n_heads // cfg.n_kv_heads, s.window) for s in map(cfg.layer, range(cfg.n_layers))}
+        )
+        calls = [
+            call
+            for group, window in shapes
+            for call in tile_report(
+                seq, cfg.head_size, group, window or None, self.compute_dtype,
+                block_kv=cfg.attention_block,
+            )
+        ]
+        get_tracer().event("kernels.flash_tiles", n_calls=len(calls), calls=json.dumps(calls))
+        self._event("flash_tiles", {"calls": calls})
 
     def _init_throughput_facts(self):
         """Static facts behind the tokens/s and MFU gauges: tokens per

@@ -2,6 +2,8 @@
 reference implementation, forward and backward (pallas kernels run
 interpreted on the CPU test mesh; the same code compiles on TPU)."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -49,6 +51,192 @@ def test_flash_rejects_indivisible_seq():
     q, k, v = _qkv(S=100)
     with pytest.raises(ValueError):
         flash_attention(q, k, v, block_q=64, block_kv=64)
+
+
+# ------------------------------------------------- the tiling and the walk
+# (group, block_q, block_kv, causal, window) at 256 tokens: several q and kv
+# blocks, block_q under and over block_kv, so interior, edge and dead tiles
+# are all there; windows aligned and unaligned to both blocks
+TILINGS = {
+    "g2-32x64": (2, 32, 64, True, None),
+    "g6-64x32": (6, 64, 32, True, None),
+    "g9-128x32": (9, 128, 32, True, None),
+    "g2-32x128": (2, 32, 128, True, None),
+    "g1-64x64-whole": (1, 64, 64, False, None),
+    "g6-32x64-whole": (6, 32, 64, False, None),
+    "g2-64x32-whole": (2, 64, 32, False, None),
+    "g9-64x32-w1": (9, 64, 32, True, 1),
+    "g9-64x32-w100": (9, 64, 32, True, 100),
+    "g9-64x32-w128": (9, 64, 32, True, 128),
+    "g9-64x32-w200": (9, 64, 32, True, 200),
+    "g2-32x64-w1": (2, 32, 64, True, 1),
+    "g2-32x64-w100": (2, 32, 64, True, 100),
+    "g2-32x64-w128": (2, 32, 64, True, 128),
+    "g2-32x64-w200": (2, 32, 64, True, 200),
+    "g6-the-rule-around-kv64": (6, None, 64, True, 100),
+    "g2-the-rule": (2, None, None, True, None),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _tiled(case):
+    """(flash, xla): forward and the three gradients on one seeded case."""
+    group, block_q, block_kv, causal, window = TILINGS[case]
+    ks = jax.random.split(jax.random.PRNGKey(11), 4)
+    q = jax.random.normal(ks[0], (1, 256, 2 * group, 16))
+    k = jax.random.normal(ks[1], (1, 256, 2, 16))
+    v = jax.random.normal(ks[2], (1, 256, 2, 16))
+    ct = jax.random.normal(ks[3], q.shape)
+
+    def both(fn):
+        out = fn(q, k, v)
+        grads = jax.grad(lambda *a: (fn(*a) * ct).sum(), (0, 1, 2))(q, k, v)
+        return dict(zip(("out", "dq", "dk", "dv"), (out, *grads)))
+
+    return (
+        both(lambda *a: flash_attention(*a, causal=causal, block_q=block_q,
+                                        block_kv=block_kv, window=window)),
+        both(lambda *a: dot_product_attention(*a, causal=causal, backend="xla",
+                                              window=window)),
+    )
+
+
+@pytest.mark.parametrize("what", ["out", "dq", "dk", "dv"])
+@pytest.mark.parametrize("case", sorted(TILINGS))
+def test_flash_tiling_matches_masked_xla(case, what):
+    flash, xla = _tiled(case)
+    # float32 online softmax against the whole softmax
+    np.testing.assert_allclose(flash[what], xla[what], atol=2e-5, rtol=2e-5)
+
+
+def _walked(walk, outer):
+    """[(block, live)] of every step of outer block `outer`, as the index
+    maps (clamped) and the kernel bodies (live) compute them."""
+    return [
+        (int(walk.at(outer, step, clamp=True)), bool(walk.at(outer, step)[1]))
+        for step in range(walk.steps)
+    ]
+
+
+@pytest.mark.parametrize("kv_major", [False, True], ids=["q-major", "kv-major"])
+@pytest.mark.parametrize(
+    "block_q,block_kv,causal,window",
+    [(128, 512, True, None), (512, 128, True, None), (256, 256, True, 512),
+     (128, 256, True, 300), (256, 256, False, None)],
+    ids=["128x512", "512x128", "w512", "w300-unaligned", "whole"],
+)
+def test_a_dead_step_repeats_the_previous_block(block_q, block_kv, causal, window, kv_major):
+    """Pallas issues a DMA when a block index changes: a skipped step must
+    keep the index of the step before it, with or without a window, in the
+    q-major kernels and in dk/dv's q-side maps; a live step fetches the
+    block it computes on."""
+    from polyaxon_tpu.ops.flash_attention import _Walk
+
+    walk = _Walk(2048, block_q, block_kv, causal, window, kv_major=kv_major)
+    dead = 0
+    for outer in range(walk.n_outer):
+        steps = _walked(walk, outer)
+        first, last = walk.span(outer)
+        assert [b for b, live in steps if live] == list(range(first, last + 1))
+        assert steps[0][1]  # every row sees its own key: the first step is live
+        for (block, live), (before, _) in zip(steps[1:], steps):
+            if not live:
+                dead += 1
+                assert block == before == last
+    counts = walk.counts()
+    assert counts["grid_steps"] - counts["live_steps"] == dead
+    assert (dead > 0) == causal  # whole attention has no edge to fall off
+
+
+def test_only_a_tile_an_edge_crosses_is_masked():
+    from polyaxon_tpu.ops.flash_attention import _Walk
+
+    def needs(walk, iq, ik):  # by the elements themselves
+        r = np.arange(iq * walk.block_q, (iq + 1) * walk.block_q)[:, None]
+        c = np.arange(ik * walk.block_kv, (ik + 1) * walk.block_kv)[None, :]
+        seen = r >= c
+        if walk.window is not None:
+            seen &= r - c < walk.window
+        return not seen.all()
+
+    for bq, bkv, window in [(64, 128, None), (128, 64, None), (64, 128, 100), (128, 64, 256)]:
+        walk = _Walk(1024, bq, bkv, True, window)
+        masked = 0
+        for iq in range(walk.nq):
+            first, last = walk.span(iq)
+            for ik in range(first, last + 1):
+                assert bool(walk.edge(iq, ik)) == needs(walk, iq, ik), (bq, bkv, window, iq, ik)
+                masked += needs(walk, iq, ik)
+        assert walk.counts()["mask_steps"] == masked
+        assert masked < walk.counts()["live_steps"] or window == 100
+
+
+# --------------------------------------------------------------- the rule
+@pytest.mark.parametrize("kernel", ["fwd", "dq", "dkv"])
+@pytest.mark.parametrize(
+    "seq,head_dim,group,window",
+    [(2048, 128, 2, None), (4096, 128, 6, None), (4096, 128, 9, 512), (2048, 64, 4, None),
+     (2496, 128, 2, None), (8192, 128, 4, None), (1000, 64, 1, None), (100, 32, 1, None),
+     (4096, 128, 1, None), (512, 128, 8, 64)],
+)
+def test_the_rule_gives_blocks_that_divide_and_are_not_narrow(kernel, seq, head_dim, group, window):
+    from polyaxon_tpu.ops.flash_attention import choose_blocks
+
+    bq, bkv = choose_blocks(kernel, seq, head_dim, group, window)
+    for b in (bq, bkv):
+        assert seq % b == 0
+        assert b % 8 == 0 or b == seq
+        assert b >= min(128, seq) and b <= 1024
+    # an explicit block is obeyed to the letter, the other chosen around it
+    assert choose_blocks(kernel, seq, head_dim, group, window, block_q=seq // 2)[0] == seq // 2
+    if seq % 8 == 0:
+        assert choose_blocks(kernel, seq, head_dim, group, window, block_kv=seq // 4)[1] == seq // 4
+    assert choose_blocks(kernel, seq, head_dim, group, window, block_q=4, block_kv=seq) == (4, seq)
+    # a pure function of the shape
+    assert choose_blocks(kernel, seq, head_dim, group, window) == (bq, bkv)
+
+
+def test_the_rule_is_the_least_estimate_and_knows_the_cells():
+    """The choice is the least `_estimate_seconds` among the pairs the
+    sequence allows and VMEM holds, and at the benchmark's call shapes it is the pair the
+    chip measured fastest (PR 30's sweep): wide kv blocks for the forward,
+    512 x 512 for the backward, and for a window of 512 at 4,096 fewer
+    executed pairs than the 2x of PR 29's 128 x 512 in both backward kernels."""
+    from polyaxon_tpu.ops.flash_attention import (
+        _VMEM_BUDGET, _Walk, _candidates, _estimate_seconds, _vmem_bytes, choose_blocks,
+        tile_report,
+    )
+
+    for kernel in ("fwd", "dq", "dkv"):
+        for seq, head_dim, group, window in [(2048, 128, 2, None), (4096, 128, 9, 512)]:
+            costs = {
+                (bq, bkv): _estimate_seconds(
+                    kernel, _Walk.of(kernel, seq, (bq, bkv), True, window), head_dim, group, 2)
+                for bq in _candidates(seq, None) for bkv in _candidates(seq, None)
+                if _vmem_bytes(kernel, bq, bkv, head_dim, group, 2) <= _VMEM_BUDGET
+            }
+            chosen = choose_blocks(kernel, seq, head_dim, group, window)
+            assert costs[chosen] == min(costs.values())
+    blocks = lambda *shape: [(c["block_q"], c["block_kv"]) for c in tile_report(*shape)]  # noqa: E731
+    assert blocks(2048, 128, 2) == [(512, 1024), (512, 512), (512, 512)]
+    assert blocks(4096, 128, 6) == [(256, 1024), (256, 512), (512, 512)]
+    assert blocks(4096, 128, 9, 512) == [(256, 512), (256, 256), (256, 256)]
+    assert blocks(2048, 64, 4) == [(512, 1024), (512, 512), (512, 512)]
+    waste = [c["executed_over_required"] for c in tile_report(4096, 128, 9, 512)]
+    assert waste[1] < 1.51 and waste[2] < 1.51 and waste[0] < 2.01
+
+
+def test_the_rule_refuses_what_no_block_divides():
+    from polyaxon_tpu.ops.flash_attention import choose_blocks, flash_shapes_ok
+
+    # 2,056 = 8 x 257: no sublane-aligned divisor between 128 and 1,024
+    with pytest.raises(ValueError, match="not divisible"):
+        choose_blocks("fwd", 2056, 128)
+    assert not flash_shapes_ok(2056)
+    assert flash_shapes_ok(2496) and flash_shapes_ok(2048, block_kv=512)
+    assert not flash_shapes_ok(2048, block_kv=192)  # an explicit block must divide
+    assert not flash_shapes_ok(2048, block_q=4, block_kv=4 * 3)
+    assert flash_shapes_ok(100)  # one block, the whole sequence
 
 
 @pytest.mark.parametrize(
@@ -240,7 +428,10 @@ def test_auto_backend_resolution(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     set_current_mesh(None)
     assert resolve_auto_backend(1024, 512) == "xla"  # short seq
-    assert resolve_auto_backend(2496, 192) == "xla"  # % block_q fails
+    assert resolve_auto_backend(2056, 512) == "xla"  # no block divides 8 x 257
+    assert resolve_auto_backend(2048, 192) == "xla"  # an explicit kv block must
+    # 2,496 = 64 x 39 runs since the kernels choose: q and kv blocks of 416
+    assert resolve_auto_backend(2496) == ("flash" if len(jax.devices()) == 1 else "xla")
     assert resolve_auto_backend(4096, 512, head_dim=80) == "xla"  # odd D
     assert resolve_auto_backend(4096, 512, head_dim=512) == "xla"  # huge D
     # no mesh bound: only a lone device can run the unpartitioned kernel

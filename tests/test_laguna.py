@@ -261,10 +261,14 @@ def _normalised(jaxpr) -> str:
 
 
 def test_no_window_traces_to_the_kernels_of_the_parent(no_mesh):
-    """`window=None` builds the grids, index maps, bodies and names PR 28's
-    tree built: the jaxpr of forward and backward, kernel bodies included,
-    has the hash taken from that tree (jax 0.9.0; another jax prints another
-    text, and the pin is then taken anew from a tree known to be unchanged)."""
+    """The jaxpr of forward and backward at explicit blocks, kernel bodies,
+    grids and index maps included, is pinned by its hash, so a change to a
+    kernel cannot pass unseen. PR 30 rewrote how the three kernels tile and
+    walk (a GQA group a step, masks on edge tiles only, clamped index maps,
+    transposed dk/dv tiles), so PR 28's hash went stale by design: this one
+    was taken anew from PR 30's finished tree (jax 0.9.0; another jax prints
+    another text, and the pin is then taken anew from a tree known to be
+    unchanged)."""
     q = jax.ShapeDtypeStruct((2, 256, 4, 32), jnp.float32)
     k = jax.ShapeDtypeStruct((2, 256, 2, 32), jnp.float32)
 
@@ -277,7 +281,7 @@ def test_no_window_traces_to_the_kernels_of_the_parent(no_mesh):
     text = str(jax.make_jaxpr(grads)(q, k, k))
     assert "flash_attention_fwd" in text and "flash_window" not in text
     if jax.__version__ == "0.9.0":
-        assert _normalised(text) == "a5d35dccb17c2b05"
+        assert _normalised(text) == "2c8a6e666f64a69f"
 
 
 def test_window_names_its_three_kernels():
@@ -294,13 +298,26 @@ def test_window_names_its_three_kernels():
 
 
 def test_a_windowed_q_block_walks_only_the_kv_blocks_it_sees():
-    from polyaxon_tpu.ops.flash_attention import _kv_walk
+    """The walk of the cell's sliding layers (4,096 tokens, window 512, nine
+    query heads a kv head): a q block steps over the kv blocks its window
+    touches and no further, in the forward and dq kernels; a kv block over
+    the q blocks that see it, in dk/dv. With the blocks the kernels choose
+    the backward walks execute 1.5 of the window's own pairs (PR 29's q
+    blocks of 128 on kv blocks of 512 executed 2); the forward keeps 2,
+    because its steps cost more than the pairs they would save."""
+    from polyaxon_tpu.ops.flash_attention import _Walk, tile_report
 
-    # the cell's sliding layers: 4,096 tokens, q blocks of 128, kv blocks of
-    # 512, window 512: two kv blocks a q block, not the sequence's eight
-    assert _kv_walk(32, 8, 128, 512, 512)[0] == 2
-    assert _kv_walk(16, 8, 64, 128, 40)[0] == 2  # one, two across an edge
-    assert _kv_walk(32, 8, 128, 512, None)[0] == 8
+    assert _Walk(4096, 128, 512, True, 512).steps == 2  # PR 29's blocks
+    assert _Walk(4096, 128, 128, True, 512).steps == 5
+    assert _Walk(4096, 256, 256, True, 512).steps == 3
+    assert _Walk(4096, 256, 256, True, 512, kv_major=True).steps == 3
+    assert _Walk(1024, 64, 128, True, 40).steps == 2  # one, two across an edge
+    assert _Walk(4096, 128, 512, True, None).steps == 8
+    assert _Walk(4096, 128, 512, False, None).steps == 8
+    for call in tile_report(4096, 128, group=9, window=512):
+        assert call["kernel"].startswith("flash_window_")
+        assert call["executed_over_required"] < (2.01 if call["kernel"].endswith("fwd") else 1.6)
+        assert call["mask_steps"] <= call["live_steps"] < call["grid_steps"]
 
 
 def test_flash_shapes_ok_knows_the_window():
@@ -315,12 +332,16 @@ def test_flash_shapes_ok_knows_the_window():
 
 
 def test_dense_config_traces_as_on_the_parent(no_mesh):
-    """An existing dense config is untouched by the new fields: forward and
-    backward of the `tiny` LoRA decoder have the jaxpr of PR 28's tree."""
+    """An existing dense config is untouched by fields it does not set:
+    forward and backward of the `tiny` LoRA decoder are pinned by the hash of
+    their jaxpr. It holds the flash kernels' bodies, which PR 30 rewrote (and
+    `attention_block` is None now: the kernels choose), so PR 28's hash went
+    stale by design and this one was taken anew from PR 30's finished tree."""
     bundle = build_model("transformer_lm", {"preset": "tiny", "seq_len": 64,
                                             "attention": "flash", "lora": {"rank": 4}})
     cfg = bundle.module.cfg
     assert cfg.head_dim is None and cfg.head_size == 32 and cfg.layers == ()
+    assert cfg.attention_block is None
     tokens = jnp.zeros((2, 64), jnp.int32)
     variables = jax.eval_shape(
         lambda: bundle.module.init({"params": jax.random.PRNGKey(0)}, tokens)
@@ -331,7 +352,7 @@ def test_dense_config_traces_as_on_the_parent(no_mesh):
         )(p)
     )(variables))
     if jax.__version__ == "0.9.0":
-        assert _normalised(text) == "cc4dd19d9d586acf"
+        assert _normalised(text) == "6f67103a5000a097"
 
 
 # ------------------------------------------------------------------ the share
@@ -470,6 +491,54 @@ def test_trainer_reports_layers_routing_and_what_it_differentiates():
     assert layers[1]["window"] == 16 and layers[0]["experts_held"] == 0
     marks = [r for r in get_tracer().recent(200) if r["name"] == "model.layers"]
     assert marks and json.loads(marks[-1]["attrs"]["layers"]) == layers
+
+
+def test_trainer_reports_the_tiles_its_flash_kernels_run():
+    """`polyaxon.kernels.flash_tiles`: once a build, per distinct call shape
+    and kernel the blocks and the walk's counts, on the run store and in the
+    tracer's ring; a windowed and a dense toy model; nothing without flash."""
+    from polyaxon_tpu.telemetry.spans import get_tracer
+
+    fields = {"kernel", "seq", "head_dim", "group", "window", "causal", "block_q",
+              "block_kv", "grid_steps", "live_steps", "mask_steps",
+              "executed_over_required"}
+
+    def marks():
+        return [r for r in get_tracer().recent(400) if r["name"] == "kernels.flash_tiles"]
+
+    before = len(marks())
+    events: list = []
+    tiny_trainer(events).close()
+    tiles = [body for kind, body in events if kind == "flash_tiles"]
+    assert len(tiles) == 1 and len(marks()) == before + 1
+    calls = tiles[0]["calls"]
+    assert json.loads(marks()[-1]["attrs"]["calls"]) == calls
+    assert all(set(c) == fields for c in calls)
+    # the toy Laguna: full layers of 4 heads, sliding ones of 6 with window
+    # 16, on 2 kv heads of 32, 128 tokens: two call shapes x three kernels
+    assert [(c["kernel"], c["group"], c["window"]) for c in calls] == [
+        ("flash_attention_fwd", 2, None), ("flash_attention_dq", 2, None),
+        ("flash_attention_dkv", 2, None), ("flash_window_fwd", 3, 16),
+        ("flash_window_dq", 3, 16), ("flash_window_dkv", 3, 16),
+    ]
+    for c in calls:
+        assert c["seq"] == 128 and c["head_dim"] == 32
+        assert c["seq"] % c["block_q"] == 0 and c["seq"] % c["block_kv"] == 0
+        assert c["mask_steps"] <= c["live_steps"] <= c["grid_steps"]
+        assert c["executed_over_required"] >= 1.0
+
+    # a dense model with a kv block its file wrote: obeyed, and reported
+    from polyaxon_tpu.models import build_model
+    from polyaxon_tpu.ops.flash_attention import tile_report
+
+    dense = build_model("transformer_lm", {"preset": "tiny", "seq_len": 256,
+                                           "attention": "flash", "attention_block": 64})
+    cfg = dense.module.cfg
+    calls = tile_report(256, cfg.head_size, cfg.n_heads // cfg.n_kv_heads,
+                        block_kv=cfg.attention_block)
+    assert [c["block_kv"] for c in calls] == [64, 64, 64]
+    assert [c["kernel"] for c in calls] == [
+        "flash_attention_fwd", "flash_attention_dq", "flash_attention_dkv"]
 
 
 def test_trainer_stops_on_overflow():

@@ -4,7 +4,8 @@ refuse (tiling, VMEM, HBM), which interpret mode on the CPU never shows.
 Nothing executes, so these say nothing about results or speed.
 
 The shapes are those of `chip_smoke.py` (Llama-3.2-1B widths: 32 query / 8
-kv heads of 64, batch 4, seq 2048) plus head width 128.
+kv heads of 64, batch 4, seq 2048) plus head width 128, and the benchmark's
+three call shapes with the blocks the kernels choose for them.
 """
 
 import os
@@ -106,6 +107,47 @@ def test_windowed_flash_kernels_compile_for_v5e(chip, heads, head_dim, block_kv,
     for name in ("flash_window_fwd", "flash_window_dq", "flash_window_dkv"):
         assert name in text
     assert "flash_attention_" not in text
+
+
+# (rows, seq, query heads, kv heads, head width, window): the call shapes of
+# the benchmark's two cells and of chip_smoke.py
+CALLS = {
+    "internlm2-2x2048-16q8kv": (2, 2048, 16, 8, 128, None),
+    "laguna-full-2x4096-48q8kv": (2, 4096, 48, 8, 128, None),
+    "laguna-sliding-2x4096-72q8kv-w512": (2, 4096, 72, 8, 128, 512),
+    "chip-smoke-4x2048-32q8kv-D64": (4, 2048, 32, 8, 64, None),
+}
+
+
+@pytest.mark.parametrize("backward", [False, True], ids=["fwd", "fwd+bwd"])
+@pytest.mark.parametrize("call", sorted(CALLS))
+def test_the_chosen_tiles_compile_for_v5e(chip, call, backward):
+    """No block is given: each kernel runs the blocks `choose_blocks` gives
+    it for the call's shape, and the chip's compiler takes them (VMEM, the
+    tiling of every block, the statistics' two layouts). The kernels keep
+    the names the benchmark's roofline metrics find them by."""
+    rows, seq, heads, kv_heads, head_dim, window = CALLS[call]
+    chosen = fa.tile_report(seq, head_dim, heads // kv_heads, window)
+    assert {(c["block_q"], c["block_kv"]) for c in chosen} != {(128, 512)}
+
+    def attend(q, k, v):
+        return fa.flash_attention(q, k, v, causal=True, window=window)
+
+    fn = attend
+    if backward:
+        def fn(q, k, v):
+            return jax.grad(
+                lambda *a: attend(*a).astype(jnp.float32).sum(), argnums=(0, 1, 2)
+            )(q, k, v)
+
+    text = jax.jit(fn).lower(
+        *_qkv(chip, heads, kv_heads, head_dim, batch=rows, seq=seq)
+    ).compile().as_text()
+    stem, other = ("flash_window_", "flash_attention_") if window else (
+        "flash_attention_", "flash_window_")
+    for kernel in ("fwd", "dq", "dkv") if backward else ("fwd",):
+        assert stem + kernel in text
+    assert other not in text
 
 
 def _dense_decode(chip, batch, cache_len=8192, n_layers=1):
