@@ -14,7 +14,6 @@ batch 4 x seq 2048, random weights from the seed):
   run (warm)   the same command again: the persistent compile cache must hit
   serve        `python -m polyaxon_tpu serve -uid <run>`, default path
   serve        the same, step engine over the paged pool
-  sync         one warm kernel timed to `block_until_ready` and to a scalar fetch
 
 Every phase is a child process, started one after another: a chip belongs
 to one process at a time, so this parent never imports JAX. What it knows of
@@ -35,7 +34,6 @@ import random
 import re
 import shutil
 import signal
-import statistics
 import subprocess
 import sys
 import time
@@ -430,59 +428,6 @@ def serve_paged(cfg, run, default):
     return res
 
 
-# --------------------------------------------------------------------- sync
-def sync_compare(cfg):
-    argv = [sys.executable, str(Path(__file__).resolve()), "--child", "sync"]
-    if cfg["rehearse"]:
-        argv.append("--rehearse")
-    out, _ = run_child("sync", argv, child_env(cfg, cfg["one_chip_env"]), timeout=300)
-    return json.loads(out.read_text().strip().splitlines()[-1])
-
-
-def child_sync(rehearse: bool) -> None:
-    """One warm jitted program — the flash kernel at the smoke's shapes —
-    called N times, timed once to `jax.block_until_ready` and once to the
-    scalar fetch of benchmarks/_timing.py. Medians of several repeats."""
-    sys.path.insert(0, str(ROOT / "benchmarks"))
-    import jax
-    import jax.numpy as jnp
-    from _timing import _sync
-
-    from polyaxon_tpu.ops.flash_attention import flash_attention
-
-    B, S, H, KV, D, n_calls, reps = (2, 256, 4, 2, 64, 3, 2) if rehearse else (4, 2048, 32, 8, 64, 50, 7)
-    ks = jax.random.split(jax.random.PRNGKey(SEED), 3)
-    q = jax.random.normal(ks[0], (B, S, H, D), jnp.bfloat16)
-    k = jax.random.normal(ks[1], (B, S, KV, D), jnp.bfloat16)
-    v = jax.random.normal(ks[2], (B, S, KV, D), jnp.bfloat16)
-    fn = jax.jit(lambda q, k, v: flash_attention(q, k, v, causal=True))  # the kernel's own blocks
-    _sync(fn(q, k, v))  # compile + warm
-
-    def timed(end) -> float:
-        t0 = time.perf_counter()
-        for _ in range(n_calls):
-            out = fn(q, k, v)
-        end(out)
-        return (time.perf_counter() - t0) / n_calls
-
-    block, fetch = [], []
-    for _ in range(reps):  # alternate, so drift hits both alike
-        block.append(timed(jax.block_until_ready))
-        fetch.append(timed(_sync))
-    d = jax.devices()[0]
-    print(json.dumps({
-        "program": f"flash_attention fwd [{B},{S},{H}q/{KV}kv,{D}] bf16 causal",
-        "calls_per_timing": n_calls,
-        "repeats": reps,
-        "block_until_ready_ms": statistics.median(block) * 1e3,
-        "scalar_fetch_ms": statistics.median(fetch) * 1e3,
-        "fetch_over_block": statistics.median(fetch) / statistics.median(block),
-        "all_block_ms": [x * 1e3 for x in block],
-        "all_fetch_ms": [x * 1e3 for x in fetch],
-        "device": {"platform": d.platform, "device_kind": d.device_kind},
-    }))
-
-
 # ---------------------------------------------------------------- four chips
 def sharded_train(cfg, one_chip):
     """The same program, same seed, on a {fsdp: 2, model: 2} mesh over all
@@ -624,10 +569,10 @@ def main() -> int:
                     help="4: only the sharded-training and routed-replica paths")
     ap.add_argument("--rehearse", action="store_true",
                     help="every phase at a tiny size on the CPU; ends ok=false")
-    ap.add_argument("--child", choices=("sync", "train4"), help=argparse.SUPPRESS)
+    ap.add_argument("--child", choices=("train4",), help=argparse.SUPPRESS)
     args = ap.parse_args()
     if args.child:
-        {"sync": child_sync, "train4": child_train4}[args.child](args.rehearse)
+        child_train4(args.rehearse)
         return 0
 
     if not (SPEC.is_file() and (ROOT / "polyaxon_tpu").is_dir()):
@@ -661,8 +606,7 @@ def main() -> int:
         default = phase("serve-default", serve_default, cfg, cold, needs=(cold,))
         paged = phase("serve-step-engine", serve_paged, cfg, cold, default,
                       needs=(cold, default))
-        sync = phase("sync", sync_compare, cfg)
-        reports = [r["device"] for r in (cold, warm, default, paged, sync) if r]
+        reports = [r["device"] for r in (cold, warm, default, paged) if r]
         ids = {i for r in (cold, warm, default, paged) if r
                for i in r["device"]["device_ids"]}
         count = len(ids)

@@ -495,9 +495,10 @@ _PERF_DIFF_DEFAULT_MAP = {
               help="fail (exit 1) when live > bench*(1+TOLERANCE) on "
                    "any compared field; omit for report-only")
 def perf_diff(bench_file, url, last, mappings, tolerance):
-    """Diff a live history window against a committed BENCH_*.json.
+    """Diff a live history window against a record file: one JSON
+    object whose `tail` string holds JSON lines.
 
-    The bench record's tail JSONL is scanned for each mapped field
+    The record's tail is scanned for each mapped field
     (last record carrying it wins), the live side is the /queryz
     aggregate over the trailing --last seconds, and the drift is
     printed per field. With --tolerance the command gates: any field

@@ -127,10 +127,8 @@ def kv_pool_bytes(layout, n_layers: int, n_kv_heads: int, head_dim: int,
                   kv_dtype_bytes: int = 2) -> int:
     """HBM bytes the paged K+V pool occupies under `layout`. int8 pools
     pay 1 byte per element plus one f32 scale per (slot, head); fp pools
-    pay `kv_dtype_bytes` per element. The admission/bench accounting
-    (`kv_pool_bytes` on /statsz and the decode_bench int8-KV record)
-    reads this, so the ≥1.9× rows-per-HBM-byte claim is measured against
-    the same formula the server budgets with."""
+    pay `kv_dtype_bytes` per element. `kv_pool_bytes` on /statsz reads
+    this: the formula the server budgets with is the one it reports."""
     slots = layout.pool_pages * layout.page_tokens
     if getattr(layout, "kv_quant", "none") == "int8":
         per_slot = n_kv_heads * (head_dim * 1 + 4)  # payload + f32 scale
@@ -191,8 +189,8 @@ def quantize_params(params, *, allow_lora: bool = False) -> tuple[dict, int]:
 
 
 def decode_weight_bytes(params) -> tuple[int, int]:
-    """(target projection bytes, total param bytes) — the bench's HBM
-    reduction is measured over these, not a synthetic estimate."""
+    """(target projection bytes, total param bytes): what quantizing
+    the projections can save is measured over these."""
     target = total = 0
 
     def walk(tree, in_target):

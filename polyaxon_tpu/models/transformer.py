@@ -1078,7 +1078,7 @@ PIPELINE_RULES = tuple(
 )
 
 PRESETS: dict[str, dict] = {
-    # tiny flagship used by tests / graft entry / bench
+    # tiny flagship used by tests / graft entry / chip_smoke's rehearsal
     "tiny": dict(
         dim=256, n_layers=4, n_heads=8, n_kv_heads=4, vocab_size=4096, seq_len=256
     ),

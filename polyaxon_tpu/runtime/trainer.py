@@ -701,8 +701,8 @@ class Trainer:
         steps_ctr = self.telemetry.counter(
             "trainer.steps", help="Training steps completed"
         )
-        # process-global on purpose: the canary reads this off /metricsz to
-        # pin that async checkpointing keeps the step-loop stall near zero
+        # process-global on purpose: /metricsz serves it, and it shows
+        # whether async checkpointing keeps the step-loop stall near zero
         from ..telemetry import get_registry
 
         stall_hist = get_registry().histogram(

@@ -6,14 +6,13 @@ Three layers, composed by `registry.Scenario`:
 * `traces` — seeded, versioned JSONL traffic traces plus the generator
   zoo (diurnal curves, correlated bursts, heavy-tailed lengths, tenant
   mixes, adversarial floods, shared-prefix cohorts, mid-stream client
-  disconnects). Every bench workload is a replayable trace.
+  disconnects).
 * `driver` — open-loop HTTP replayer against the real router+replicas
   stack with a per-request outcome ledger and a hard zero-hung-requests
   invariant at drain.
 * `twin` — a discrete-event serving twin on `scheduler.clock.SimClock`
   driven by measured per-phase costs, so million-user multi-hour soaks
-  run in seconds on CI while the real stack validates the twin's
-  shed-rate/latency predictions at small scale.
+  run in seconds on CI.
 
 This package is deliberately free of raw clocks (`time.*`, `datetime.*`
 — lint_telemetry rule 13): simulated time comes from SimClock, measured

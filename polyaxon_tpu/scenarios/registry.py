@@ -15,8 +15,7 @@ A `Scenario` composes
 against a live router+replicas rig (built here exactly like
 tests/test_router.py builds one, or passed in for reuse) or through the
 discrete-event twin — same trace, same seed, same assertion schema, so
-`benchmarks/scenario_bench.py` can pin the twin's predictions against
-the real stack (`sim_vs_real_calibration_error`).
+a twin run and a real run of one scenario can be read side by side.
 
 Rule 13: no raw clocks — waits go through `threading.Event.wait`,
 measurements through `telemetry.now()`.
@@ -305,8 +304,8 @@ _register(Scenario(
 # ------------------------------------------------------------------ rig
 class Rig:
     """A live 2+-replica router rig, shaped exactly like the
-    tests/test_router.py fixture. Build once, reuse across scenarios
-    (scenario_bench does); `stop()` tears the whole stack down."""
+    tests/test_router.py fixture. Build once, reuse across scenarios;
+    `stop()` tears the whole stack down."""
 
     def __init__(self, mgr, router, port: int, replicas: int):
         self.mgr = mgr
@@ -560,20 +559,6 @@ def evaluate(a: Assertions, summary: dict, metrics: dict,
         check("min_disconnects", dc >= a.min_disconnects,
               f"disconnects={dc} >= {a.min_disconnects}")
     return out
-
-
-def calibration_error(twin_summary: dict, real_summary: dict) -> float:
-    """The pinned twin-vs-real disagreement: max of the absolute
-    shed-rate gap and the relative mean-latency gap. Means, not p99s —
-    at calibration scale (dozens of requests on a noisy 1-core CI box)
-    a p99 is one sample, and pinning noise would make the gate
-    meaningless. p99s still ride along in the bench record."""
-    shed_gap = abs(twin_summary["shed_rate"] - real_summary["shed_rate"])
-    tm = twin_summary["latency_ms"]["mean"]
-    rm = real_summary["latency_ms"]["mean"]
-    if tm is None or rm is None or rm <= 0:
-        return shed_gap
-    return max(shed_gap, abs(tm - rm) / rm)
 
 
 # ------------------------------------------------------------------ run

@@ -23,10 +23,7 @@ bytes — the prefix cache sees real reuse, not a statistical fiction.
 
 Generators are registered by name in `GENERATORS`; `generate(name,
 seed, **params)` returns a lazy iterator so a million-user soak never
-materializes a million dataclasses. The bench workloads
-(benchmarks/serving_bench.py `bench_mix`, serving_overload_bench.py
-`single_shape`) live here too — every benchmark request mix is a
-replayable seeded trace.
+materializes a million dataclasses.
 """
 
 from __future__ import annotations
@@ -326,41 +323,6 @@ def tenant_storm(seed: int, *, n: int = 400, noisy_frac: float = 0.85,
         )
 
 
-def bench_mix(seed: int, *, n: int = 96) -> Iterator[TraceRequest]:
-    """The serving_bench request mix as a trace (ISSUE 16 satellite):
-    a modest pool of 12 distinct prompt lengths — enough variety that
-    an exact-shape baseline keeps recompiling, small enough that a full
-    run finishes on CPU — with small output budgets. `at` is 0 for all:
-    the closed-loop bench drives its own schedule."""
-    rng = random.Random(f"bench_mix:{seed}")
-    lengths = rng.sample(range(4, 49), 12)
-    news = [4, 6, 8]
-    for i in range(n):
-        yield TraceRequest(
-            i=i, at=0.0,
-            prompt_len=rng.choice(lengths),
-            max_new=rng.choice(news),
-            seed=i, prompt_seed=rng.randrange(1 << 31),
-        )
-
-
-def single_shape(seed: int, *, n: int = 150, rps: float = 0.0,
-                 prompt_len: int = 16, max_new: int = 24,
-                 deadline_ms: Optional[float] = None) -> Iterator[TraceRequest]:
-    """The overload-bench workload as a trace: one fixed shape (one
-    bucket, one compile), so capacity is a pure decode-rate property.
-    `rps=0` leaves scheduling to the caller (the bench computes offsets
-    from its own calibrated capacity)."""
-    rng = random.Random(f"single_shape:{seed}")
-    for i in range(n):
-        yield TraceRequest(
-            i=i, at=(i / rps) if rps > 0 else 0.0,
-            prompt_len=prompt_len, max_new=max_new,
-            seed=i, prompt_seed=rng.randrange(1 << 31),
-            deadline_ms=deadline_ms,
-        )
-
-
 GENERATORS = {
     "diurnal": diurnal,
     "bursts": bursts,
@@ -368,8 +330,6 @@ GENERATORS = {
     "shared_prefix": shared_prefix,
     "disconnect_storm": disconnect_storm,
     "tenant_storm": tenant_storm,
-    "bench_mix": bench_mix,
-    "single_shape": single_shape,
 }
 
 

@@ -6,9 +6,9 @@ idiom `scheduler/sim.py` proved for the fleet scheduler, pointed at the
 serving stack. Simulated replicas are driven by measured per-phase
 costs (`PhaseCosts`: prefill-per-token, decode-step, per-batch
 overhead) fitted from real `/metricsz` scrapes, so a multi-hour
-million-request soak runs in seconds of wall time while the real stack
-validates the twin's shed-rate and latency predictions at small scale
-(`benchmarks/scenario_bench.py` pins `sim_vs_real_calibration_error`).
+million-request soak runs in seconds of wall time. How far its
+shed-rate and latency predictions are from the real stack's is not
+measured by anything in the tree.
 
 What the twin models — deliberately at batch granularity, the level the
 measured costs live at:

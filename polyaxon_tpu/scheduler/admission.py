@@ -30,7 +30,7 @@ AdmissionController:
   on a following pass once the chips are back.
 
 All timing goes through scheduler/clock.py, so the same controller runs
-deterministically under SimClock in benchmarks/scheduler_bench.py.
+deterministically under SimClock in scheduler/sim.py.
 """
 
 from __future__ import annotations

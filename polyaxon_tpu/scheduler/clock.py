@@ -1,11 +1,11 @@
 """The scheduler's ONE time source: wall clock in production, a stepped
-SimClock in the scheduler benchmark and tests.
+SimClock in the simulator and the tests.
 
 Every piece of scheduling arithmetic (queue wait, reservation age, event
 ordering in the simulator) reads `clock.time()` from an injected Clock —
 never `time.time()` directly. That keeps the fleet scheduler fully
-deterministic under simulation (benchmarks/scheduler_bench.py replays a
-seeded workload through SimClock) and is enforced by
+deterministic under simulation (`scheduler/sim.py` replays a seeded
+workload through SimClock) and is enforced by
 scripts/lint_telemetry.py: `time.time(`/`time.monotonic(` are forbidden
 inside polyaxon_tpu/scheduler/ outside this module.
 

@@ -9,11 +9,11 @@ simulated: an admitted job "runs" for its remaining duration and a
 preempted job checkpoints its progress at the eviction instant, exactly
 like the trainer's step-boundary checkpoint.
 
-Used by benchmarks/scheduler_bench.py (seeded synthetic workloads →
-makespan / wait percentiles / utilization / preemption count) and by the
-acceptance tests (invariants asserted at EVERY event: quotas never
-exceeded at any instant, reservations all-or-nothing, preempted runs
-resume from checkpoint and finish).
+Used by the acceptance tests (tests/test_fleet.py): seeded synthetic
+workloads → makespan / wait percentiles / utilization / preemption
+count, with invariants asserted at EVERY event: quotas never exceeded at
+any instant, reservations all-or-nothing, preempted runs resume from
+checkpoint and finish.
 """
 
 from __future__ import annotations
@@ -78,16 +78,12 @@ class FleetSimulator:
         quotas: Optional[list] = None,
         home=None,
         invariant_fn=None,
-        durable_store: bool = True,
     ):
         import tempfile
 
         self.clock = SimClock()
         self.home = home or tempfile.mkdtemp(prefix="polyaxon-sim-")
-        # durable_store=False skips the event log's fsyncs: benchmark
-        # population of 10k-run workloads is IO-bound on fsync, and the
-        # scheduling decisions under test are identical either way
-        self.store = RunStore(self.home, eventlog_fsync=durable_store)
+        self.store = RunStore(self.home)
         self.fleet = Fleet(self.store, clock=self.clock)
         self.fleet.configure(topology=topology, chips=chips)
         self.quotas = QuotaManager(self.store)

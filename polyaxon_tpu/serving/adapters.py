@@ -24,8 +24,8 @@ coalesced decode group mixes tenants; this module owns the slots:
 
 Adapter sources are either an `.npz` file (keys = slash-joined param
 paths, e.g. ``layer_0/attention/q_proj/lora_a``; `save_adapter` writes
-this format) or the deterministic synthesizer ``seed:<int>`` (tests,
-benches and the TPU canary use it — same seed, same bytes, anywhere).
+this format) or the deterministic synthesizer ``seed:<int>`` (tests
+use it — same seed, same bytes, anywhere).
 
 The device-resident copy of an adapter IS its slot slice of the stacked
 params — the registry never holds a second device copy. It reads/writes

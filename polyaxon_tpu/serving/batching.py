@@ -557,7 +557,7 @@ class DecodeCoalescer:
         # or in flight) — the admission bound and the drain/idle signal
         self._count_lock = threading.Lock()
         self._outstanding = 0
-        # occupancy + resilience telemetry (read by /statsz and benches)
+        # occupancy + resilience telemetry (read by /statsz)
         self.batches_run = 0
         self.rows_run = 0
         self.shed_total = 0

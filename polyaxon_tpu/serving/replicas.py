@@ -15,7 +15,7 @@ The module is deliberately jax-free: replicas are opaque lifecycle
 handles. Two shapes are provided —
 
 - `InProcessReplica`: a ModelServer born from a factory in this
-  process. The test/bench correctness shape (the GIL serializes decode
+  process. The tests' correctness shape (the GIL serializes decode
   across in-process replicas, so it proves routing/failover semantics,
   not throughput).
 - `SubprocessReplica`: a child process started from an argv factory

@@ -301,8 +301,7 @@ class Router:
         )
         # stitching happens at READ time (`tracez`), never on the
         # serving path: the remote /tracez fetch is paid by the operator
-        # looking at a trace, not by the request being traced (the ≤5%
-        # federation overhead budget in benchmarks/serving_bench.py).
+        # looking at a trace, not by the request being traced.
         self._stitch_lock = threading.Lock()
         self._httpd: Optional[ThreadingHTTPServer] = None
         self._thread: Optional[threading.Thread] = None

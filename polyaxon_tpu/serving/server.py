@@ -473,8 +473,7 @@ class ModelServer:
             self._mesh.shape.get("model", 1) if self._mesh is not None else 1
         )
         # paged KV + streaming series (ISSUE 6) — registered from startup
-        # (zeros when the pool is off) so the canary's KV gate can scrape
-        # them unconditionally
+        # (zeros when the pool is off) so a scraper finds them either way
         self._m_kv_total = self.telemetry.gauge(
             "serving.kv_pages_total",
             help="KV page pool capacity (0 = dense per-group caches)",
@@ -498,8 +497,7 @@ class ModelServer:
             help="Requests that found no cached KV prefix",
         )
         # tiered prefix spill series (ISSUE 17) — registered from startup
-        # (zeros when spill is off) so the canary's affinity gate can
-        # scrape them unconditionally
+        # (zeros when spill is off) so a scraper finds them either way
         self._m_spill_bytes = self.telemetry.counter(
             "serving.kv_spill_bytes",
             help="Bytes of evicted KV prefixes accepted into the spill "
@@ -516,8 +514,7 @@ class ModelServer:
             "served as clean misses",
         )
         # live KV handoff series (ISSUE 20) — registered from startup
-        # (zeros when pools are off) so the canary's handoff gate can
-        # scrape them unconditionally
+        # (zeros when pools are off) so a scraper finds them either way
         self._m_handoff_ms = self.telemetry.histogram(
             "serving.kv_handoff_ms",
             buckets=(1, 2, 5, 10, 25, 50, 100, 250, 500, 1000, 2500, 5000),
@@ -557,8 +554,8 @@ class ModelServer:
             "kv_pages_prefix_held in drain accounting",
         )
         # fast-decode series (ISSUE 8) — registered from startup (zeros
-        # when speculation/quant are off) so the canary's spec gate can
-        # scrape them unconditionally
+        # when speculation/quant are off) so a scraper finds them either
+        # way
         self._m_spec_proposed = self.telemetry.counter(
             "serving.spec_proposed",
             help="Draft tokens proposed to speculative verify windows",
@@ -601,8 +598,8 @@ class ModelServer:
             "sampled token; whole-decode on the dense path)",
         )
         # chunked prefill + step scheduling series (ISSUE 14) — registered
-        # from startup (zeros when chunking is off) so the canary's
-        # chunked-prefill gate can scrape them unconditionally
+        # from startup (zeros when chunking is off) so a scraper finds
+        # them either way
         self._m_prefill_chunks = self.telemetry.counter(
             "serving.prefill_chunks",
             help="Prefill slices executed by the step scheduler "
@@ -1202,7 +1199,7 @@ class ModelServer:
     # ------------------------------------------------------------ tracing
     def _new_trace(self, rid: str, **attrs) -> Optional[RequestTrace]:
         """A RequestTrace for this request id, or None when tracing is
-        off (config.trace=False — the benchmarked fast-path toggle)."""
+        off (config.trace=False)."""
         if not self.config.trace:
             return None
         return RequestTrace(rid, **attrs)

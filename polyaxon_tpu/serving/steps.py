@@ -153,7 +153,7 @@ class StepScheduler(DecodeCoalescer):
         self._classic: deque[PendingRequest] = deque()
         self._starved = False  # budget excluded prefill last step
         self._classic_waits = 0  # steppable steps run while classic waited
-        # step telemetry (read by /statsz and the interference bench)
+        # step telemetry (read by /statsz)
         self.steps_run = 0
         self.prefill_only_steps = 0
         self.classic_forced_steps = 0

@@ -236,7 +236,7 @@ class EventLog:
         # vanish (size < offset), never change — checked on every heal.
         self._index_good = 0
         self._batcher = _Batcher(self._flush)
-        # introspection for tests/benchmarks
+        # introspection for tests
         self.appends = 0
         self.fsyncs = 0
         from ..telemetry import get_registry

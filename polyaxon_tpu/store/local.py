@@ -64,12 +64,7 @@ STORE_FORMAT = "2"
 
 
 class RunStore:
-    def __init__(
-        self,
-        home: Optional[Path | str] = None,
-        *,
-        eventlog_fsync: Optional[bool] = None,
-    ):
+    def __init__(self, home: Optional[Path | str] = None):
         self.home = Path(home) if home else polyaxon_home()
         self.runs_dir = self.home / "runs"
         self.runs_dir.mkdir(parents=True, exist_ok=True)
@@ -80,9 +75,8 @@ class RunStore:
             with contextlib.suppress(OSError):
                 stamp.write_text(STORE_FORMAT + "\n")
         self._eventlog = None
-        self._eventlog_fsync = eventlog_fsync
-        # O(runs) listing counter: the scheduler-bench no-directory-scan
-        # assertion pins this to zero growth in steady state
+        # O(runs) listing counter: tests pin it to zero growth in steady
+        # state (timelines and cursors read the log, not the directories)
         self.scans = 0
 
     # ----------------------------------------------------------- event log
@@ -98,7 +92,6 @@ class RunStore:
                 self.home,
                 wall=time.time,
                 mono=_mono,
-                fsync=self._eventlog_fsync,
                 view_writer=self._write_view,
             )
         return self._eventlog
@@ -210,6 +203,9 @@ class RunStore:
         self, run_uuid: str, status: str, reason: str = "", message: str = ""
     ):
         self._ensure_migrated(run_uuid)
+        # callers pass the enum: the record this process caches must be
+        # the plain string a reader of the log gets
+        status = V1Statuses(status).value
 
         def _validate(doc: dict) -> None:
             current = doc.get("status")
@@ -238,7 +234,7 @@ class RunStore:
         reg.counter(
             "runs.transitions", help="Run status transitions, all statuses"
         ).inc()
-        reg.counter(f"runs.transitions.{V1Statuses(status).value}").inc()
+        reg.counter(f"runs.transitions.{status}").inc()
         # chips never outlive the lifecycle: EVERY terminal transition —
         # succeeded, failed, stopped, skipped — drops the run's gang
         # reservation, whichever process drove the run there

@@ -24,8 +24,7 @@ monitor wrote straight to the store. Everything now flows through:
 - `compiles` — the one listener on JAX's compile events: `xla.programs`,
   `xla.traces`, `xla.lowerings` and their seconds, for `/statsz` `xla`
   and the trainer's log.
-- `quantile`/`summarize` — the one exact-percentile implementation
-  (benchmarks used to each carry their own).
+- `quantile` — the one exact-percentile implementation.
 - `now()` — the sanctioned monotonic clock for metrics timing. No other
   module in the package may call `time.perf_counter()` directly
   (enforced by scripts/lint_telemetry.py and tests/test_telemetry.py).
@@ -78,8 +77,6 @@ from .stats import (
     mfu,
     quantile,
     required_train_step_flops,
-    summarize,
-    train_step_flops,
 )
 from .tracing import RequestTrace, TraceRing, new_trace_id, tracez_payload
 
@@ -116,6 +113,4 @@ __all__ = [
     "now",
     "quantile",
     "required_train_step_flops",
-    "summarize",
-    "train_step_flops",
 ]

@@ -1,16 +1,14 @@
-"""Exact percentile math + the analytic train-step FLOPs formula.
+"""Exact percentile math + the program's count of a train step's work.
 
-The ONE implementation of sample quantiles for the repo: benchmarks
-(`benchmarks/_timing.py`, `benchmarks/serving_bench.py`) and the
-registry's `/statsz` summaries used to each hand-roll their own (median
-here, `sorted[int(0.95*(n-1))]` there) — close enough to agree on large
-samples, different enough to diverge on the small ones CI runs.
+The ONE implementation of exact sample quantiles for the repo (the
+scenario driver's and the twin's reports read `quantile`; histograms
+estimate theirs from bucket counts in `registry.py`).
 
-`train_step_flops` is the analytic transformer fwd+bwd cost shared by
-bench.py and the trainer's MFU gauge: 6·N FLOPs per token for the
-parameter matmuls plus the 12·L·d·s attention-score term. (XLA's
-cost_analysis would need a second full compile of the step — minutes of
-bench time for a number this formula gives within a few percent.)
+`required_train_step_flops` is the program's one count of what an
+optimizer step requires; the trainer's `train.mfu` gauge divides it by
+the step time and the chip's peak (`mfu`). (XLA's cost_analysis would
+need a second full compile of the step for a number this gives
+analytically.)
 """
 
 from __future__ import annotations
@@ -21,7 +19,7 @@ from typing import Optional, Sequence
 def quantile(values: Sequence[float], q: float) -> Optional[float]:
     """Exact sample quantile with linear interpolation between order
     statistics (numpy's default / type-7), q in [0, 1]. None on empty
-    input rather than raising — benchmark tails are often empty."""
+    input rather than raising — a tail sample is often empty."""
     if not values:
         return None
     if not 0.0 <= q <= 1.0:
@@ -32,28 +30,6 @@ def quantile(values: Sequence[float], q: float) -> Optional[float]:
     hi = min(lo + 1, len(s) - 1)
     frac = pos - lo
     return s[lo] * (1.0 - frac) + s[hi] * frac
-
-
-def summarize(values: Sequence[float]) -> dict:
-    """count/mean/p50/p95/p99 of a sample — the benchmark reporting
-    shape."""
-    n = len(values)
-    return {
-        "count": n,
-        "mean": (sum(values) / n) if n else None,
-        "p50": quantile(values, 0.5),
-        "p95": quantile(values, 0.95),
-        "p99": quantile(values, 0.99),
-    }
-
-
-def train_step_flops(
-    n_params: int, n_layers: int, dim: int, seq_len: int, tokens: int
-) -> float:
-    """Analytic transformer train-step FLOPs for `tokens` tokens."""
-    return float(
-        (6 * n_params + 12 * n_layers * dim * seq_len) * tokens
-    )
 
 
 def required_train_step_flops(
@@ -72,8 +48,8 @@ def required_train_step_flops(
     its own gradient too (6); attention's QK^T and PV over the causal
     triangle (diagonal included), backward twice the forward.
     `frozen`/`trainable` count weights that enter a product; `attn_width`
-    is heads x head size. 6 per weight for all of a LoRA model, as
-    `train_step_flops` has it, overstates the requirement by half."""
+    is heads x head size. 6 per weight for all of a LoRA model would
+    overstate the requirement by half."""
     pairs = seq_len * (seq_len + 1) / 2 if causal else seq_len * seq_len
     attn_fwd = n_layers * (tokens / seq_len) * 2 * 2 * pairs * attn_width
     return float(4.0 * frozen * tokens + 6.0 * trainable * tokens + 3.0 * attn_fwd)

@@ -1,6 +1,6 @@
 """TPU hardware facts: per-chip peak bf16 matmul FLOPs by device kind
-(public spec sheets). One shared copy for every MFU computation
-(bench.py, benchmarks/run_baselines.py, monitors)."""
+(public spec sheets). The program's one copy: `telemetry.stats.mfu`
+divides by it for the trainer's `train.mfu` gauge."""
 
 from __future__ import annotations
 

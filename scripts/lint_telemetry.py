@@ -37,7 +37,7 @@ saves/uploads/restores purely by step number, and the one duration that
 matters — the step-loop checkpoint stall — is measured by the trainer's
 span tree on the telemetry clock (`trainer_checkpoint_stall_ms`). A raw
 `time.*()` read inside the tier machinery would grow a second stall
-clock that can disagree with the histogram the canary gates on, so any
+clock that can disagree with the histogram /metricsz serves, so any
 `time.time/monotonic/perf_counter` (and `_ns` variants) there is
 forbidden.
 
@@ -56,7 +56,7 @@ in either module is forbidden — logical generation index only.
 Seventh rule: the SLO/trace layer itself uses only the injected
 telemetry clock. `polyaxon_tpu/telemetry/slo.py` (burn-rate windows)
 and `polyaxon_tpu/telemetry/tracing.py` (request span timelines) are
-the modules whose OUTPUT the canary gates on; a raw `time.*()` read
+the modules whose OUTPUT alerting reads; a raw `time.*()` read
 there would mix wall-clock (NTP steps, DST) into burn windows and span
 durations — the exact drift this lint exists to prevent. They must take
 time from `registry.now` (or an injected `clock=` callable), so any
@@ -204,9 +204,8 @@ server layer on the telemetry clock. Any direct `time.time/monotonic/
 perf_counter/sleep` (and `_ns` variants) or `datetime.now/utcnow/
 today` call in that file is forbidden.
 
-Scope is the package only. Benchmarks, tests, and top-level scripts own
-their methodology (e.g. benchmarks/_timing.py subtracts its sync's own
-round trip) and are exempt.
+Scope is the package only. The benchmark (`cellbench/`), tests and
+top-level scripts own their methodology and are exempt.
 
     python scripts/lint_telemetry.py        # exit 0 clean, 1 with hits
 """

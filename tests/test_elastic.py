@@ -447,8 +447,14 @@ class TestElasticChaos:
         resumed = _events(store, compiled.run_uuid, "resumed")
         assert resumed
         assert resumed[0]["step"] >= upload_step
-        assert steps - resumed[0]["step"] <= every
-        assert store.read_metrics(compiled.run_uuid)[-1]["step"] == steps
+        # WHICH later boundary save surfaces the uploader's death is a race
+        # between the upload and the step loop; that save lands first
+        # (CheckpointTiers.save), so the resume is from that boundary's
+        # local copy and no step is trained twice
+        assert resumed[0]["tier"] == "local"
+        assert resumed[0]["step"] % every == 0
+        logged = [r["step"] for r in store.read_metrics(compiled.run_uuid)]
+        assert logged == sorted(set(logged)) and logged[-1] == steps
         # the durable tier never lists a torn step — no staging residue
         durable = str(store.outputs_dir(compiled.run_uuid) / "checkpoints")
         assert not any(

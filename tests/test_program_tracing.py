@@ -426,7 +426,6 @@ def test_trainer_mfu_counts_required_work_as_the_benchmark_does(stacking):
     from cellbench.common import load_cell
     from polyaxon_tpu.runtime.trainer import Trainer
     from polyaxon_tpu.schemas.run_kinds import V1Program
-    from polyaxon_tpu.telemetry import train_step_flops
 
     _, _, cell, config = load_cell("internlm2-1.8b.lora-train-2k", rehearse=True)
     spec = cell["program"]
@@ -447,10 +446,5 @@ def test_trainer_mfu_counts_required_work_as_the_benchmark_does(stacking):
         )["total"]
         assert trainer._tokens_per_step == rows * seq
         assert trainer._flops_per_step == pytest.approx(want, rel=1e-12)
-        # what the gauge used before: 6 per parameter, the embedding too
-        n = sum(x.size for x in jax.tree.leaves(trainer.state.params))
-        m = config["model"]
-        old = train_step_flops(n, m["n_layers"], m["dim"], seq, rows * seq)
-        assert old > 1.3 * want
     finally:
         trainer.close()

@@ -41,6 +41,7 @@ from polyaxon_tpu.serving.router import (
     Router,
     parse_prometheus,
 )
+from polyaxon_tpu.telemetry import now
 
 pytestmark = pytest.mark.serving
 
@@ -207,7 +208,9 @@ def test_autoscale_scale_up_cooldown_and_clamp():
         autoscale=AutoscalePolicy(max_replicas=3, cooldown_s=3600.0),
     )
     assert r.slo_engine is not None  # shed-burn objective is armed
-    r._last_scale_t = 0.0
+    # on the router's own clock, whose zero is the host's boot: 0.0 would
+    # be "inside the cooldown" on any host up for less than an hour
+    r._last_scale_t = now() - 2 * 3600.0
     r._scale_up({"slo": "router-upstream-shed"})
     assert sc.calls == [2]
     r._scale_up({})  # inside cooldown: ignored
@@ -227,7 +230,7 @@ def test_autoscale_calm_window_scales_down():
         ),
     )
     r.states()[0].healthy = True  # idle, zero queue → calm
-    r._last_scale_t = 0.0
+    r._last_scale_t = now() - 1.0
     r._autoscale_tick()  # opens the calm window
     assert sc.calls == []
     time.sleep(0.1)

@@ -18,7 +18,6 @@ import threading
 import time
 import urllib.error
 import urllib.request
-from pathlib import Path
 
 import pytest
 
@@ -36,7 +35,6 @@ from polyaxon_tpu.serving.batching import (
 
 pytestmark = pytest.mark.serving
 
-REPO = Path(__file__).resolve().parent.parent
 
 KEY = GroupKey(32, 16, 0.8, 40, None)
 
@@ -763,27 +761,3 @@ def test_read_json_quarantine_shields_run_status(tmp_home):
     status = store.get_status(uuid)  # would raise before the quarantine
     assert status == {}
     assert (store.run_dir(uuid) / "status.json.corrupt").exists()
-
-
-# ------------------------------------------------------- bench smoke (CI)
-def test_overload_bench_smoke(tmp_path):
-    import subprocess
-    import sys
-
-    out = tmp_path / "metricsz.txt"
-    proc = subprocess.run(
-        [sys.executable, str(REPO / "benchmarks/serving_overload_bench.py"),
-         "--smoke", "--requests", "24", "--metricsz-out", str(out)],
-        capture_output=True, text=True, timeout=600,
-        env={**__import__("os").environ, "JAX_PLATFORMS": "cpu"},
-    )
-    assert proc.returncode == 0, proc.stdout + proc.stderr
-    rec = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert rec["metric"] == "serving_overload_goodput"
-    assert rec["pass"] is True
-    assert rec["hung"] == 0
-    assert rec["shed_503"] + rec["deadline_504"] > 0
-    text = out.read_text()
-    for series in ("serving_shed_total", "serving_deadline_exceeded_total",
-                   "serving_breaker_state", "serving_ready"):
-        assert series in text, f"missing {series} on /metricsz"

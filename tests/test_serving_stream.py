@@ -6,7 +6,7 @@
     returns identical tokens;
   * `POST /generate?stream=1` SSE: prompt + concatenated chunks equals
     the non-streamed result, delivered incrementally;
-  * TTFT / page-pool / prefix-cache series on /metricsz (the canary gate);
+  * TTFT / page-pool / prefix-cache series on /metricsz;
   * pool exhaustion sheds 503 with reason "kv_pages" through the PR 5
     admission path without crashing the worker, and never-fits is a 400;
   * no leaked pages or reservations once traffic drains.

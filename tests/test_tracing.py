@@ -664,7 +664,7 @@ def test_slo_breach_flips_sloz_and_writes_flight_recorder(
         assert s["name"] == "availability" and s["breached"]
         assert s["burn_rate"] > 1.0 and s["bad"] >= 4
         assert set(s["burn_rates"]) == {"5s", "30s"}
-        # the gauges reach /metricsz for the canary + alerting
+        # the gauges reach /metricsz for alerting
         st, text = _get(port, "/metricsz")
         text = text.decode()
         assert "slo_burn_rate" in text and "slo_breached 1" in text
