@@ -60,6 +60,13 @@ class ModelBundle:
     # computes the loss from pre-head features — the [B, S, V] logits
     # never materialize (ops/losses.fused_linear_masked_lm).
     fused_loss: Optional[Callable] = None
+    # The rungs of the Trainer's remat ladder (`train.remat: true`) that the
+    # module understands beyond "all", most kept first: each is a value of a
+    # static `keep=` argument of `module.__call__` that says what the backward
+    # finds kept ("block": each block's input, the block run again from it).
+    # Empty: the module names no block boundary, and the ladder's second rung
+    # is a checkpoint of the whole apply.
+    keep_rungs: tuple[str, ...] = ()
 
 
 def register(name: str):

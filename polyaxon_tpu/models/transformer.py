@@ -13,6 +13,10 @@ model is in-repo and TPU-shaped:
   degrade to replication on meshes without those axes (parallel/sharding.py).
 - `scan_layers`: stack the blocks with `nn.scan` so compile time is O(1) in
   depth (XLA sees one block body; params gain a leading layer axis).
+- `keep=` of `__call__` (static, training): what the backward finds kept.
+  `"all"` what the forward left; `"block"` each block's input only, the
+  block (or the scan body) run again from it under `nn.remat`. The Trainer
+  chooses it under `train.remat: true` from what the device holds.
 - Attention backend selectable: `xla` (einsum softmax, fine for short seq),
   `flash` (Pallas blockwise kernel, ops/flash_attention.py), `ring`, `ulysses`
   (context-parallel blockwise over the `context` axis, parallel/ring.py).
@@ -916,6 +920,11 @@ class PipelinedLayers(nn.Module):
         return h
 
 
+# The rungs of the Trainer's remat ladder that `Transformer.__call__(keep=)`
+# understands beyond "all", most kept first (`ModelBundle.keep_rungs`).
+KEEP_RUNGS = ("block",)
+
+
 class Transformer(nn.Module):
     cfg: TransformerConfig
 
@@ -927,6 +936,9 @@ class Transformer(nn.Module):
         train: bool = False,
         decode: bool = False,
         return_features: bool = False,
+        keep: str = "all",  # what the backward finds kept (the Trainer's
+        # remat ladder, KEEP_RUNGS): "all" = what the forward left, "block" =
+        # each block's input, the block run again from it
         pad=None,  # [B] left-pad widths for bucketed decode (serving path)
         pages=None,  # [B, n_pages] page table → block-paged KV decode
         pos=None,  # traced int32 scalar (or [B] per-row speculative
@@ -984,11 +996,15 @@ class Transformer(nn.Module):
         # weakness #2). An explicit constraint at the producer turns it
         # into one all-gather over fsdp at a well-defined point.
         x = constrain(x, BATCH, "context", None)
+        if keep not in ("all", *KEEP_RUNGS):
+            raise ValueError(f"keep={keep!r}: one of 'all', {KEEP_RUNGS}")
         if cfg.pipeline_stages > 1:
             x = PipelinedLayers(cfg, name="pipeline")(x)
         elif cfg.scan_layers:
             Layers = nn.scan(
-                _ScanBlock,
+                # the scan keeps each iteration's residuals apart already
+                nn.remat(_ScanBlock, prevent_cse=False)
+                if keep == "block" else _ScanBlock,
                 variable_axes={"params": 0, "losses": 0, "cache": 0},
                 split_rngs={"params": True, "dropout": True},
                 length=cfg.n_layers,
@@ -1020,8 +1036,9 @@ class Transformer(nn.Module):
             else:
                 x, _ = layers(x, None)
         else:
+            block_cls = nn.remat(Block) if keep == "block" else Block
             for i in range(cfg.n_layers):
-                x = Block(
+                x = block_cls(
                     cfg, train, decode,
                     kv_layout=kv_layout, prefix_len=prefix_len,
                     index=i,
@@ -1324,6 +1341,9 @@ def build_transformer(config: dict) -> ModelBundle:
         aux_losses=cfg.n_experts > 0 and cfg.moe_aux_weight > 0,
         step_metrics=moe_step_metrics if cfg.n_experts > 0 else None,
         fused_loss=fused,
+        # the pipelined stack applies its blocks functionally, in stages: no
+        # block boundary that `keep=` could name
+        keep_rungs=() if cfg.pipeline_stages > 1 else KEEP_RUNGS,
     )
 
 

@@ -11,13 +11,15 @@ in user containers behind Kubeflow CRDs). TPU-first design decisions:
 - Sharding via NamedShardings from model-declared logical rules
   (parallel/sharding.py); init runs under jit with `out_shardings`, so params
   materialize directly on their devices — no host-side full copy.
-- Optional `jax.checkpoint` (remat) over the model apply to trade FLOPs for
-  HBM when activations don't fit.
+- `train.remat: true` recomputes as little as the device allows: the step
+  is compiled on a short ladder of rungs (`_RematLadder`), most kept first,
+  and the first that the device's compiler accepts runs.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 from pathlib import Path
 from typing import Any, Callable, Optional
 
@@ -107,6 +109,86 @@ def make_param_init(bundle, param_dtype, example):
         return params, extra
 
     return init_fn
+
+
+class _RematLadder:
+    """`train_step` under `train.remat: true`: the step of every rung, ordered
+    by recomputation, and the executable of the first whose compile the
+    device's compiler accepts.
+
+    The choice is made at the first call (or the first `lower`), from the
+    shapes that call brings, by compiling ahead of time; the executable that
+    compile gave is what every call then runs, so where the first rung fits
+    the step is traced, lowered and compiled once, as a plain `jax.jit` would.
+    Like any compiled step it takes one shape of state and batch. A rung is
+    refused only by the compiler's own RESOURCE_EXHAUSTED, which comes before
+    anything ran and so before any state was donated; every other error is
+    raised as it is, and when every rung is refused, the last refusal.
+
+    Every process of a multi-host job compiles the same program for the same
+    kind of device, so all land on the same rung without a collective."""
+
+    def __init__(self, steps: dict, report: Callable[[dict], None]):
+        self.steps = steps  # rung -> the `jax.jit` of its step
+        self.rung: Optional[str] = None
+        self.tried: list[dict] = []
+        self._report = report
+        self._compiled = None
+
+    def attempt(self, rung: str, state, batch):
+        """(what the compiler answered, the executable or its refusal):
+        `fits` with the compiler's bytes, or `refused` with its first line
+        and the seconds the refused compile cost."""
+        t0 = _now()
+        lowered = self.steps[rung].lower(state, batch)
+        try:
+            compiled = lowered.compile()
+        except jax.errors.JaxRuntimeError as e:
+            if "RESOURCE_EXHAUSTED" not in str(e):
+                raise
+            return {
+                "rung": rung, "result": "refused",
+                "seconds": round(_now() - t0, 3),
+                "compiler": str(e).strip().splitlines()[0][:400],
+            }, e
+        return {"rung": rung, "result": "fits", "bytes": _step_bytes(compiled)}, compiled
+
+    def _choose(self, state, batch):
+        for rung in self.steps:
+            answer, outcome = self.attempt(rung, state, batch)
+            self.tried.append(answer)
+            if answer["result"] == "fits":
+                self.rung, self._compiled = rung, outcome
+                break
+        self._report(
+            {"rung": self.rung, "ladder": list(self.steps), "tried": self.tried}
+        )
+        if self.rung is None:
+            raise outcome  # every rung refused: the last refusal
+
+    def __call__(self, state, batch):
+        if self._compiled is None:
+            self._choose(state, batch)
+        return self._compiled(state, batch)
+
+    def lower(self, state, batch):
+        """The lowering of the rung that runs, chosen first (from these
+        shapes) where no step has run yet."""
+        if self._compiled is None:
+            self._choose(state, batch)
+        return self.steps[self.rung].lower(state, batch)
+
+
+def _step_bytes(compiled) -> Optional[int]:
+    """The compiler's total for an executable on one device, where the
+    backend gives one."""
+    ma = compiled.memory_analysis()
+    if ma is None:
+        return None
+    return int(
+        ma.argument_size_in_bytes + ma.output_size_in_bytes
+        + ma.temp_size_in_bytes - ma.alias_size_in_bytes
+    )
 
 
 class Trainer:
@@ -349,7 +431,6 @@ class Trainer:
 
         compute_dtype = self.compute_dtype
         loss_fn, tx, sched = self.loss_fn, self.tx, self.sched
-        use_remat = bool(tspec.remat)
         is_classification = bundle.task == "classification"
         seed = int(tspec.seed)
 
@@ -375,17 +456,17 @@ class Trainer:
         # (the [B,S,V] logits never materialize — ops/losses.py)
         apply_kw = {"return_features": True} if fused_loss is not None else {}
 
-        def apply(params, extra, inputs, rng):
+        def apply(keep_kw, params, extra, inputs, rng):
             rngs = {k: jax.random.fold_in(rng, i) for i, k in enumerate(bundle.rngs)}
             variables = {"params": params, **extra}
             if not collections:
                 logits = bundle.module.apply(
-                    variables, inputs, train=True, rngs=rngs, **apply_kw
+                    variables, inputs, train=True, rngs=rngs, **apply_kw, **keep_kw
                 )
                 return logits, {}, jnp.zeros((), jnp.float32), {}
             logits, updates = bundle.module.apply(
                 variables, inputs, train=True, rngs=rngs, mutable=collections,
-                **apply_kw
+                **apply_kw, **keep_kw
             )
             updates = dict(updates)
             sown = updates.pop("losses", {})
@@ -399,16 +480,36 @@ class Trainer:
             )
             return logits, updates, aux, stats
 
-        if use_remat or tspec.remat_policy:
-            policies = {
-                None: None,  # jax.checkpoint's default: save nothing
-                "nothing": jax.checkpoint_policies.nothing_saveable,
-                "dots": jax.checkpoint_policies.checkpoint_dots,
-                "dots_no_batch": (
-                    jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims
-                ),
-            }
-            apply = jax.checkpoint(apply, policy=policies[tspec.remat_policy])
+        # What the backward finds kept, by rung, most kept first. "all": no
+        # checkpoint, the compiler keeps what the backward reads. A rung the
+        # bundle offers (`keep_rungs`: "block") is a static argument of the
+        # module. "apply": the whole apply checkpointed, which holds what
+        # "all" holds while the forward runs again and so is never chosen
+        # for a module that names its blocks; it can still lower the peak of
+        # one that does not (a loss over unfused logits).
+        policies = {
+            None: None,  # jax.checkpoint's default: save nothing
+            "nothing": jax.checkpoint_policies.nothing_saveable,
+            "dots": jax.checkpoint_policies.checkpoint_dots,
+            "dots_no_batch": (
+                jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims
+            ),
+        }
+
+        def apply_of(rung):
+            fn = functools.partial(
+                apply, {"keep": rung} if rung in bundle.keep_rungs else {}
+            )
+            if rung == "apply":
+                fn = jax.checkpoint(fn, policy=policies[tspec.remat_policy])
+            return fn
+
+        if tspec.remat_policy:
+            rungs = ("apply",)  # an explicit policy is obeyed: no ladder
+        elif tspec.remat:
+            rungs = ("all", *(bundle.keep_rungs or ("apply",)))
+        else:
+            rungs = ("all",)  # no checkpoint, and a refusal is the user's
 
         param_dtype = self.param_dtype
 
@@ -464,7 +565,7 @@ class Trainer:
                 labels, trained, frozen,
             )
 
-        def grads_of(trained, frozen, extra, batch, rng):
+        def grads_of(apply, trained, frozen, extra, batch, rng):
             """One microbatch: (loss, grads, new_extra, logits, stats);
             `grads` mirrors `trained`, the differentiated half of the
             parameters; `stats` is what the module sowed for the step's
@@ -495,13 +596,13 @@ class Trainer:
             )(trained)
             return loss, grads, new_extra, logits, stats
 
-        def step_fn(state: TrainState, batch):
+        def step(apply, state: TrainState, batch):
             rng = jax.random.fold_in(jax.random.PRNGKey(seed), state.step)
             trained, frozen = split(state.params)
 
             if grad_accum == 1:
                 loss, grads, new_extra, logits, stats = grads_of(
-                    trained, frozen, state.extra, batch, rng
+                    apply, trained, frozen, state.extra, batch, rng
                 )
                 acc_metric = (
                     accuracy_metric(logits, batch) if is_classification else None
@@ -523,7 +624,8 @@ class Trainer:
                     # the sown stats of a microbatch are not carried: a step
                     # of several reports none
                     loss, grads, new_extra, logits, _ = grads_of(
-                        trained, frozen, extra_c, mb, jax.random.fold_in(rng, i)
+                        apply, trained, frozen, extra_c, mb,
+                        jax.random.fold_in(rng, i),
                     )
                     grads = _cast_floats(grads, param_dtype)
                     grads_c = jax.tree.map(jnp.add, grads_c, grads)
@@ -586,12 +688,26 @@ class Trainer:
             )
 
         donate = (0,) if tspec.donate_state else ()
-        self.train_step = jax.jit(
-            step_fn,
-            in_shardings=(state_shardings, self.b_shard),
-            out_shardings=(state_shardings, rep),
-            donate_argnums=donate,
-        )
+
+        def jit_step(rung):
+            apply = apply_of(rung)
+
+            def step_fn(state: TrainState, batch):  # the program `jit_step_fn`
+                return step(apply, state, batch)
+
+            return jax.jit(
+                step_fn,
+                in_shardings=(state_shardings, self.b_shard),
+                out_shardings=(state_shardings, rep),
+                donate_argnums=donate,
+            )
+
+        if len(rungs) == 1:
+            self.train_step = jit_step(rungs[0])
+        else:
+            self.train_step = _RematLadder(
+                {rung: jit_step(rung) for rung in rungs}, self._report_remat
+            )
 
         def eval_fn(state: TrainState, batch):
             params = (
@@ -872,6 +988,26 @@ class Trainer:
             "differentiated",
             {"trainable_params": trainable, "frozen_params": frozen},
         )
+
+    def _report_remat(self, choice: dict):
+        """Which rung of the remat ladder runs, and what each rung tried
+        cost: event `polyaxon.train.remat` (run store `remat`), gauges
+        `train.remat.rung` (0 = `all`; -1 = every rung refused) and
+        `train.remat.refused_compiles`. A set-up that grew reads from here."""
+        import json
+
+        refused = [t for t in choice["tried"] if t["result"] == "refused"]
+        ladder = choice["ladder"]
+        self.telemetry.gauge("train.remat.rung").set(
+            ladder.index(choice["rung"]) if choice["rung"] else -1
+        )
+        self.telemetry.gauge("train.remat.refused_compiles").set(len(refused))
+        self.tracer.event(
+            "remat", rung=choice["rung"] or "", ladder=json.dumps(ladder),
+            tried=json.dumps(choice["tried"]),
+            refused_seconds=round(sum(t["seconds"] for t in refused), 3),
+        )
+        self._event("remat", choice)
 
     def _report_layers(self):
         """What each layer of a decoder is (kind, heads, rope, dense or

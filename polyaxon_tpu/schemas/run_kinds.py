@@ -98,11 +98,17 @@ class V1TrainSpec(BaseSchema):
     resume: Optional[bool] = None
     seed: int | str = 0
     precision: Literal["bfloat16", "float32", "mixed"] = "mixed"
+    # true: recompute in the backward as little as the device allows. The
+    # Trainer compiles the step on a short ladder, most kept first, and runs
+    # the first rung the device's compiler accepts: no checkpoint at all,
+    # then one per transformer block (the whole apply for a model without
+    # blocks). Unset or false: no checkpoint, and a step that does not fit
+    # is refused
     remat: Optional[bool] = None
-    # what the backward pass may keep from the forward (jax.checkpoint
-    # policy): nothing = recompute all (max HBM savings), dots = keep matmul
-    # outputs (recompute cheap elementwise only — the usual TPU sweet spot),
-    # dots_no_batch = keep only non-batch matmuls (Megatron-style)
+    # an explicit policy is obeyed as it is, with no ladder: the whole apply
+    # in one jax.checkpoint that may keep nothing (recompute all), dots
+    # (matmul outputs; recompute elementwise only) or dots_no_batch (only
+    # non-batch matmuls, Megatron-style)
     remat_policy: Optional[Literal["nothing", "dots", "dots_no_batch"]] = None
     donate_state: bool = True
     loss: Optional[str] = None

@@ -3,17 +3,20 @@ compiler for a TPU v5e that is described, not attached: what the step needs
 of the device's memory (`memory_analysis`) and how long it takes to trace
 and lower, with no chip time. Nothing executes.
 
-    JAX_PLATFORMS=cpu python scripts/step_memory.py <cell> [--lower-only] [--repo <checkout>]
+    JAX_PLATFORMS=cpu python scripts/step_memory.py <cell> [--rungs] [--rows N] [--lower-only] [--repo <checkout>]
 
-`--repo` reads another checkout (a `git archive` of the parent, say), so two
-trees can be compared. The Trainer is built on the described device with
+Under `train.remat: true` the Trainer's step is a ladder of rungs (PR 32:
+`all`, then `block`): the line names the rung the Trainer chose for the
+described device and what each rung it tried answered; `--rungs` compiles
+every rung and gives the compiler's bytes or its refusal for each. `--rows N`
+sizes the step at N rows in place of the cell's. `--repo` reads another
+checkout (a `git archive` of the parent, say), so two trees can be compared.
+The Trainer is built on the described device with
 `jax.jit` and `jax.device_put` replaced, while it builds, by stand-ins that
 return shapes placed as the real calls would place arrays (a described
 device cannot hold one); the step itself is the Trainer's own `train_step`,
 lowered from those shapes. PR 29 found the result equal to the byte to what
-the chip's traced runs print; PR 30 read 15,889,743,872 -> 15,870,779,904
-(`internlm2-1.8b.lora-train-2k`) and 15,897,312,256 -> 15,895,892,992
-(`laguna-s-2.1-ep8.lora-train`) with it.
+the chip's traced runs print; `PERF.md` section 4 has the table read with it.
 """
 
 import argparse
@@ -31,6 +34,8 @@ def main() -> None:
     ap.add_argument("cell")
     ap.add_argument("--repo", default=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     ap.add_argument("--lower-only", action="store_true")
+    ap.add_argument("--rungs", action="store_true", help="compile every rung of the remat ladder")
+    ap.add_argument("--rows", type=int, help="rows a step, in place of the cell's")
     args = ap.parse_args()
     sys.path.insert(0, args.repo)
     os.chdir(args.repo)
@@ -72,6 +77,8 @@ def main() -> None:
         )
 
     cell = json.load(open(f"cellbench/workloads/{args.cell}.json"))
+    if args.rows:
+        cell["traffic"]["rows"] = cell["program"]["data"]["batchSize"] = args.rows
     ctx = argparse.Namespace(
         cell=cell, config=json.load(open(f"cellbench/configs/{cell['config']}.json")), seed=7
     )
@@ -88,18 +95,33 @@ def main() -> None:
         k: jax.ShapeDtypeStruct((rows, seq), jnp.int32, sharding=trainer.b_shard)
         for k in ("inputs", "labels")
     }
+    step = trainer.train_step
+    ladder = getattr(step, "steps", None)  # None: a plain `jax.jit`, no ladder
+    out = {"cell": args.cell, "repo": args.repo, "rows": rows, "build_s": round(build_s, 1)}
     t0 = time.time()
-    lowered = trainer.train_step.lower(trainer.state, batch)
-    out = {"cell": args.cell, "repo": args.repo, "build_s": round(build_s, 1),
-           "trace_and_lower_s": round(time.time() - t0, 1)}
-    if not args.lower_only:
-        ma = lowered.compile().memory_analysis()
-        out.update(
-            total=ma.argument_size_in_bytes + ma.output_size_in_bytes
-            + ma.temp_size_in_bytes - ma.alias_size_in_bytes,
-            arguments=ma.argument_size_in_bytes, temporaries=ma.temp_size_in_bytes,
-            aliased=ma.alias_size_in_bytes,
-        )
+    if ladder is None or args.lower_only:
+        lowered = (step if ladder is None else ladder["all"]).lower(trainer.state, batch)
+        out["trace_and_lower_s"] = round(time.time() - t0, 1)
+        if not args.lower_only:
+            ma = lowered.compile().memory_analysis()
+            out.update(
+                total=ma.argument_size_in_bytes + ma.output_size_in_bytes
+                + ma.temp_size_in_bytes - ma.alias_size_in_bytes,
+                arguments=ma.argument_size_in_bytes, temporaries=ma.temp_size_in_bytes,
+                aliased=ma.alias_size_in_bytes,
+            )
+    else:
+        try:
+            step.lower(trainer.state, batch)  # the Trainer's own choice, by compiling
+        except jax.errors.JaxRuntimeError:
+            pass  # every rung refused: `tried` has each refusal
+        out.update(ladder=list(ladder), rung=step.rung, tried=list(step.tried),
+                   choose_s=round(time.time() - t0, 1))
+        if args.rungs:  # and what the rungs under the chosen one would answer
+            out["tried"] += [
+                step.attempt(rung, trainer.state, batch)[0]
+                for rung in list(ladder)[len(step.tried):]
+            ]
     print(json.dumps(out))
 
 

@@ -541,6 +541,47 @@ def test_trainer_reports_the_tiles_its_flash_kernels_run():
         "flash_attention_fwd", "flash_attention_dq", "flash_attention_dkv"]
 
 
+def three_steps_on(rung):
+    """Three steps of one rung of the remat ladder (`train.remat: true`, as
+    the cell says) on the cell's seeded weights and feed: every metric of
+    every step (loss, gradient norm, what the routed layers sowed) and the
+    adapters after."""
+    cell, config = small()
+    ctx = ctx_for(cell, config)
+    trainer = one_chip_trainer(ctx)
+    cap = drv.capture(trainer)
+    drv.seed_state(trainer, cap, SEED, config["init"])
+    feed = drv.make_feed(ctx, trainer, SEED)
+    assert list(trainer.train_step.steps) == ["all", "block"]
+    step, metrics = trainer.train_step.steps[rung], []
+    for _ in range(3):
+        trainer.state, m = step(trainer.state, feed.get())
+        metrics.append(jax.device_get(m))
+    adapters = drv.trainable_leaves(trainer.state.params, cell["reference"]["trainable"])
+    feed.close()
+    trainer.close()
+    return metrics, adapters
+
+
+def test_a_checkpoint_per_block_takes_the_same_three_steps():
+    """Window and full layers, a routed MLP and `moe_stats` sown under the
+    checkpoint: rung `block` against rung `all`, in float32."""
+    want_metrics, want = three_steps_on("all")
+    got_metrics, got = three_steps_on("block")
+    assert {"loss", "grad_norm", "moe.assignments_local", "moe.overflow"} <= set(
+        want_metrics[0]
+    )
+    for w, g in zip(want_metrics, got_metrics):
+        assert set(g) == set(w)
+        for name in w:
+            np.testing.assert_allclose(g[name], w[name], rtol=1e-5, err_msg=name)
+    assert want and set(got) == set(want)
+    for path in want:
+        np.testing.assert_allclose(
+            got[path], want[path], rtol=1e-4, atol=1e-6, err_msg=path
+        )
+
+
 def test_trainer_stops_on_overflow():
     trainer = tiny_trainer([], steps=2, seq_len=256)
     # a router of noughts sends every token to experts 0 and 1, both held:

@@ -478,11 +478,11 @@ def test_data_model_shape_mismatch_is_clear():
 # then says whose weight gradient it is.
 
 
-def lora_program(lora=True, rows=2, **train_overrides):
+def lora_program(lora=True, rows=2, model=None, **train_overrides):
     cfg = {
         "dim": 64, "n_layers": 2, "n_heads": 4, "n_kv_heads": 2,
         "hidden_dim": 96, "vocab_size": 160, "seq_len": 24,
-        "fused_lm_loss": True,
+        "fused_lm_loss": True, **(model or {}),
     }
     if lora:
         cfg["lora"] = {
@@ -541,11 +541,13 @@ def _run_steps(trainer, batches):
     return metrics
 
 
-def _product_shapes(trainer, batch):
-    """Output shapes of every dot and convolution of the compiled step."""
+def _product_shapes(trainer, batch, step=None):
+    """Output shapes of every dot and convolution of the compiled step (the
+    Trainer's own, or `step`: one rung's)."""
     import re
 
-    text = trainer.train_step.lower(trainer.state, batch).compile().as_text()
+    step = step or trainer.train_step
+    text = step.lower(trainer.state, batch).compile().as_text()
     return {
         tuple(int(d) for d in dims.split(","))
         for dims, _ in re.findall(
@@ -708,3 +710,238 @@ def test_lora_grad_accum_matches_the_doubled_batch():
     # the accumulated gradient holds adapters only: no buffer of a frozen
     # kernel's shape is carried through the microbatch loop
     assert not _product_shapes(halves, batches[0]) & {(64, 64), (64, 96), (96, 64)}
+
+
+# --------------------------------------------------------------------------
+# `remat: true` keeps what the device can hold (PR 32): the step of every
+# rung of a short ladder, most kept first, and the first whose compile the
+# device's compiler accepts. On the CPU every rung fits, so a rung is run
+# through its own `jax.jit` (`train_step.steps[rung]`) and a refusal is a
+# stand-in's.
+
+
+class _StandInStep:
+    """What the ladder asks of a rung's `jax.jit`: `.lower(...).compile()`."""
+
+    def __init__(self, rung, error=None):
+        self.rung, self.error, self.lowerings = rung, error, 0
+
+    def lower(self, state, batch):
+        self.lowerings += 1
+        return self
+
+    def compile(self):
+        if self.error is not None:
+            raise self.error
+        return self
+
+    def memory_analysis(self):
+        return None
+
+    def __call__(self, state, batch):
+        return self.rung, state, batch
+
+
+def _refusal(rung):
+    return jax.errors.JaxRuntimeError(
+        f"RESOURCE_EXHAUSTED: XLA:TPU compile permanent error. Ran out of "
+        f"memory in memory space hbm ({rung}).\nUsed 19.13G of 15.75G hbm."
+    )
+
+
+@pytest.mark.parametrize("refused", [0, 1, 2, 3])
+def test_remat_ladder_lands_on_the_first_rung_that_compiles(refused):
+    from polyaxon_tpu.runtime.trainer import _RematLadder
+
+    rungs = ("all", "block", "apply")
+    steps = {
+        r: _StandInStep(r, _refusal(r) if i < refused else None)
+        for i, r in enumerate(rungs)
+    }
+    reports = []
+    ladder = _RematLadder(steps, reports.append)
+    if refused == len(rungs):
+        with pytest.raises(jax.errors.JaxRuntimeError, match=r"hbm \(apply\)"):
+            ladder("state", "batch")
+        assert ladder.rung is None
+    else:
+        assert ladder("state", "batch") == (rungs[refused], "state", "batch")
+        assert ladder.rung == rungs[refused]
+        ladder("state", "batch")  # chosen once: no rung is lowered again
+        assert ladder.lower("state", "batch") is steps[rungs[refused]]
+    (report,) = reports
+    assert report["rung"] == ladder.rung and report["ladder"] == list(rungs)
+    tried = report["tried"]
+    assert [t["rung"] for t in tried] == list(rungs[: refused + 1])
+    assert [t["result"] for t in tried] == (["refused"] * refused + ["fits"])[: len(rungs)]
+    for t in tried[:refused]:
+        assert t["seconds"] >= 0 and t["compiler"].startswith("RESOURCE_EXHAUSTED")
+        assert "\n" not in t["compiler"]
+    # each rung tried was lowered once (the one that runs once more, for
+    # `lower`), a rung below it never
+    want = [1] * len(tried) + [0] * (len(rungs) - len(tried))
+    if ladder.rung is not None:
+        want[refused] += 1
+    assert [s.lowerings for s in steps.values()] == want
+
+
+@pytest.mark.parametrize(
+    "error",
+    [
+        jax.errors.JaxRuntimeError("INTERNAL: Mosaic failed to compile TPU kernel"),
+        ValueError("RESOURCE_EXHAUSTED is the compiler's word, not a ValueError's"),
+    ],
+    ids=["another-status", "another-type"],
+)
+def test_remat_ladder_lets_any_other_error_through(error):
+    from polyaxon_tpu.runtime.trainer import _RematLadder
+
+    steps = {"all": _StandInStep("all", error), "block": _StandInStep("block")}
+    reports = []
+    with pytest.raises(type(error)) as raised:
+        _RematLadder(steps, reports.append)("state", "batch")
+    assert raised.value is error
+    assert steps["block"].lowerings == 0 and reports == []
+
+
+def test_trainer_reports_the_rung_that_runs():
+    import json
+
+    events = []
+    t = _lora_trainer(remat=True, events=events)
+    assert list(t.train_step.steps) == ["all", "block"]
+    assert not [e for e in events if e[0] == "remat"]  # chosen at the first step
+    _run_steps(t, _batches(t, 1))
+    ((_, body),) = [e for e in events if e[0] == "remat"]
+    assert body["rung"] == "all" and body["ladder"] == ["all", "block"]
+    assert [(a["rung"], a["result"]) for a in body["tried"]] == [("all", "fits")]
+    snap = t.telemetry.snapshot()
+    assert snap["train.remat.rung"] == 0
+    assert snap["train.remat.refused_compiles"] == 0
+    (mark,) = [r for r in t.tracer.recent(64) if r["name"] == "remat"]
+    assert mark["attrs"]["rung"] == "all"
+    assert json.loads(mark["attrs"]["tried"]) == body["tried"]
+
+
+@pytest.mark.parametrize(
+    "model,train,rungs",
+    [
+        ({}, {}, None),
+        ({}, {"remat": True}, ["all", "block"]),
+        ({"scan_layers": True}, {"remat": True}, ["all", "block"]),
+        ({"pipeline_stages": 2}, {"remat": True}, ["all", "apply"]),
+        ({}, {"remat": True, "rematPolicy": "dots"}, None),
+        ({}, {"rematPolicy": "nothing"}, None),
+    ],
+    ids=["off", "blocks", "scan", "pipelined", "policy-and-remat", "policy"],
+)
+def test_which_ladder_a_program_gets(model, train, rungs):
+    """A ladder only under `remat: true` with no explicit policy; its second
+    rung is the module's own where the bundle offers one."""
+    from polyaxon_tpu.runtime.trainer import _RematLadder
+
+    t = _lora_trainer(model=model, **train)
+    if rungs is None:
+        assert not isinstance(t.train_step, _RematLadder)
+    else:
+        assert list(t.train_step.steps) == rungs
+
+
+def test_mlp_has_no_block_rung():
+    """A module that names no block boundary: `all`, then the whole apply."""
+    from polyaxon_tpu.runtime.trainer import Trainer
+
+    p = make_program(steps=2, logEvery=1)
+    p.train.remat = True
+    t = Trainer(p, mesh_axes={"data": 8})
+    assert list(t.train_step.steps) == ["all", "apply"]
+    result = t.run()
+    assert t.train_step.rung == "all"
+    assert np.isfinite(result.history[-1]["loss"])
+
+
+def _dot_generals(step, trainer, batch) -> int:
+    return step.lower(trainer.state, batch).as_text().count("stablehlo.dot_general")
+
+
+@pytest.mark.parametrize("scan", [False, True], ids=["loop", "scan"])
+def test_products_of_each_rung(scan):
+    """Rung `all` is the step of `remat: false`; `block` computes more
+    products than it and no more than a checkpoint of the whole apply; no
+    rung computes the weight gradient of a frozen kernel (PR 28)."""
+    model = {"scan_layers": scan}
+    t = _lora_trainer(model=model, remat=True)
+    plain = _lora_trainer(model=model)
+    whole = _lora_trainer(model=model, rematPolicy="nothing")
+    (batch,) = _batches(t, 1)
+    n = {rung: _dot_generals(step, t, batch) for rung, step in t.train_step.steps.items()}
+    assert n["all"] == _dot_generals(plain.train_step, plain, batch)
+    assert n["all"] < n["block"] <= _dot_generals(whole.train_step, whole, batch)
+    frozen = {
+        shape
+        for path, x in _leaves_by_path(t.state.params).items()
+        if not _is_adapter(path) and x.ndim >= 2
+        for shape in (x.shape[-2:], x.shape[-2:][::-1])
+    }
+    assert {(64, 64), (64, 32), (64, 96), (96, 64), (64, 160)} <= frozen
+    for rung, step in t.train_step.steps.items():
+        products = {s[-2:] for s in _product_shapes(t, batch, step)}
+        assert not products & frozen, (rung, sorted(products & frozen))
+        assert {(64, 4), (4, 64), (4, 32)} <= products, rung
+
+
+def _three_steps_on(rung, **kw):
+    """(losses, adapter leaves) after three steps of one rung's own step."""
+    t = _lora_trainer(remat=True, **kw)
+    step, losses = t.train_step.steps[rung], []
+    for b in _batches(t, 3):
+        t.state, m = step(t.state, b)
+        losses.append(float(m["loss"]))
+    return losses, {
+        p: x for p, x in _leaves_by_path(t.state.params).items() if _is_adapter(p)
+    }
+
+
+@pytest.mark.parametrize(
+    "model",
+    [{}, {"scan_layers": True}, {"dropout_rate": 0.1}],
+    ids=["loop", "scan", "dropout"],
+)
+def test_every_rung_takes_the_same_three_steps(model):
+    want_losses, want = _three_steps_on("all", model=model)
+    got_losses, got = _three_steps_on("block", model=model)
+    np.testing.assert_allclose(got_losses, want_losses, rtol=1e-6)
+    assert want and list(got) == list(want)
+    for path in want:
+        np.testing.assert_allclose(
+            got[path], want[path], rtol=1e-4, atol=1e-6, err_msg=path
+        )
+
+
+def test_a_fitting_first_rung_is_traced_lowered_and_compiled_once():
+    import jax.monitoring
+
+    seen, listening = [], [True]
+
+    def on(event, duration, fun_name=None, **_):  # a listener cannot be taken off
+        if listening and fun_name in ("step_fn", "jit(step_fn)"):
+            seen.append(event.rsplit("/", 1)[-1])
+
+    t = _lora_trainer(remat=True)
+    batches = _batches(t, 3)
+    jax.monitoring.register_event_duration_secs_listener(on)
+    try:
+        _run_steps(t, batches)
+    finally:
+        listening.clear()
+    assert sorted(seen) == [
+        "backend_compile_duration", "jaxpr_to_mlir_module_duration",
+        "jaxpr_trace_duration",
+    ]
+    assert t.train_step.rung == "all"
+    # and the benchmark's question of the step that runs is still answered
+    lowered = t.train_step.lower(t.state, batches[0])
+    assert lowered.as_text().count("stablehlo.dot_general") == _dot_generals(
+        t.train_step.steps["all"], t, batches[0]
+    )
+    assert lowered.compile().memory_analysis() is not None
