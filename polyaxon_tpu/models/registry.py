@@ -51,10 +51,13 @@ class ModelBundle:
     # True if the module sows auxiliary losses into the `losses` collection
     # (e.g. MoE load balancing); the trainer adds them to the total loss.
     aux_losses: bool = False
-    # Per-step readings the module sows into the `moe_stats` collection
-    # (routed layers: local assignments, load, overflow), reduced over the
-    # layers to a flat dict; the trainer reports them as step metrics.
+    # Per-step readings the module sows into the `step_collections`
+    # (`moe_stats` of routed layers: local assignments, load, overflow;
+    # `ssm_stats` of Mamba layers: step size, in-chunk decay), reduced over
+    # the layers to a flat dict by `step_metrics({collection: sown})`; the
+    # trainer reports them as step metrics.
     step_metrics: Optional[Callable] = None
+    step_collections: tuple[str, ...] = ()
     # Optional fused head+loss: (params, features, batch) -> scalar. When
     # set, the trainer applies the module with return_features=True and
     # computes the loss from pre-head features — the [B, S, V] logits

@@ -25,6 +25,11 @@ model is in-repo and TPU-shaped:
   gate (`attn_gate`) and top-k routing over the experts held here
   (models/moe.py): what a published config of mixed window/full attention
   and sparse experts asks of one decoder. Unrolled layers only.
+- A layer's mixer is attention or a Mamba-2 state-space mixer (models/ssm.py,
+  ops/ssd.py), by `layer_types`; attention may go without rotation (`nope`);
+  the four muP constants of a published config (`embedding_multiplier`,
+  `attention_multiplier`, `residual_multiplier`, `logits_scaling`). A Mamba
+  layer trains; it has no decode path.
 - Optional LoRA (`lora_rank > 0`): frozen base kernels + trainable A/B
   adapters on all projections; the trainer masks the optimizer to adapter
   params via `ModelBundle.trainable_patterns`.
@@ -64,25 +69,46 @@ class RopeSpec:
 
 @dataclasses.dataclass(frozen=True)
 class LayerSpec:
-    """What one layer is, where layers differ: its query heads, its window
-    (0 = causal attention over the whole sequence), its rotary table, and
-    whether its MLP is routed or dense."""
+    """What one layer is, where layers differ: its mixer (`attention` or
+    `mamba`), and for attention its query heads, its window (0 = causal
+    attention over the whole sequence) and its rotary table (a
+    `rotary_factor` of 0 rotates nothing); whether its MLP is routed or
+    dense."""
 
     n_heads: int
     window: int = 0
     rope: RopeSpec = RopeSpec()
     routed: bool = False
+    mixer: str = "attention"  # attention | mamba
 
     def describe(self, cfg: "TransformerConfig") -> dict:
-        return {
-            "kind": "sliding" if self.window else "full",
-            "window": self.window,
-            "heads": self.n_heads,
-            "rope": "yarn" if self.rope.yarn_factor else "default",
-            "rope_theta": self.rope.theta,
+        mlp = {
             "mlp": "routed" if self.routed else "dense",
             "experts_held": cfg.held if self.routed else 0,
             "experts_published": cfg.n_experts if self.routed else 0,
+        }
+        if self.mixer == "mamba":
+            return {
+                "mixer": "mamba",
+                "heads": cfg.mamba_n_heads,
+                "head_width": cfg.mamba_d_head,
+                "state": cfg.mamba_d_state,
+                "conv": cfg.mamba_d_conv,
+                "chunk": cfg.mamba_chunk_size,
+                "groups": cfg.mamba_n_groups,
+                **mlp,
+            }
+        return {
+            "mixer": "attention",
+            "kind": "sliding" if self.window else "full",
+            "window": self.window,
+            "heads": self.n_heads,
+            "rope": (
+                "none" if not self.rope.rotary_factor
+                else "yarn" if self.rope.yarn_factor else "default"
+            ),
+            "rope_theta": self.rope.theta,
+            **mlp,
         }
 
 
@@ -136,6 +162,23 @@ class TransformerConfig:
     # attention block's input, one scalar a head and token, on the
     # attention output before o_proj
     attn_gate: bool = False
+    # the four muP constants of a published config, under their published
+    # names: the embeddings times `embedding_multiplier`; softmax of
+    # q k^T times `attention_multiplier` (None = 1 / sqrt(head width)); each
+    # residual branch times `residual_multiplier`; the logits over
+    # `logits_scaling`
+    embedding_multiplier: float = 1.0
+    attention_multiplier: Optional[float] = None
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    # the Mamba-2 mixer of the layers whose `layer_types` entry is "mamba"
+    # (models/ssm.py), under their published names
+    mamba_n_heads: int = 128
+    mamba_d_head: int = 64
+    mamba_d_state: int = 128
+    mamba_n_groups: int = 1
+    mamba_d_conv: int = 4
+    mamba_chunk_size: int = 256
     # MoE (models/moe.py): the router's width (the PUBLISHED count of
     # experts); 0 = dense MLPs. This process holds experts
     # [expert_offset, expert_offset + experts_held) of each routed layer
@@ -273,6 +316,12 @@ def apply_rope_at(x: jnp.ndarray, cos, sin, positions: jnp.ndarray):
     c = jnp.take(cos, positions, axis=0)[:, :, None, :]  # [B, S, 1, half]
     s = jnp.take(sin, positions, axis=0)[:, :, None, :]
     return _rotate(x, c, s)
+
+
+def _times(x, constant: float):
+    """x times a published constant; a constant of 1 multiplies nothing, so
+    a model without it traces as it did."""
+    return x if constant == 1.0 else x * constant
 
 
 class RMSNorm(nn.Module):
@@ -431,8 +480,11 @@ class Attention(nn.Module):
         q = constrain(q, BATCH, "context", "model", None)
         k = constrain(k, BATCH, "context", "model", None)
         v = constrain(v, BATCH, "context", "model", None)
-        cos_np, sin_np = rope_table_for(cfg.seq_len, hd, spec.rope)
-        cos, sin = jnp.asarray(cos_np), jnp.asarray(sin_np)
+        rotates = spec.rope.rotary_factor > 0  # "nope": positions from causality alone
+        if rotates:
+            cos_np, sin_np = rope_table_for(cfg.seq_len, hd, spec.rope)
+            cos, sin = jnp.asarray(cos_np), jnp.asarray(sin_np)
+        sm_scale = cfg.attention_multiplier  # None: 1 / sqrt(hd)
 
         if decode:
             # autoregressive step: append this token's K/V into a per-layer
@@ -536,10 +588,10 @@ class Attention(nn.Module):
                 row_slots = (
                     pos[:, None] if per_row else pos
                 ) + jnp.arange(S)[None, :]
-                if pad is None:
+                if rotates and pad is None:
                     q = apply_rope(q, cos, sin, offset=pos)
                     k = apply_rope(k, cos, sin, offset=pos)
-                else:
+                elif rotates:
                     # left-padded rows: cache slot s holds the row's true
                     # position s - pad[b]. Pad slots clamp to 0 — their K/V
                     # never attend (masked below), only the table index
@@ -650,7 +702,10 @@ class Attention(nn.Module):
                     q.reshape(B, S, nkv, G, hd),
                     k_all,
                     preferred_element_type=jnp.float32,
-                ).reshape(B, nh, S, win) / np.sqrt(hd)
+                ).reshape(B, nh, S, win)
+                scores = (
+                    scores / np.sqrt(hd) if sm_scale is None else scores * sm_scale
+                )
                 # query row i may see cache positions <= pos + i (with a
                 # per-row pos the comparison broadcasts to [B, S, win])
                 live = (
@@ -701,8 +756,9 @@ class Attention(nn.Module):
             # cache creation pass (first mutable apply): fall through to the
             # ordinary full-sequence attention so output shapes are normal
 
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
+        if rotates:
+            q = apply_rope(q, cos, sin)
+            k = apply_rope(k, cos, sin)
         # GQA expansion is the attention dispatch's concern: flash consumes
         # grouped kv natively (no repeated K/V in HBM), ring rotates it and
         # ulysses scatters it at true kv-head width; only the plain einsum
@@ -713,7 +769,7 @@ class Attention(nn.Module):
 
         out = dot_product_attention(
             q, k, v, causal=True, backend=cfg.attention,
-            block_kv=cfg.attention_block, window=window,
+            block_kv=cfg.attention_block, window=window, scale=sm_scale,
         )
         if gate is not None:
             out = out * gate
@@ -764,21 +820,29 @@ class Block(nn.Module):
         cfg = self.cfg
         spec = cfg.layer(self.index)
         x = constrain(x, BATCH, "context", None)
-        h = Attention(cfg, spec, name="attention")(
-            RMSNorm(cfg.norm_eps, name="attention_norm")(x),
-            train=self.train,
-            decode=self.decode,
-            pad=pad,
-            pages=pages,
-            pos=pos,
-            kv_layout=self.kv_layout,
-            prefix_len=self.prefix_len,
-            prefix_lens=prefix_lens,
-            adapter_ix=adapter_ix,
-        )
+        normed = RMSNorm(cfg.norm_eps, name="attention_norm")(x)
+        if spec.mixer == "mamba":
+            from .ssm import Mamba2
+
+            h = Mamba2(cfg, name="mamba")(
+                normed, decode=self.decode, adapter_ix=adapter_ix
+            )
+        else:
+            h = Attention(cfg, spec, name="attention")(
+                normed,
+                train=self.train,
+                decode=self.decode,
+                pad=pad,
+                pages=pages,
+                pos=pos,
+                kv_layout=self.kv_layout,
+                prefix_len=self.prefix_len,
+                prefix_lens=prefix_lens,
+                adapter_ix=adapter_ix,
+            )
         if cfg.dropout_rate:
             h = nn.Dropout(cfg.dropout_rate, deterministic=not self.train)(h)
-        x = x + h
+        x = x + _times(h, cfg.residual_multiplier)
         if spec.routed:
             from .moe import MoEFeedForward
 
@@ -807,7 +871,7 @@ class Block(nn.Module):
             )
         if cfg.dropout_rate:
             h = nn.Dropout(cfg.dropout_rate, deterministic=not self.train)(h)
-        return x + h
+        return x + _times(h, cfg.residual_multiplier)
 
 
 class _ScanBlock(nn.Module):
@@ -985,7 +1049,7 @@ class Transformer(nn.Module):
             name="embed",
             embedding_init=nn.initializers.normal(0.02),
         )
-        x = embed(tokens)
+        x = _times(embed(tokens), cfg.embedding_multiplier)
         from ..parallel.sharding import constrain
 
         # Pin the gather output to the blocks' activation layout HERE:
@@ -1046,6 +1110,10 @@ class Transformer(nn.Module):
                 )(x, pad=pad, pages=pages, pos=pos, prefix_lens=prefix_lens,
                   adapter_ix=adapter_ix)
         x = RMSNorm(cfg.norm_eps, name="final_norm")(x)
+        if cfg.logits_scaling != 1.0:
+            # the head is linear: the features over it are the logits over it,
+            # on the fused-loss path too
+            x = x / cfg.logits_scaling
         if return_features:
             # fused-loss path: the caller computes head+loss from features;
             # the head params must still exist in the tree, so touch the
@@ -1070,12 +1138,12 @@ TRANSFORMER_RULES = (
     # — vocab-sharding instead makes GSPMD emit a cross-shard gather with
     # involuntary full rematerialization
     (r"embed/embedding", (None, ("model", "fsdp"))),
-    (r"(q_proj|k_proj|v_proj|gate_proj|up_proj)/kernel", ("fsdp", "model")),
-    (r"(o_proj|down_proj)/kernel", ("model", "fsdp")),
-    (r"(q_proj|k_proj|v_proj|gate_proj|up_proj)/lora_a", ("fsdp", None)),
-    (r"(q_proj|k_proj|v_proj|gate_proj|up_proj)/lora_b", (None, "model")),
-    (r"(o_proj|down_proj)/lora_a", ("model", None)),
-    (r"(o_proj|down_proj)/lora_b", (None, "fsdp")),
+    (r"(q_proj|k_proj|v_proj|gate_proj|up_proj|in_proj)/kernel", ("fsdp", "model")),
+    (r"(o_proj|down_proj|out_proj)/kernel", ("model", "fsdp")),
+    (r"(q_proj|k_proj|v_proj|gate_proj|up_proj|in_proj)/lora_a", ("fsdp", None)),
+    (r"(q_proj|k_proj|v_proj|gate_proj|up_proj|in_proj)/lora_b", (None, "model")),
+    (r"(o_proj|down_proj|out_proj)/lora_a", ("model", None)),
+    (r"(o_proj|down_proj|out_proj)/lora_b", (None, "fsdp")),
     (r"lm_head/kernel", ("fsdp", "model")),
 )
 
@@ -1136,8 +1204,9 @@ def _rope_spec(published: dict) -> RopeSpec:
 
 _LAYER_KEYS = (
     "layer_types", "num_attention_heads_per_layer", "sliding_window",
-    "rope_parameters", "mlp_only_layers",
+    "rope_parameters", "mlp_only_layers", "position_embedding_type",
 )
+_ATTENTION_KINDS = ("full_attention", "sliding_attention", "attention")
 
 
 def _layer_specs(pub: dict, base: dict) -> tuple:
@@ -1164,20 +1233,29 @@ def _layer_specs(pub: dict, base: dict) -> tuple:
         ropes = {kind: ropes for kind in set(kinds)}  # one group for all
     dense = set(pub.get("mlp_only_layers") or ())
     routed = int(base.get("n_experts") or 0) > 0
+    positions = pub.get("position_embedding_type") or "rope"
+    if positions not in ("rope", "nope"):
+        raise ValueError(
+            f"unknown position_embedding_type {positions!r} (known: rope, nope)"
+        )
     specs = []
     for i, (kind, h) in enumerate(zip(kinds, heads)):
-        if kind not in ("full_attention", "sliding_attention"):
+        if kind not in (*_ATTENTION_KINDS, "mamba"):
             raise ValueError(f"unknown layer type {kind!r} at layer {i}")
         if kind == "sliding_attention" and window < 1:
             raise ValueError("sliding_attention layers need sliding_window")
+        if positions == "nope":
+            rope = RopeSpec(rotary_factor=0.0)
+        elif kind in ropes:
+            rope = _rope_spec(ropes[kind])
+        else:
+            rope = RopeSpec(theta=float(base.get("rope_theta", 10000.0)))
         specs.append(LayerSpec(
             n_heads=int(h),
             window=window if kind == "sliding_attention" else 0,
-            rope=(
-                _rope_spec(ropes[kind]) if kind in ropes
-                else RopeSpec(theta=float(base.get("rope_theta", 10000.0)))
-            ),
+            rope=rope,
             routed=routed and i not in dense,
+            mixer="mamba" if kind == "mamba" else "attention",
         ))
     return tuple(specs)
 
@@ -1222,8 +1300,9 @@ def _make_config(config: dict) -> TransformerConfig:
         raise ValueError(
             "scan_layers and pipeline_stages stack one block's parameters "
             "along a layer axis and cannot hold layers that differ (heads, "
-            "window, rope or dense/routed MLP by layer: layer_types, "
-            "num_attention_heads_per_layer, rope_parameters, mlp_only_layers)"
+            "window, rope, attention or Mamba mixer, dense/routed MLP by layer: "
+            "layer_types, num_attention_heads_per_layer, rope_parameters, "
+            "mlp_only_layers, position_embedding_type)"
         )
     for spec in cfg.layers or (cfg.layer(None),):
         if spec.n_heads % cfg.n_kv_heads:
@@ -1257,23 +1336,37 @@ def _make_config(config: dict) -> TransformerConfig:
     return cfg
 
 
-def moe_step_metrics(sown) -> dict:
-    """What the routed layers sowed into `moe_stats` in one step, over the
-    layers: the mean count of assignments to experts held here, the fullest
-    held expert against the mean one (the worst layer's), and the
-    assignments that did not fit their buffer (0, or the Trainer stops)."""
+_STEP_STATS = {  # collection -> how each sown name is reduced over the layers
+    "moe_stats": {"assignments_local": jnp.mean, "load_max_over_mean": jnp.max,
+                  "overflow": jnp.sum},
+    "ssm_stats": {"dt_max": jnp.max, "chunk_decay_min": jnp.min},
+}
+
+
+def step_metrics(sown: dict) -> dict:
+    """What the layers sowed in one step, reduced over the layers. Routed
+    layers (`moe_stats` -> `moe.*`): the mean count of assignments to experts
+    held here, the fullest held expert against the mean one (the worst
+    layer's), and the assignments that did not fit their buffer (0, or the
+    Trainer stops). Mamba layers (`ssm_stats` -> `ssm.*`): the largest step
+    size and the most negative in-chunk running sum of `dt A` (the worst
+    layer's: how far the in-chunk decays underflow)."""
     from flax.traverse_util import flatten_dict
 
-    by_name: dict = {}
-    for path, sown_here in flatten_dict(sown).items():  # layer_i/moe/<name>: (value,)
-        by_name.setdefault(path[-1], []).extend(
-            jnp.asarray(v, jnp.float32).reshape(()) for v in sown_here
+    out = {}
+    for collection, how in _STEP_STATS.items():
+        by_name: dict = {}
+        # layer_i/<module>/<name>: (value,)
+        for path, sown_here in flatten_dict(sown.get(collection, {})).items():
+            by_name.setdefault(path[-1], []).extend(
+                jnp.asarray(v, jnp.float32).reshape(()) for v in sown_here
+            )
+        prefix = collection.removesuffix("_stats")
+        out.update(
+            (f"{prefix}.{name}", how[name](jnp.stack(vals)))
+            for name, vals in by_name.items()
         )
-    how = {"assignments_local": jnp.mean, "load_max_over_mean": jnp.max,
-           "overflow": jnp.sum}
-    return {
-        f"moe.{name}": how[name](jnp.stack(vals)) for name, vals in by_name.items()
-    }
+    return out
 
 
 @register("transformer_lm")
@@ -1313,6 +1406,12 @@ def build_transformer(config: dict) -> ModelBundle:
             (r"lm_head/kernel", (None, "model")),
         )
         rules = edge + moe_rules + rules
+    stats = tuple(
+        name for name, has in (
+            ("moe_stats", cfg.n_experts > 0),
+            ("ssm_stats", any(spec.mixer == "mamba" for spec in cfg.layers)),
+        ) if has
+    )
     fused = None
     if cfg.fused_lm_loss:
         from ..ops.losses import fused_linear_masked_lm
@@ -1339,7 +1438,8 @@ def build_transformer(config: dict) -> ModelBundle:
         task="lm",
         trainable_patterns=trainable,
         aux_losses=cfg.n_experts > 0 and cfg.moe_aux_weight > 0,
-        step_metrics=moe_step_metrics if cfg.n_experts > 0 else None,
+        step_metrics=step_metrics if stats else None,
+        step_collections=stats,
         fused_loss=fused,
         # the pipelined stack applies its blocks functionally, in stages: no
         # block boundary that `keep=` could name

@@ -69,7 +69,8 @@ def resolve_auto_backend(
     return "flash" if flash_ok else "xla"
 
 
-def _flash_sharded(q, k, v, *, causal: bool, block_kv: int | None, mesh, window=None):
+def _flash_sharded(q, k, v, *, causal: bool, block_kv: int | None, mesh, window=None,
+                   scale=None):
     """The Pallas flash kernel on a live multi-device mesh.
 
     The kernel has no GSPMD partitioning rule, so partition it manually:
@@ -107,7 +108,9 @@ def _flash_sharded(q, k, v, *, causal: bool, block_kv: int | None, mesh, window=
         head = "model"
     q_spec = P(batch or None, None, head, None)
     kv_spec = P(batch or None, None, head, None)
-    body = partial(flash_attention, causal=causal, block_kv=block_kv, window=window)
+    body = partial(
+        flash_attention, causal=causal, block_kv=block_kv, window=window, sm_scale=scale
+    )
     fn = shard_map_nocheck(
         body,
         mesh=mesh,
@@ -119,9 +122,13 @@ def _flash_sharded(q, k, v, *, causal: bool, block_kv: int | None, mesh, window=
 
 def dot_product_attention(
     q, k, v, *, causal: bool, backend: str = "xla", block_kv: int | None = None,
-    window: int | None = None,
+    window: int | None = None, scale: float | None = None,
 ):
     """q: [B, S, H, D]; k/v: [B, S, KV, D] with KV dividing H → [B, S, H, D].
+
+    `scale`: what `q k^T` is multiplied by before the softmax; None is
+    1 / sqrt(D). The einsum and the flash kernels take another (a published
+    `attention_multiplier`); the context-parallel backends have none.
 
     `block_kv`: None lets the flash kernels choose their blocks (and the
     ring and ulysses chunk stay 512); a number is the kv block of all.
@@ -148,6 +155,11 @@ def dot_product_attention(
                 "context-parallel kernels attend the whole sequence (use "
                 "xla or flash for windowed layers)"
             )
+    if scale is not None and backend in ("ring", "ulysses"):
+        raise ValueError(
+            f"attention backend {backend!r} scales by 1 / sqrt(head width) only "
+            "(use xla or flash with an attention_multiplier)"
+        )
     # flash consumes grouped kv natively; ring rotates it and ulysses
     # scatters it at kv-head width (4x less fabric traffic at llama
     # ratios), both expanding internally only when shards don't divide.
@@ -165,10 +177,10 @@ def dot_product_attention(
         if mesh is not None and mesh.size > 1 and not constraints_suspended():
             return _flash_sharded(
                 q, k, v, causal=causal, block_kv=block_kv, mesh=mesh,
-                window=window,
+                window=window, scale=scale,
             )
         return flash_attention(
-            q, k, v, causal=causal, block_kv=block_kv, window=window
+            q, k, v, causal=causal, block_kv=block_kv, window=window, sm_scale=scale
         )
     if backend == "ring":
         from ..parallel.ring import ring_attention
@@ -183,7 +195,8 @@ def dot_product_attention(
     hd = q.shape[-1]
     scores = jnp.einsum(
         "bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32
-    ) / np.sqrt(hd)
+    )
+    scores = scores / np.sqrt(hd) if scale is None else scores * scale
     if causal:
         S = q.shape[1]
         mask = jnp.tril(jnp.ones((S, S), bool))

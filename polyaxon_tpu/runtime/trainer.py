@@ -438,7 +438,7 @@ class Trainer:
         collections = (
             list(mutable)
             + (["losses"] if bundle.aux_losses else [])
-            + (["moe_stats"] if step_metrics is not None else [])
+            + (list(bundle.step_collections) if step_metrics is not None else [])
         )
 
         fused_loss = bundle.fused_loss
@@ -475,7 +475,7 @@ class Trainer:
                 jnp.zeros((), jnp.float32),
             )
             stats = (
-                step_metrics(updates.pop("moe_stats", {}))
+                step_metrics({c: updates.pop(c, {}) for c in bundle.step_collections})
                 if step_metrics is not None else {}
             )
             return logits, updates, aux, stats
@@ -1010,10 +1010,13 @@ class Trainer:
         self._event("remat", choice)
 
     def _report_layers(self):
-        """What each layer of a decoder is (kind, heads, rope, dense or
-        routed, experts held of published): one event at build, on the run
-        store and as `polyaxon.model.layers` in the tracer's ring and any
-        profiler capture."""
+        """What each layer of a decoder is (mixer: attention or mamba; kind,
+        heads, rope or a Mamba layer's heads, head width, state, conv, chunk
+        and groups; dense or routed, experts held of published): one event at
+        build, on the run store and as `polyaxon.model.layers` in the tracer's
+        ring and any profiler capture. A model with Mamba layers adds
+        `polyaxon.model.ssm` (run store `model_ssm`): the scan's chunk count
+        and the bytes of its largest intermediate for the step's shape."""
         cfg = getattr(self.bundle.module, "cfg", None)
         if cfg is None or not hasattr(cfg, "layer"):
             return
@@ -1026,6 +1029,26 @@ class Trainer:
             "model.layers", n_layers=len(layers), layers=json.dumps(layers)
         )
         self._event("model_layers", {"layers": layers})
+        if any(layer["mixer"] == "mamba" for layer in layers):
+            from ..ops.ssd import heads_per_step, largest_intermediate_bytes
+
+            rows = max(  # a device's rows of the global batch
+                1, self.data.batch_size * jax.process_count() // local_batch_slice(self.mesh)
+            )
+            seq = int(self.data.meta.get("seq_len") or cfg.seq_len)
+            shape = (rows, seq, cfg.mamba_chunk_size)
+            ssm = {
+                "rows": rows, "seq_len": seq, "chunk": cfg.mamba_chunk_size,
+                "chunks": seq // cfg.mamba_chunk_size,
+                "heads_per_step": heads_per_step(
+                    *shape, cfg.mamba_n_heads // cfg.mamba_n_groups
+                ),
+                "largest_intermediate_bytes": largest_intermediate_bytes(
+                    *shape, cfg.mamba_n_heads, cfg.mamba_n_groups
+                ),
+            }
+            get_tracer().event("model.ssm", **ssm)
+            self._event("model_ssm", ssm)
         self._report_flash_tiles(cfg)
 
     def _report_flash_tiles(self, cfg):
@@ -1049,7 +1072,8 @@ class Trainer:
         if backend != "flash":
             return
         shapes = sorted(
-            {(s.n_heads // cfg.n_kv_heads, s.window) for s in map(cfg.layer, range(cfg.n_layers))}
+            {(s.n_heads // cfg.n_kv_heads, s.window)
+             for s in map(cfg.layer, range(cfg.n_layers)) if s.mixer == "attention"}
         )
         calls = [
             call

@@ -48,8 +48,8 @@ def small(model_over=None, config_over=None):
     return cell, config
 
 
-def ctx_for(cell, config, seed=SEED):
-    bench, entry, _, _ = load_cell(CELL, rehearse=True)
+def ctx_for(cell, config, seed=SEED, name=CELL):
+    bench, entry, _, _ = load_cell(name, rehearse=True)
     args = argparse.Namespace(seed=seed, seconds=0.2, trace=0, rehearse=True,
                               t_process=time.perf_counter())
     ctx = Ctx(args, bench, entry, cell, config)
@@ -68,10 +68,12 @@ def one_chip_trainer(ctx, **kw):
     return Trainer(V1Program.model_validate(spec), devices=jax.devices()[:1], **kw)
 
 
-def program_side(cell, config, seed=SEED):
+def program_side(cell, config, seed=SEED, name=CELL):
     """What the comparison reads of the program: the Trainer's own step on
-    seeded weights, three steps (losses, first gradient, adapters after)."""
-    ctx = ctx_for(cell, config, seed)
+    seeded weights, three steps (losses, first gradient, adapters after).
+    `name`: the cell whose BENCHMARK.json entry the context carries (the
+    hybrid decoder's tests pass their own)."""
+    ctx = ctx_for(cell, config, seed, name)
     trainer = one_chip_trainer(ctx)
     cap = drv.capture(trainer)
     drv.seed_state(trainer, cap, seed, config["init"])
@@ -356,28 +358,43 @@ def test_dense_config_traces_as_on_the_parent(no_mesh):
 
 
 # ------------------------------------------------------------------ the share
-def uncut_layer_case():
-    """One sparse layer of the rehearsal widths with all 16 experts: weights,
-    tokens, and what the uncut reference gives for routed + shared."""
-    _, config = small()
-    d = ref.Dims.from_published(config, lo=0, held=16)
+GRANITE = "granite-4.0-h-small-ep8.lora-train-8k"
+granite_ref = load_module(
+    HERE / "references" / "granite_hybrid_decoder.py", "test_laguna_granite_reference"
+)
+
+
+def uncut_layer_case(family="laguna"):
+    """One sparse layer of a rehearsal's widths with ALL its experts: weights,
+    tokens, and what the uncut reference gives for routed + shared. `laguna`:
+    16 experts, top-2, weights 2.5 x softmax renormalised (laguna_decoder.py);
+    `granite`: the published 72 experts and top-10, softmax over the chosen
+    (granite_hybrid_decoder.py)."""
+    if family == "laguna":
+        module, n = ref, 16
+        _, config = small()
+    else:
+        module, n = granite_ref, 72
+        _, _, _, config = load_cell(GRANITE, rehearse=True)
+        config = {**config, "router_width": n, "num_experts_per_tok": 10}
+    d = module.Dims.from_published(config, lo=0, held=n)
     ks = jax.random.split(jax.random.PRNGKey(11), 8)
-    D, F = d.hidden, d.expert
+    D, F, Fs = d.hidden, d.expert, d.shared
     w = {
-        "router": jax.random.normal(ks[0], (D, 16)) / np.sqrt(D),
-        "experts.gate": jax.random.normal(ks[1], (16, D, F)) / np.sqrt(D),
-        "experts.up": jax.random.normal(ks[2], (16, D, F)) / np.sqrt(D),
-        "experts.down": jax.random.normal(ks[3], (16, F, D)) / np.sqrt(F),
-        "shared.gate": jax.random.normal(ks[4], (D, F)) / np.sqrt(D),
-        "shared.up": jax.random.normal(ks[5], (D, F)) / np.sqrt(D),
-        "shared.down": jax.random.normal(ks[6], (F, D)) / np.sqrt(F),
+        "router": jax.random.normal(ks[0], (D, n)) / np.sqrt(D),
+        "experts.gate": jax.random.normal(ks[1], (n, D, F)) / np.sqrt(D),
+        "experts.up": jax.random.normal(ks[2], (n, D, F)) / np.sqrt(D),
+        "experts.down": jax.random.normal(ks[3], (n, F, D)) / np.sqrt(F),
+        "shared.gate": jax.random.normal(ks[4], (D, Fs)) / np.sqrt(D),
+        "shared.up": jax.random.normal(ks[5], (D, Fs)) / np.sqrt(D),
+        "shared.down": jax.random.normal(ks[6], (Fs, D)) / np.sqrt(Fs),
     }
     m = jax.random.normal(ks[7], (256, D))
     with jax.default_matmul_precision("highest"):
-        whole = ref._routed(m, w, d, ref._mm_f32) + ref._swiglu(
-            m, w["shared.gate"], w["shared.up"], w["shared.down"], ref._mm_f32
+        whole = module._routed(m, w, d, module._mm_f32) + module._swiglu(
+            m, w["shared.gate"], w["shared.up"], w["shared.down"], module._mm_f32
         )
-    return config, d, w, m, whole
+    return module, config, d, w, m, whole
 
 
 def share_of(w, lo, held):
@@ -385,31 +402,37 @@ def share_of(w, lo, held):
 
 
 @pytest.mark.parametrize("side", ["program", "reference"])
-@pytest.mark.parametrize("shares,held", [(4, 4), (2, 8)], ids=["4x4", "2x8"])
-def test_shares_add_up_to_the_uncut_layer(side, shares, held):
+@pytest.mark.parametrize(
+    "family,shares,held", [("laguna", 4, 4), ("laguna", 2, 8), ("granite", 8, 9)],
+    ids=["4x4", "2x8", "granite-8x9"],
+)
+def test_shares_add_up_to_the_uncut_layer(side, family, shares, held):
     """The guide's share test: over all shares of a layer, the routed parts
-    summed and the shared expert counted once equal the uncut reference."""
-    config, d, w, m, whole = uncut_layer_case()
+    summed and the shared expert counted once equal the uncut reference.
+    Granite's case is the cell's own cut: eight shares of 9 of 72 experts
+    (offsets 0, 9, ..., 63), top-10."""
+    module, config, d, w, m, whole = uncut_layer_case(family)
     total = 0.0
     with jax.default_matmul_precision("highest"):
         for s in range(shares):
             lo = s * held
             part = share_of(w, lo, held)
             if side == "reference":
-                ds = ref.Dims.from_published(config, lo=lo, held=held)
-                total = total + ref._routed(m, part, ds, ref._mm_f32)
+                ds = module.Dims.from_published(config, lo=lo, held=held)
+                total = total + module._routed(m, part, ds, module._mm_f32)
             else:
                 layer = MoEFeedForward(
-                    d.hidden, d.expert, 16, held=held, offset=lo, top_k=d.top_k,
-                    routed_scale=d.routed_scale, norm_topk=d.norm_topk, aux_weight=0.0,
+                    d.hidden, d.expert, d.router, held=held, offset=lo, top_k=d.top_k,
+                    routed_scale=getattr(d, "routed_scale", 1.0),
+                    norm_topk=getattr(d, "norm_topk", True), aux_weight=0.0,
                 )
                 params = {"router": {"kernel": part["router"]},
                           "gate_kernel": part["experts.gate"],
                           "up_kernel": part["experts.up"],
                           "down_kernel": part["experts.down"]}
                 total = total + layer.apply({"params": params}, m[None])[0]
-        total = total + ref._swiglu(
-            m, w["shared.gate"], w["shared.up"], w["shared.down"], ref._mm_f32
+        total = total + module._swiglu(
+            m, w["shared.gate"], w["shared.up"], w["shared.down"], module._mm_f32
         )
     np.testing.assert_allclose(total, whole, atol=2e-5)  # f32 sums, other order
 
@@ -541,17 +564,17 @@ def test_trainer_reports_the_tiles_its_flash_kernels_run():
         "flash_attention_fwd", "flash_attention_dq", "flash_attention_dkv"]
 
 
-def three_steps_on(rung):
+def three_steps_on(rung, case=None, seed=SEED, name=CELL):
     """Three steps of one rung of the remat ladder (`train.remat: true`, as
     the cell says) on the cell's seeded weights and feed: every metric of
-    every step (loss, gradient norm, what the routed layers sowed) and the
-    adapters after."""
-    cell, config = small()
-    ctx = ctx_for(cell, config)
+    every step (loss, gradient norm, what the layers sowed) and the adapters
+    after. `case`: (cell, config); this file's `small()` by default."""
+    cell, config = case or small()
+    ctx = ctx_for(cell, config, seed, name)
     trainer = one_chip_trainer(ctx)
     cap = drv.capture(trainer)
-    drv.seed_state(trainer, cap, SEED, config["init"])
-    feed = drv.make_feed(ctx, trainer, SEED)
+    drv.seed_state(trainer, cap, seed, config["init"])
+    feed = drv.make_feed(ctx, trainer, seed)
     assert list(trainer.train_step.steps) == ["all", "block"]
     step, metrics = trainer.train_step.steps[rung], []
     for _ in range(3):
@@ -563,14 +586,12 @@ def three_steps_on(rung):
     return metrics, adapters
 
 
-def test_a_checkpoint_per_block_takes_the_same_three_steps():
-    """Window and full layers, a routed MLP and `moe_stats` sown under the
-    checkpoint: rung `block` against rung `all`, in float32."""
-    want_metrics, want = three_steps_on("all")
-    got_metrics, got = three_steps_on("block")
-    assert {"loss", "grad_norm", "moe.assignments_local", "moe.overflow"} <= set(
-        want_metrics[0]
-    )
+def assert_block_takes_the_steps_of_all(sown: set, **case):
+    """Rung `block` against rung `all`, in float32: every step's metrics
+    (`sown` among them) and the adapters after the third."""
+    want_metrics, want = three_steps_on("all", **case)
+    got_metrics, got = three_steps_on("block", **case)
+    assert {"loss", "grad_norm"} | sown <= set(want_metrics[0])
     for w, g in zip(want_metrics, got_metrics):
         assert set(g) == set(w)
         for name in w:
@@ -580,6 +601,12 @@ def test_a_checkpoint_per_block_takes_the_same_three_steps():
         np.testing.assert_allclose(
             got[path], want[path], rtol=1e-4, atol=1e-6, err_msg=path
         )
+
+
+def test_a_checkpoint_per_block_takes_the_same_three_steps():
+    """Window and full layers, a routed MLP and `moe_stats` sown under the
+    checkpoint."""
+    assert_block_takes_the_steps_of_all({"moe.assignments_local", "moe.overflow"})
 
 
 def test_trainer_stops_on_overflow():

@@ -203,14 +203,19 @@ class TestTracking:
 
 
 class TestCli:
-    def test_run_and_ops(self, tmp_home):
+    @pytest.mark.parametrize(
+        "example,batch",
+        [("mnist", 16), ("granite_hybrid_lora", 8)],  # 8: a row a virtual device
+    )
+    def test_run_and_ops(self, tmp_home, example, batch):
         from click.testing import CliRunner
 
         from polyaxon_tpu.cli.main import cli
 
         runner = CliRunner()
         res = runner.invoke(
-            cli, ["run", "-f", "examples/mnist.yaml", "-P", "steps=4", "-P", "batch_size=16"]
+            cli, ["run", "-f", f"examples/{example}.yaml", "-P", "steps=4",
+                  "-P", f"batch_size={batch}"]
         )
         assert res.exit_code == 0, res.output
         res = runner.invoke(cli, ["ops", "ls"])
