@@ -2,17 +2,20 @@
 `transformers`' `GraniteMoeHybridMambaLayer` computes it in training:
 
     [z | xBC | dt] = in_proj(u)              widths  H*P | H*P + 2*G*N | H
-    xBC = silu(conv1d(xBC))                  depthwise over time, causal, with bias
+    xBC = silu(conv1d(xBC))                  depthwise over time, causal, with bias  (ops/mamba_fused.py)
     [x | B | C] = xBC                        x as H heads of P; B, C as G groups of N
     dt = softplus(dt + dt_bias)              A = -exp(A_log)        (one each a head)
     h_t = exp(dt_t A) h_{t-1} + dt_t x_t B_t^T,   y_t = h_t C_t + D x_t     (ops/ssd.py)
-    y = RMSNorm(y * silu(z)) * w             the gate BEFORE the norm, over all H*P
+    y = RMSNorm(y * silu(z)) * w             the gate BEFORE the norm, over all H*P  (ops/mamba_fused.py)
     out = out_proj(y)
 
 The two projections go through the decoder's own `_proj`, so `lora_targets`
 may name `in_proj` and `out_proj` and the gradient reaches their adapters
 through the scan's backward. `dt`, `A` and the scan's sums are float32
-whatever the activations are. Per step the layer sows into `ssm_stats` the
+whatever the activations are. The two elementwise chains are one fused op
+each (`conv_silu`, `gated_rmsnorm`: Pallas kernels where the shape allows,
+the `jax.numpy` forms of `ops/ssd.py` elsewhere), and read `z` and `xBC`
+where they lie in `in_proj`'s output: only `dt` is sliced out. Per step the layer sows into `ssm_stats` the
 largest step size and the most negative in-chunk running sum of `dt A` (how
 far the in-chunk decays underflow).
 
@@ -28,7 +31,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops.ssd import causal_conv1d, ssd_scan
+from ..ops.mamba_fused import conv_silu, gated_rmsnorm
+from ..ops.ssd import ssd_scan
 
 
 def _a_log_init(key, shape, dtype=jnp.float32):
@@ -42,12 +46,24 @@ def _dt_bias_init(key, shape, dtype=jnp.float32):
     return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
 
 
+class _GatedNorm(nn.Module):
+    """The mixer's `norm`: `scale` where `RMSNorm` would keep it
+    (`mamba/norm/scale`), the gate and the norm one fused op."""
+
+    eps: float = 1e-5
+
+    @nn.compact
+    def __call__(self, y, zxbcdt):
+        scale = self.param("scale", nn.initializers.ones, (y.shape[-1],))
+        return gated_rmsnorm(y, zxbcdt, scale, self.eps, z_columns=(0, y.shape[-1]))
+
+
 class Mamba2(nn.Module):
     cfg: "TransformerConfig"  # noqa: F821 - models/transformer.py imports this module
 
     @nn.compact
     def __call__(self, u, *, decode: bool = False, adapter_ix=None):
-        from .transformer import RMSNorm, _run_proj
+        from .transformer import _run_proj
 
         cfg = self.cfg
         if decode:
@@ -65,17 +81,17 @@ class Mamba2(nn.Module):
         f32 = jnp.float32
 
         zxbcdt = _run_proj(cfg, 2 * inner + 2 * bc + heads, "in_proj", u, adapter_ix)
-        z, xbc, dt = jnp.split(zxbcdt, [inner, 2 * inner + 2 * bc], axis=-1)
         conv_kernel = self.param(
             "conv_kernel", nn.initializers.normal(1.0 / np.sqrt(k)), (k, inner + 2 * bc)
         )
         conv_bias = self.param("conv_bias", nn.initializers.zeros, (inner + 2 * bc,))
-        xbc = nn.silu(causal_conv1d(xbc, conv_kernel, conv_bias))
+        xbc = conv_silu(zxbcdt, conv_kernel, conv_bias, columns=(inner, inner + 2 * bc))
         x, b, c = jnp.split(xbc, [inner, inner + bc], axis=-1)
 
         dt_bias = self.param("dt_bias", _dt_bias_init, (heads,))
         a_log = self.param("A_log", _a_log_init, (heads,))
         d_skip = self.param("D", nn.initializers.ones, (heads,))
+        dt = zxbcdt[..., 2 * inner + 2 * bc :]
         dt = jax.nn.softplus(dt.astype(f32) + dt_bias.astype(f32))  # [B, S, H]
         a_rate = -jnp.exp(a_log.astype(f32))
 
@@ -90,7 +106,5 @@ class Mamba2(nn.Module):
         self.sow("ssm_stats", "chunk_decay_min", jnp.min(sums))
 
         # the gate before the norm; the norm over the whole inner width, in float32
-        y = RMSNorm(cfg.norm_eps, name="norm")(
-            y.astype(f32) * nn.silu(z.astype(f32))
-        ).astype(u.dtype)
+        y = _GatedNorm(cfg.norm_eps, name="norm")(y, zxbcdt)
         return _run_proj(cfg, cfg.dim, "out_proj", y, adapter_ix)

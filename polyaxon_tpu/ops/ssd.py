@@ -146,3 +146,12 @@ def causal_conv1d(x, kernel, bias):
     padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
     y = sum(padded[:, i : i + seq] * kernel[i].astype(x.dtype) for i in range(k))
     return y + bias.astype(x.dtype)
+
+
+def gated_rmsnorm(y, z, scale, eps: float = 1e-5):
+    """RMSNorm(y * silu(z)) * scale over the last axis, the gate BEFORE the
+    norm, in float32; the result in y's type."""
+    f32 = jnp.float32
+    g = y.astype(f32) * jax.nn.silu(z.astype(f32))
+    normed = g * jax.lax.rsqrt(jnp.mean(g * g, -1, keepdims=True) + eps)
+    return (normed * scale).astype(y.dtype)

@@ -1016,7 +1016,9 @@ class Trainer:
         build, on the run store and as `polyaxon.model.layers` in the tracer's
         ring and any profiler capture. A model with Mamba layers adds
         `polyaxon.model.ssm` (run store `model_ssm`): the scan's chunk count
-        and the bytes of its largest intermediate for the step's shape."""
+        and the bytes of its largest intermediate for the step's shape, and
+        per Mamba layer which path each fused chain of the mixer takes
+        (`ops/mamba_fused.py`: `pallas` with its tiles, or `xla` and why)."""
         cfg = getattr(self.bundle.module, "cfg", None)
         if cfg is None or not hasattr(cfg, "layer"):
             return
@@ -1030,6 +1032,7 @@ class Trainer:
         )
         self._event("model_layers", {"layers": layers})
         if any(layer["mixer"] == "mamba" for layer in layers):
+            from ..ops.mamba_fused import conv_plan, gate_plan
             from ..ops.ssd import heads_per_step, largest_intermediate_bytes
 
             rows = max(  # a device's rows of the global batch
@@ -1037,6 +1040,14 @@ class Trainer:
             )
             seq = int(self.data.meta.get("seq_len") or cfg.seq_len)
             shape = (rows, seq, cfg.mamba_chunk_size)
+            inner = cfg.mamba_n_heads * cfg.mamba_d_head
+            conv_width = inner + 2 * cfg.mamba_n_groups * cfg.mamba_d_state
+            fused = {  # the same for every Mamba layer: they share their widths
+                "conv_silu": conv_plan(
+                    seq, conv_width, self.compute_dtype, inner, cfg.mamba_d_conv
+                ),
+                "gate_norm": gate_plan(seq, inner, self.compute_dtype),
+            }
             ssm = {
                 "rows": rows, "seq_len": seq, "chunk": cfg.mamba_chunk_size,
                 "chunks": seq // cfg.mamba_chunk_size,
@@ -1046,8 +1057,12 @@ class Trainer:
                 "largest_intermediate_bytes": largest_intermediate_bytes(
                     *shape, cfg.mamba_n_heads, cfg.mamba_n_groups
                 ),
+                "fused": [
+                    {"layer": i, **fused}
+                    for i, layer in enumerate(layers) if layer["mixer"] == "mamba"
+                ],
             }
-            get_tracer().event("model.ssm", **ssm)
+            get_tracer().event("model.ssm", **{**ssm, "fused": json.dumps(ssm["fused"])})
             self._event("model_ssm", ssm)
         self._report_flash_tiles(cfg)
 

@@ -34,7 +34,9 @@ import time
 
 DEFAULT_SCOPES = (
     "ssd=/ssd/,mamba_in_proj=/mamba/in_proj,mamba_out_proj=/mamba/out_proj,"
-    "mamba_conv_gate_norm=/mamba/,moe=/moe/,shared_mlp=/shared_expert/,"
+    # the mixer's two fused chains (`ops/mamba_fused.py` names their scopes), then what is left
+    "mamba_conv_silu=/mamba/.*conv_silu,mamba_gate_norm=/mamba/.*gate_norm,mamba_rest=/mamba/,"
+    "moe=/moe/,shared_mlp=/shared_expert/,"
     "attention=/attention/,norms=_norm/,embed_head_loss=embed|lm_head|logsumexp|fused"
 )
 

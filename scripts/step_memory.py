@@ -50,6 +50,10 @@ def main() -> None:
     from polyaxon_tpu.ops import flash_attention
 
     flash_attention._interpret = lambda: False  # lower the kernels for Mosaic
+    try:  # wraps its kernels in `jax.jit` as it is imported: before the stand-in below
+        from polyaxon_tpu.ops import mamba_fused  # noqa: F401
+    except ImportError:  # a `--repo` from before PR 34
+        pass
     from cellbench.drivers import train as driver
     from polyaxon_tpu.runtime.trainer import Trainer
     from polyaxon_tpu.schemas.run_kinds import V1Program

@@ -319,9 +319,16 @@ def test_trainer_reports_mixers_the_scan_and_its_decays():
         "chunk": 8, "groups": 1, "mlp": "routed", "experts_held": 2, "experts_published": 8,
     }
     assert layers[1]["rope"] == "none" and layers[1]["heads"] == 4
+    # the rehearsal's widths: `xBC` of 128 + 2 x 16 columns is no multiple of
+    # 128 and takes the `jax.numpy` path; the gate's 128 run the kernels
+    fused = {
+        "conv_silu": {"path": "xla", "why": "width 160 is no multiple of 128"},
+        "gate_norm": {"path": "pallas", "block_rows": 64, "chunk_rows": 16, "in_place": True},
+    }
     assert by_kind["model_ssm"] == {
         "rows": 1, "seq_len": 64, "chunk": 8, "chunks": 8, "heads_per_step": 8,
         "largest_intermediate_bytes": 8 * 64 * 8 * 4,
+        "fused": [{"layer": 0, **fused}, {"layer": 2, **fused}],
     }
     marks = [r["name"] for r in get_tracer().recent(400)]
     assert "model.layers" in marks and "model.ssm" in marks

@@ -5,7 +5,8 @@ Nothing executes, so these say nothing about results or speed.
 
 The shapes are those of `chip_smoke.py` (Llama-3.2-1B widths: 32 query / 8
 kv heads of 64, batch 4, seq 2048) plus head width 128, and the benchmark's
-three call shapes with the blocks the kernels choose for them.
+three call shapes with the blocks the kernels choose for them, and the two
+fused ops of the Granite cell's Mamba mixer at its widths.
 """
 
 import os
@@ -148,6 +149,57 @@ def test_the_chosen_tiles_compile_for_v5e(chip, call, backward):
     for kernel in ("fwd", "dq", "dkv") if backward else ("fwd",):
         assert stem + kernel in text
     assert other not in text
+
+
+# the Granite cell's mixer: 1 row x 8,192, `in_proj`'s output of 16,768
+# columns holding `z` (8,192) | `xBC` (8,448) | `dt` (128); and the same
+# chains in float32 at a width that gets column blocks of 512 and 128
+MIXERS = {
+    "granite-bf16-1x8192": (1, 8192, 8192, 8448, jnp.bfloat16),
+    "float32-2x2048": (2, 2048, 1024, 1152, jnp.float32),
+}
+
+
+@pytest.mark.parametrize("op", ["conv_silu", "gated_rmsnorm"])
+@pytest.mark.parametrize("mixer", sorted(MIXERS))
+def test_fused_mamba_kernels_compile_for_v5e(chip, mixer, op):
+    """Forward and backward of each fused chain with the tiles its plan
+    chooses, read in place from `in_proj`'s output: the chip's compiler takes
+    the blocks, the halo blocks of one sublane tile, the rotation along the
+    rows and the chunked walk (interpret mode shows none of that), and the
+    four kernels keep the names `mamba_fused_roofline.train` finds them by."""
+    from polyaxon_tpu.ops import mamba_fused as mf
+
+    rows, seq, inner, conv, dtype = MIXERS[mixer]
+    wide = inner + conv + 128
+
+    def sds(*shape, of=dtype):
+        return jax.ShapeDtypeStruct(shape, of, sharding=chip)
+
+    if op == "conv_silu":
+        assert mf.conv_plan(seq, conv, dtype, inner)["in_place"]
+
+        def grads(x, kernel, bias):
+            return jax.grad(lambda *a: mf.conv_silu(*a, columns=(inner, conv))
+                            .astype(jnp.float32).sum(), argnums=(0, 1, 2))(x, kernel, bias)
+
+        args = (sds(rows, seq, wide), sds(4, conv, of=jnp.float32), sds(conv, of=jnp.float32))
+        names = ("mamba_conv_silu_fwd", "mamba_conv_silu_bwd")
+    else:
+        assert mf.gate_plan(seq, inner, dtype, 0)["in_place"]
+
+        def grads(y, z, scale):
+            return jax.grad(lambda *a: mf.gated_rmsnorm(*a, 1e-5, z_columns=(0, inner))
+                            .astype(jnp.float32).sum(), argnums=(0, 1, 2))(y, z, scale)
+
+        args = (sds(rows, seq, inner), sds(rows, seq, wide), sds(inner, of=jnp.float32))
+        names = ("mamba_gate_norm_fwd", "mamba_gate_norm_bwd")
+    text = jax.jit(grads).lower(*args).compile().as_text()
+    assert names[1] in text and mf.FROZEN_SCOPE in text
+    forward = mf.conv_silu if op == "conv_silu" else mf.gated_rmsnorm
+    columns = {"columns": (inner, conv)} if op == "conv_silu" else {"z_columns": (0, inner)}
+    text = jax.jit(lambda *a: forward(*a, **columns)).lower(*args).compile().as_text()
+    assert names[0] in text
 
 
 def _dense_decode(chip, batch, cache_len=8192, n_layers=1):
