@@ -13,7 +13,13 @@ One routed layer for both uses:
   left out; no code stands in for the other chips or their exchange.
 
 How: router logits and softmax in f32; the k largest of all `n_experts`;
-`w = p / sum of the k` where `norm_topk`, times `routed_scale`. The
+`w = p / sum of the k` where `norm_topk`, times `routed_scale`. A router of
+DeepSeek-V3's kind (`score="sigmoid"`, `bias`, `groups`) scores by a sigmoid
+of logits kept in float32,
+chooses by `c = s + b` with `b` a frozen selection bias (`router_bias`), and
+where `groups > 1` only among the experts of the `groups_kept` best of
+`groups` consecutive groups (a group's score: the sum of its two largest
+`c`); the weights are the chosen experts' `s`, never `c`. The
 assignments to held experts are ordered by expert (one stable argsort) into a
 buffer of static length, their tokens' rows gathered, the three SwiGLU
 products taken as grouped products over the held experts
@@ -64,6 +70,10 @@ class MoEFeedForward(nn.Module):
     aux_weight: float = 0.01
     buffer_factor: float = 2.0
     router_noise: float = 0.0
+    score: str = "softmax"  # softmax | sigmoid
+    bias: bool = False  # a selection bias a expert, added for the choice only
+    groups: int = 1  # > 1: the choice is limited to the best `groups_kept` groups
+    groups_kept: int = 1
 
     @nn.compact
     def __call__(self, x, *, train: bool = False):
@@ -73,14 +83,34 @@ class MoEFeedForward(nn.Module):
         T = B * S
         m = x.reshape(T, D)
 
-        logits = nn.Dense(E, use_bias=False, name="router")(m).astype(jnp.float32)
+        # a sigmoid router's choice hangs on gaps of a thousandth between group
+        # scores: its logits come out of the product in float32 (as the
+        # published gate computes them), not rounded to the activations' type
+        # first (bf16 near 2.3 steps by 0.016: a token in five then keeps
+        # another group than float32 does)
+        router_dtype = jnp.float32 if self.score == "sigmoid" else None
+        logits = nn.Dense(E, use_bias=False, name="router", dtype=router_dtype)(m).astype(
+            jnp.float32
+        )
         if train and self.router_noise > 0:
             rng = self.make_rng("dropout")
             logits = logits + self.router_noise * jax.random.normal(
                 rng, logits.shape, jnp.float32
             )
-        probs = jax.nn.softmax(logits, axis=-1)  # [T, E]
-        top_p, top_e = jax.lax.top_k(probs, K)  # [T, K]
+        if self.score == "softmax":
+            probs = jax.nn.softmax(logits, axis=-1)  # [T, E]
+        elif self.score == "sigmoid":
+            probs = jax.nn.sigmoid(logits)
+        else:
+            raise ValueError(f"unknown router score {self.score!r} (known: softmax, sigmoid)")
+        if self.bias or self.groups > 1:
+            b = (
+                self.param("router_bias", nn.initializers.zeros, (E,)) if self.bias else None
+            )
+            top_e = limited_choice(probs, K, b, self.groups, self.groups_kept)
+            top_p = jnp.take_along_axis(probs, top_e, axis=-1)
+        else:
+            top_p, top_e = jax.lax.top_k(probs, K)  # [T, K]
         weight = top_p / jnp.sum(top_p, -1, keepdims=True) if self.norm_topk else top_p
         weight = weight * self.routed_scale
 
@@ -128,9 +158,27 @@ class MoEFeedForward(nn.Module):
         h = h * jax.lax.ragged_dot(picked, wu.astype(dt), sizes)
         y = jax.lax.ragged_dot(h, wd.astype(dt), sizes).astype(jnp.float32)
         w_row = weight.reshape(T * K)[order]
-        y = jnp.where(here[:, None], y * w_row[:, None], 0.0)
+        # the rows no expert owns are cleared BEFORE the weights multiply
+        # them: what the kernel leaves there may be NaN, and the weights'
+        # cotangent is a product with these rows (0 x NaN on the way back)
+        y = jnp.where(here[:, None], y, 0.0) * w_row[:, None]
         out = jnp.zeros((T, D), jnp.float32).at[token].add(y)
         return out.astype(x.dtype).reshape(B, S, D)
+
+
+def limited_choice(scores, top_k: int, bias=None, groups: int = 1, groups_kept: int = 1):
+    """The `top_k` experts [T, k] by `scores + bias` (scores [T, E] float32),
+    among the experts of the `groups_kept` of `groups` consecutive groups
+    whose two best sum highest."""
+    choice = scores if bias is None else scores + bias.astype(jnp.float32)
+    if groups > 1:
+        tokens = choice.shape[0]
+        grouped = choice.reshape(tokens, groups, -1)
+        best_two = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)  # [T, groups]
+        _, kept = jax.lax.top_k(best_two, groups_kept)
+        keep = jnp.any(kept[:, :, None] == jnp.arange(groups), axis=1)  # [T, groups]
+        choice = jnp.where(keep[:, :, None], grouped, -jnp.inf).reshape(tokens, -1)
+    return jax.lax.top_k(choice, top_k)[1]
 
 
 # sharding rules for stacked expert weights: expert dim over `expert` axis,

@@ -30,6 +30,12 @@ model is in-repo and TPU-shaped:
   the four muP constants of a published config (`embedding_multiplier`,
   `attention_multiplier`, `residual_multiplier`, `logits_scaling`). A Mamba
   layer trains; it has no decode path.
+- Two more mixers by `layer_types`: `kda`, a delta-rule linear-attention
+  layer with a decay a key channel (models/kda.py, ops/kda.py), and `mla`,
+  latent attention whose scores are wider than its values (models/mla.py:
+  the flash kernels take the two widths); a router that scores by a sigmoid,
+  chooses with a frozen bias and within the best groups (models/moe.py).
+  They train; neither has a decode path.
 - Optional LoRA (`lora_rank > 0`): frozen base kernels + trainable A/B
   adapters on all projections; the trainer masks the optimizer to adapter
   params via `ModelBundle.trainable_patterns`.
@@ -69,17 +75,18 @@ class RopeSpec:
 
 @dataclasses.dataclass(frozen=True)
 class LayerSpec:
-    """What one layer is, where layers differ: its mixer (`attention` or
-    `mamba`), and for attention its query heads, its window (0 = causal
-    attention over the whole sequence) and its rotary table (a
-    `rotary_factor` of 0 rotates nothing); whether its MLP is routed or
+    """What one layer is, where layers differ: its mixer (`attention`,
+    `mamba`, `kda` or `mla`), and for attention its query heads, its window
+    (0 = causal attention over the whole sequence) and its rotary table (a
+    `rotary_factor` of 0 rotates nothing); `kda` and `mla` read their heads
+    (and `mla` its rotary base) from here too; whether its MLP is routed or
     dense."""
 
     n_heads: int
     window: int = 0
     rope: RopeSpec = RopeSpec()
     routed: bool = False
-    mixer: str = "attention"  # attention | mamba
+    mixer: str = "attention"  # attention | mamba | kda | mla
 
     def describe(self, cfg: "TransformerConfig") -> dict:
         mlp = {
@@ -87,6 +94,34 @@ class LayerSpec:
             "experts_held": cfg.held if self.routed else 0,
             "experts_published": cfg.n_experts if self.routed else 0,
         }
+        if self.routed:
+            mlp.update(  # `groups` alone is a Mamba layer's count of B and C groups
+                score=cfg.router_score, router_groups=cfg.router_groups,
+                router_groups_kept=cfg.router_groups_kept,
+            )
+        if self.mixer == "kda":
+            return {
+                "mixer": "kda",
+                "heads": self.n_heads,
+                "key_width": cfg.head_size,
+                "value_width": cfg.head_size,
+                "conv": cfg.kda_conv,
+                "chunk": cfg.kda_chunk_size,
+                "gate_bound": cfg.kda_gate_bound,
+                **mlp,
+            }
+        if self.mixer == "mla":
+            return {
+                "mixer": "mla",
+                "heads": self.n_heads,
+                "latent": cfg.mla_latent,
+                "nope_width": cfg.mla_nope_dim,
+                "rope_width": cfg.mla_rope_dim,
+                "value_width": cfg.mla_value_dim,
+                "rope_theta": self.rope.theta,
+                "gate": cfg.attn_gate,
+                **mlp,
+            }
         if self.mixer == "mamba":
             return {
                 "mixer": "mamba",
@@ -179,6 +214,22 @@ class TransformerConfig:
     mamba_n_groups: int = 1
     mamba_d_conv: int = 4
     mamba_chunk_size: int = 256
+    # the delta-rule mixer of the layers whose `layer_types` entry is "kda"
+    # (models/kda.py): heads of `head_size` for keys and values alike, the
+    # short convolutions' taps, the scan's chunk, and the bound of the gate
+    # (log-decay a channel in (kda_gate_bound, 0); ops/kda.py needs 16 x its
+    # magnitude under float32's exp range)
+    kda_conv: int = 4
+    kda_chunk_size: int = 64
+    kda_gate_bound: float = -5.0
+    # latent attention of the layers whose entry is "mla" (models/mla.py): the
+    # latent keys and values are expanded from, a head's score columns
+    # without and with rotation (theta: the layer's rope), a head's values
+    mla_latent: int = 512
+    mla_nope_dim: int = 128
+    mla_rope_dim: int = 64
+    mla_value_dim: int = 128
+    mla_qk_norm: bool = False
     # MoE (models/moe.py): the router's width (the PUBLISHED count of
     # experts); 0 = dense MLPs. This process holds experts
     # [expert_offset, expert_offset + experts_held) of each routed layer
@@ -189,6 +240,13 @@ class TransformerConfig:
     experts_per_token: int = 1
     routed_scale: float = 1.0
     norm_topk: bool = False
+    # how the router scores and chooses: `softmax` or `sigmoid` scores; a
+    # frozen selection bias an expert; the choice limited to the
+    # `router_groups_kept` best of `router_groups` consecutive groups
+    router_score: str = "softmax"
+    router_bias: bool = False
+    router_groups: int = 1
+    router_groups_kept: int = 1
     expert_dim: Optional[int] = None  # an expert's width; None = ffn_dim
     shared_expert_dim: int = 0  # > 0: a dense SwiGLU beside the routed ones
     moe_aux_weight: float = 0.01  # Switch load-balancing loss; 0 = none
@@ -827,6 +885,18 @@ class Block(nn.Module):
             h = Mamba2(cfg, name="mamba")(
                 normed, decode=self.decode, adapter_ix=adapter_ix
             )
+        elif spec.mixer == "kda":
+            from .kda import KimiDeltaAttention
+
+            h = KimiDeltaAttention(cfg, spec.n_heads, name="kda")(
+                normed, decode=self.decode, adapter_ix=adapter_ix
+            )
+        elif spec.mixer == "mla":
+            from .mla import LatentAttention
+
+            h = LatentAttention(cfg, spec, name="mla")(
+                normed, decode=self.decode, adapter_ix=adapter_ix
+            )
         else:
             h = Attention(cfg, spec, name="attention")(
                 normed,
@@ -858,6 +928,10 @@ class Block(nn.Module):
                 norm_topk=cfg.norm_topk,
                 aux_weight=cfg.moe_aux_weight,
                 buffer_factor=cfg.expert_buffer_factor,
+                score=cfg.router_score,
+                bias=cfg.router_bias,
+                groups=cfg.router_groups,
+                groups_kept=cfg.router_groups_kept,
                 name="moe",
             )(normed, train=self.train)
             if cfg.shared_expert_dim:
@@ -1207,6 +1281,7 @@ _LAYER_KEYS = (
     "rope_parameters", "mlp_only_layers", "position_embedding_type",
 )
 _ATTENTION_KINDS = ("full_attention", "sliding_attention", "attention")
+_OTHER_MIXERS = ("mamba", "kda", "mla")  # a `layer_types` entry that names its mixer
 
 
 def _layer_specs(pub: dict, base: dict) -> tuple:
@@ -1240,7 +1315,7 @@ def _layer_specs(pub: dict, base: dict) -> tuple:
         )
     specs = []
     for i, (kind, h) in enumerate(zip(kinds, heads)):
-        if kind not in (*_ATTENTION_KINDS, "mamba"):
+        if kind not in (*_ATTENTION_KINDS, *_OTHER_MIXERS):
             raise ValueError(f"unknown layer type {kind!r} at layer {i}")
         if kind == "sliding_attention" and window < 1:
             raise ValueError("sliding_attention layers need sliding_window")
@@ -1255,7 +1330,7 @@ def _layer_specs(pub: dict, base: dict) -> tuple:
             window=window if kind == "sliding_attention" else 0,
             rope=rope,
             routed=routed and i not in dense,
-            mixer="mamba" if kind == "mamba" else "attention",
+            mixer=kind if kind in _OTHER_MIXERS else "attention",
         ))
     return tuple(specs)
 
@@ -1300,7 +1375,7 @@ def _make_config(config: dict) -> TransformerConfig:
         raise ValueError(
             "scan_layers and pipeline_stages stack one block's parameters "
             "along a layer axis and cannot hold layers that differ (heads, "
-            "window, rope, attention or Mamba mixer, dense/routed MLP by layer: "
+            "window, rope, attention, Mamba, KDA or MLA mixer, dense/routed MLP by layer: "
             "layer_types, num_attention_heads_per_layer, rope_parameters, "
             "mlp_only_layers, position_embedding_type)"
         )
@@ -1315,6 +1390,15 @@ def _make_config(config: dict) -> TransformerConfig:
             raise ValueError(
                 f"experts_per_token {cfg.experts_per_token} of "
                 f"{cfg.n_experts} experts"
+            )
+        groups, kept = cfg.router_groups, cfg.router_groups_kept
+        if groups < 1 or cfg.n_experts % groups or not 0 < kept <= groups or (
+            groups > 1 and cfg.experts_per_token > kept * (cfg.n_experts // groups)
+        ):
+            raise ValueError(
+                f"router_groups {groups} with {kept} kept do not divide "
+                f"{cfg.n_experts} experts or hold fewer than experts_per_token "
+                f"{cfg.experts_per_token}"
             )
         if cfg.expert_offset < 0 or cfg.expert_offset + cfg.held > cfg.n_experts:
             raise ValueError(
@@ -1340,6 +1424,7 @@ _STEP_STATS = {  # collection -> how each sown name is reduced over the layers
     "moe_stats": {"assignments_local": jnp.mean, "load_max_over_mean": jnp.max,
                   "overflow": jnp.sum},
     "ssm_stats": {"dt_max": jnp.max, "chunk_decay_min": jnp.min},
+    "kda_stats": {"log_decay_min": jnp.min, "beta_max": jnp.max},
 }
 
 
@@ -1350,7 +1435,9 @@ def step_metrics(sown: dict) -> dict:
     layer's), and the assignments that did not fit their buffer (0, or the
     Trainer stops). Mamba layers (`ssm_stats` -> `ssm.*`): the largest step
     size and the most negative in-chunk running sum of `dt A` (the worst
-    layer's: how far the in-chunk decays underflow)."""
+    layer's: how far the in-chunk decays underflow). KDA layers (`kda_stats`
+    -> `kda.*`): the most negative in-chunk running sum of the log-decay and
+    the largest step size, the worst layer's."""
     from flax.traverse_util import flatten_dict
 
     out = {}
@@ -1410,6 +1497,7 @@ def build_transformer(config: dict) -> ModelBundle:
         name for name, has in (
             ("moe_stats", cfg.n_experts > 0),
             ("ssm_stats", any(spec.mixer == "mamba" for spec in cfg.layers)),
+            ("kda_stats", any(spec.mixer == "kda" for spec in cfg.layers)),
         ) if has
     )
     fused = None

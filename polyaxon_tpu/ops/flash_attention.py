@@ -42,6 +42,12 @@ How the kernels tile and walk the score matrix:
   compare and select. The softmax scale is folded into the exponent's
   argument (forward) or applied once to the accumulator (dq, dk), never as
   a pass over a score tile.
+- **Two widths.** q and k share the score width (their last dim), v, o and
+  do the value width (v's last dim); the two may differ (latent attention:
+  scores over 192, values of 128). Nothing is padded: each operand's block
+  is as wide as the operand, dq and dk accumulate at the score width, o and
+  dv at the value width. Where the two are equal every kernel is what it
+  was.
 - **dk/dv works on transposed tiles** (`s^T = k q^T`, `[block_kv,
   block_q]`): both accumulating products are then plain `A @ B`, no tile is
   transposed, and logsumexp and delta come as lane-major rows (`[..., 1,
@@ -274,42 +280,51 @@ def _names(window):
     return {k: stem + k for k in KERNELS}
 
 
-def _q_major_specs(walk, group, D):
+def _q_major_specs(walk, group, D, Dv):
     """Block specs of the q-major kernels: (rows of every head of the
-    group, the K/V block of a step, the rows' statistics)."""
+    group at the score width and at the value width, the K and the V block
+    of a step, the rows' statistics)."""
     bq, bkv = walk.block_q, walk.block_kv
-    rows = pl.BlockSpec((1, group, bq, D), lambda b, i, j: (b, 0, i, 0))
-    # a dead step keeps the block of the last live one: no DMA is issued
-    kv = pl.BlockSpec((1, bkv, D), lambda b, i, j: (b, walk.at(i, j, clamp=True), 0))
+
+    def rows(width):
+        return pl.BlockSpec((1, group, bq, width), lambda b, i, j: (b, 0, i, 0))
+
+    def cols(width):
+        # a dead step keeps the block of the last live one: no DMA is issued
+        return pl.BlockSpec(
+            (1, bkv, width), lambda b, i, j: (b, walk.at(i, j, clamp=True), 0)
+        )
+
     # statistics ride a trailing singleton dim: Mosaic requires the last
     # two block dims divisible by (8, 128) OR equal to the array's
     stat = pl.BlockSpec((1, group, bq, 1), lambda b, i, j: (b, 0, i, 0))
-    return rows, kv, stat
+    return rows(D), rows(Dv), cols(D), cols(Dv), stat
 
 
 @_one_lowering
 def _fwd(q, k, v, causal, scale, blocks, window=None, *, interpret):
-    """q: [B*KV, group, S, D]; k, v: [B*KV, S, D] -> (o like q, lse
-    [B*KV, group, S, 1] f32)."""
+    """q: [B*KV, group, S, D]; k: [B*KV, S, D]; v: [B*KV, S, Dv] -> (o
+    [B*KV, group, S, Dv], lse [B*KV, group, S, 1] f32)."""
     from jax.experimental.pallas import tpu as pltpu
 
     BKV, group, S, D = q.shape
+    Dv = v.shape[-1]
     walk = _Walk.of("fwd", S, blocks, causal, window)
-    rows, kv, stat = _q_major_specs(walk, group, D)
+    q_rows, o_rows, k_cols, v_cols, stat = _q_major_specs(walk, group, D, Dv)
     bq = walk.block_q
     return pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, walk=walk),
         grid=(BKV, walk.nq, walk.steps),
-        in_specs=[rows, kv, kv],
-        out_specs=[rows, stat],
+        in_specs=[q_rows, k_cols, v_cols],
+        out_specs=[o_rows, stat],
         out_shape=[
-            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct((BKV, group, S, Dv), q.dtype),
             jax.ShapeDtypeStruct((BKV, group, S, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((group, bq, _LANES), jnp.float32),
             pltpu.VMEM((group, bq, _LANES), jnp.float32),
-            pltpu.VMEM((group, bq, D), jnp.float32),
+            pltpu.VMEM((group, bq, Dv), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
@@ -356,12 +371,12 @@ def _dq(q, k, v, do, lse, delta, causal, scale, blocks, window=None, *, interpre
 
     BKV, group, S, D = q.shape
     walk = _Walk.of("dq", S, blocks, causal, window)
-    rows, kv, stat = _q_major_specs(walk, group, D)
+    q_rows, o_rows, k_cols, v_cols, stat = _q_major_specs(walk, group, D, v.shape[-1])
     return pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, walk=walk),
         grid=(BKV, walk.nq, walk.steps),
-        in_specs=[rows, kv, kv, rows, stat, stat],
-        out_specs=rows,
+        in_specs=[q_rows, k_cols, v_cols, o_rows, stat, stat],
+        out_specs=q_rows,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((group, walk.block_q, D), jnp.float32)],
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
@@ -416,14 +431,19 @@ def _dkv(q, k, v, do, lse, delta, causal, scale, blocks, window=None, *, interpr
     from jax.experimental.pallas import tpu as pltpu
 
     BKV, group, S, D = q.shape
+    Dv = v.shape[-1]
     walk = _Walk.of("dkv", S, blocks, causal, window)
     bq, bkv = walk.block_q, walk.block_kv
 
     def q_at(b, j, t):
         return (b, 0, walk.at(j, t, clamp=True), 0)
 
-    rows = pl.BlockSpec((1, group, bq, D), q_at)
-    kv = pl.BlockSpec((1, bkv, D), lambda b, j, t: (b, j, 0))
+    def rows(width):
+        return pl.BlockSpec((1, group, bq, width), q_at)
+
+    def cols(width):
+        return pl.BlockSpec((1, bkv, width), lambda b, j, t: (b, j, 0))
+
     # a q block's statistics as one lane-major row: the last two block dims
     # (1, bq) ARE the array's, whatever bq is
     stat = pl.BlockSpec((1, group, 1, 1, bq), lambda b, j, t: (*q_at(b, j, t), 0))
@@ -431,15 +451,15 @@ def _dkv(q, k, v, do, lse, delta, causal, scale, blocks, window=None, *, interpr
     return pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, walk=walk),
         grid=(BKV, walk.nk, walk.steps),
-        in_specs=[rows, kv, kv, rows, stat, stat],
-        out_specs=[kv, kv],
+        in_specs=[rows(D), cols(D), cols(Dv), rows(Dv), stat, stat],
+        out_specs=[cols(D), cols(Dv)],
         out_shape=[
             jax.ShapeDtypeStruct(k.shape, k.dtype),
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((bkv, D), jnp.float32),
-            pltpu.VMEM((bkv, D), jnp.float32),
+            pltpu.VMEM((bkv, Dv), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
@@ -565,39 +585,45 @@ def _candidates(seq: int, fixed: int | None) -> list[int]:
     return [seq] if seq <= _PREFERRED else []
 
 
-def _vmem_bytes(kernel, bq, bkv, head_dim, group, itemsize):
+def _vmem_bytes(kernel, bq, bkv, head_dim, group, itemsize, value_dim=None):
     """What a step keeps in VMEM: double-buffered operand blocks, the f32
     scratch, and the score-sized temporaries (s, p, and for the backward dp,
     ds, with p's and ds's casts; the unrolled heads of a group overlap, so
-    some of the next head's live beside them)."""
+    some of the next head's live beside them). `head_dim` is the score
+    width (q, k, dq, dk), `value_dim` the value width (v, o, do, dv; None =
+    the same)."""
+    dv = head_dim if value_dim is None else value_dim
     tile = bq * bkv * 4 * (1 + group / 6)
-    rows, cols = group * bq * head_dim, bkv * head_dim
-    if kernel == "fwd":
-        blocks = 2 * itemsize * (2 * rows + 2 * cols) + 2 * group * bq * _LANES * 4
-        scratch = group * bq * (2 * _LANES + head_dim) * 4
+    rows, cols = group * bq, bkv  # times a width: an operand's block
+    both = head_dim + dv
+    if kernel == "fwd":  # q, o | k, v
+        blocks = 2 * itemsize * (rows * both + cols * both) + 2 * group * bq * _LANES * 4
+        scratch = group * bq * (2 * _LANES + dv) * 4
         return blocks + scratch + 3 * tile
     stats = 2 * 2 * group * bq * _LANES * 4
-    if kernel == "dq":
-        blocks = 2 * itemsize * (3 * rows + 2 * cols) + stats
-        return blocks + rows * 4 + 5 * tile
-    blocks = 2 * itemsize * (2 * rows + 4 * cols) + stats // 16
-    return blocks + 2 * cols * 4 + 5 * tile
+    if kernel == "dq":  # q, dq, do | k, v
+        blocks = 2 * itemsize * (rows * (both + head_dim) + cols * both) + stats
+        return blocks + rows * head_dim * 4 + 5 * tile
+    blocks = 2 * itemsize * (rows * both + 2 * cols * both) + stats // 16  # q, do | k, v, dk, dv
+    return blocks + cols * both * 4 + 5 * tile
 
 
-def _estimate_seconds(kernel, walk, head_dim, group, itemsize):
+def _estimate_seconds(kernel, walk, head_dim, group, itemsize, value_dim=None):
     """One kv head's walk by `_COSTS`: a live step costs its scores, its
     resident and its streamed rows (but not less than the streamed blocks
     take across HBM), a step that masks the mask, every grid step its
     fixed cost. A head narrower than the MXU's 128 columns costs a full
-    one; a wider one in proportion."""
+    one; a wider one in proportion (a kernel's products are half over the
+    score width and half over the value width: their mean)."""
     bq, bkv = walk.block_q, walk.block_kv
     score, resident, streamed, mask, step = _COSTS[kernel]
-    score *= max(head_dim, 128) / 128
+    both = head_dim + (head_dim if value_dim is None else value_dim)
+    score *= max(both / 2, 128) / 128
     rows, cols = (bkv, group * bq) if walk.kv_major else (group * bq, bkv)
     if walk.kv_major:  # q, do and the two statistics rows stream, K/V stay
-        hbm = cols * (2 * head_dim * itemsize + 2 * 8 * 4)
+        hbm = cols * (both * itemsize + 2 * 8 * 4)
     else:  # K and V stream, the rows stay
-        hbm = 2 * cols * head_dim * itemsize
+        hbm = cols * both * itemsize
     live = max(
         rows * cols * score + rows * resident + cols * streamed,
         hbm / _HBM_BYTES_PER_SECOND,
@@ -614,9 +640,12 @@ def choose_blocks(
     kernel: str, seq: int, head_dim: int, group: int = 1,
     window: int | None = None, dtype=jnp.bfloat16, causal: bool = True,
     block_q: int | None = None, block_kv: int | None = None,
+    value_dim: int | None = None,
 ) -> tuple[int, int]:
     """`(block_q, block_kv)` for one of the three kernels (`fwd`, `dq`,
     `dkv`), from the call's shape alone: no table of models, no switch.
+    `head_dim` is the score width; `value_dim` the value width where it
+    differs.
 
     The pair that minimises an estimate of the walk's time
     (`_estimate_seconds`, its constants measured on the chip) over the
@@ -646,7 +675,8 @@ def choose_blocks(
     if kernel not in KERNELS:
         raise ValueError(f"unknown flash kernel {kernel!r}")
     return _choose(kernel, seq, head_dim, group, _clip(window, seq),
-                   jnp.dtype(dtype).itemsize, causal, block_q, block_kv)
+                   jnp.dtype(dtype).itemsize, causal, block_q, block_kv,
+                   None if value_dim == head_dim else value_dim)
 
 
 def _clip(window, seq):
@@ -655,15 +685,18 @@ def _clip(window, seq):
 
 
 @functools.lru_cache(maxsize=None)
-def _choose(kernel, seq, head_dim, group, window, itemsize, causal, block_q, block_kv):
+def _choose(kernel, seq, head_dim, group, window, itemsize, causal, block_q, block_kv,
+            value_dim=None):
     best = None
     for bq in _candidates(seq, block_q):
         for bkv in _candidates(seq, block_kv):
             if seq % bq or seq % bkv:  # an explicit block that does not divide
                 continue
-            fits = _vmem_bytes(kernel, bq, bkv, head_dim, group, itemsize) <= _VMEM_BUDGET
+            fits = _vmem_bytes(
+                kernel, bq, bkv, head_dim, group, itemsize, value_dim
+            ) <= _VMEM_BUDGET
             walk = _Walk.of(kernel, seq, (bq, bkv), causal, window)
-            cost = _estimate_seconds(kernel, walk, head_dim, group, itemsize)
+            cost = _estimate_seconds(kernel, walk, head_dim, group, itemsize, value_dim)
             # a pair that does not fit is kept only while nothing fits (an
             # explicit block is obeyed whatever it needs)
             key = (not fits, cost, bq * bkv)
@@ -676,10 +709,11 @@ def _choose(kernel, seq, head_dim, group, window, itemsize, causal, block_q, blo
     return best[1]
 
 
-def _all_blocks(seq, head_dim, group, window, dtype, causal, block_q, block_kv):
+def _all_blocks(seq, head_dim, group, window, dtype, causal, block_q, block_kv,
+                value_dim=None):
     return _Blocks(*(
         choose_blocks(kernel, seq, head_dim, group, window, dtype, causal,
-                      block_q, block_kv)
+                      block_q, block_kv, value_dim)
         for kernel in KERNELS
     ))
 
@@ -687,13 +721,17 @@ def _all_blocks(seq, head_dim, group, window, dtype, causal, block_q, block_kv):
 def tile_report(
     seq: int, head_dim: int, group: int = 1, window: int | None = None,
     dtype=jnp.bfloat16, causal: bool = True, block_q: int | None = None,
-    block_kv: int | None = None,
+    block_kv: int | None = None, value_dim: int | None = None,
 ) -> list[dict]:
     """What the three kernels of one call shape would run: per kernel its
     name, the shape, the blocks chosen and the walk's counts (grid steps,
-    live steps, steps that mask, executed over required pairs), a head."""
+    live steps, steps that mask, executed over required pairs), a head. A
+    call whose value width differs from its score width (`head_dim`) says
+    so under `value_dim`."""
     window = _clip(window, seq)
-    blocks = _all_blocks(seq, head_dim, group, window, dtype, causal, block_q, block_kv)
+    blocks = _all_blocks(
+        seq, head_dim, group, window, dtype, causal, block_q, block_kv, value_dim
+    )
     out = []
     for kernel in KERNELS:
         walk = _Walk.of(kernel, seq, getattr(blocks, kernel), causal, window)
@@ -701,6 +739,7 @@ def tile_report(
             "kernel": _names(window)[kernel], "seq": seq, "head_dim": head_dim,
             "group": group, "window": window, "causal": causal,
             "block_q": walk.block_q, "block_kv": walk.block_kv, **walk.counts(),
+            **({} if value_dim in (None, head_dim) else {"value_dim": value_dim}),
         })
     return out
 
@@ -728,24 +767,29 @@ def flash_shapes_ok(
     )
 
 
-def _prepare(q, k, block_q, block_kv, window, causal):
+def _prepare(q, k, v, block_q, block_kv, window, causal):
     """Shared front of the two entry points: the group, the blocks of the
-    three kernels, and the layouts the kernels take."""
+    three kernels, and the layouts the kernels take. q and k share the score
+    width, v has the value width (the output's)."""
     B, S, H, D = q.shape
     KV = k.shape[2]
     if H % KV:
         raise ValueError(f"query heads {H} not divisible by kv heads {KV}")
+    if k.shape[3] != D:
+        raise ValueError(f"q is {D} wide and k {k.shape[3]}: scores need one width")
     group = H // KV
-    blocks = _all_blocks(S, D, group, window, q.dtype, causal, block_q, block_kv)
+    blocks = _all_blocks(
+        S, D, group, window, q.dtype, causal, block_q, block_kv, v.shape[3]
+    )
 
     def rows(x):  # [B,S,H,D] -> [B*KV, group, S, D]
-        return x.transpose(0, 2, 1, 3).reshape(B * KV, group, S, D)
+        return x.transpose(0, 2, 1, 3).reshape(B * KV, group, S, x.shape[3])
 
     def cols(x):  # [B,S,KV,D] -> [B*KV, S, D]
-        return x.transpose(0, 2, 1, 3).reshape(B * KV, S, D)
+        return x.transpose(0, 2, 1, 3).reshape(B * KV, S, x.shape[3])
 
-    def back(o):  # [B*KV, group, S, D] -> [B,S,H,D]
-        return o.reshape(B, H, S, D).transpose(0, 2, 1, 3)
+    def back(o):  # [B*KV, group, S, Dv] -> [B,S,H,Dv]
+        return o.reshape(B, H, S, o.shape[3]).transpose(0, 2, 1, 3)
 
     return blocks, rows, cols, back
 
@@ -758,7 +802,7 @@ def flash_attention_lse(
     the delta term of the shared backward kernels) — ring attention's
     cross-hop online-softmax merge depends on that."""
     B, S, H, D = q.shape
-    blocks, rows, cols, back = _prepare(q, k, block_q, block_kv, None, causal)
+    blocks, rows, cols, back = _prepare(q, k, v, block_q, block_kv, None, causal)
     scale = sm_scale if sm_scale is not None else D ** -0.5
     o, lse = _flash_lse(rows(q), cols(k), cols(v), causal, scale, blocks)
     return back(o), lse.reshape(B, H, S)
@@ -768,7 +812,9 @@ def flash_attention(
     q, k, v, *, causal=True, block_q=None, block_kv=None, sm_scale=None,
     window=None,
 ):
-    """q: [B, S, H, D]; k/v: [B, S, KV, D] with KV dividing H.
+    """q: [B, S, H, D]; k: [B, S, KV, D]; v: [B, S, KV, Dv] with KV dividing
+    H. D is the score width (the default scale is D ** -0.5), Dv the value
+    width, which is the output's; the two may differ.
 
     `window` (causal only): query i attends keys j with 0 <= i - j <
     window. A window that covers the whole sequence is plain causal
@@ -782,7 +828,7 @@ def flash_attention(
     (`jnp.repeat` before the call) never exist in HBM, and a K/V byte
     fetched serves the whole group. The backward accumulates dk/dv across
     the group inside the kv-block scratch.
-    Returns [B, S, H, D]."""
+    Returns [B, S, H, Dv]."""
     S, D = q.shape[1], q.shape[3]
     if window is not None:
         if not causal:
@@ -790,6 +836,6 @@ def flash_attention(
         if window < 1:
             raise ValueError(f"window must be at least 1, got {window}")
         window = _clip(window, S)
-    blocks, rows, cols, back = _prepare(q, k, block_q, block_kv, window, causal)
+    blocks, rows, cols, back = _prepare(q, k, v, block_q, block_kv, window, causal)
     scale = sm_scale if sm_scale is not None else D ** -0.5
     return back(_flash(rows(q), cols(k), cols(v), causal, scale, blocks, window))

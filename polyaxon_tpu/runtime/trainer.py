@@ -1018,7 +1018,11 @@ class Trainer:
         `polyaxon.model.ssm` (run store `model_ssm`): the scan's chunk count
         and the bytes of its largest intermediate for the step's shape, and
         per Mamba layer which path each fused chain of the mixer takes
-        (`ops/mamba_fused.py`: `pallas` with its tiles, or `xla` and why)."""
+        (`ops/mamba_fused.py`: `pallas` with its tiles, or `xla` and why). A
+        model with KDA layers adds `polyaxon.model.kda` (run store
+        `model_kda`): the delta-rule scan's chunk, sub-block, chunk count,
+        heads walked at a time and largest intermediate, and per KDA layer
+        the path of its three short convolutions."""
         cfg = getattr(self.bundle.module, "cfg", None)
         if cfg is None or not hasattr(cfg, "layer"):
             return
@@ -1031,14 +1035,14 @@ class Trainer:
             "model.layers", n_layers=len(layers), layers=json.dumps(layers)
         )
         self._event("model_layers", {"layers": layers})
+        rows = max(  # a device's rows of the global batch
+            1, self.data.batch_size * jax.process_count() // local_batch_slice(self.mesh)
+        )
+        seq = int(self.data.meta.get("seq_len") or cfg.seq_len)
         if any(layer["mixer"] == "mamba" for layer in layers):
             from ..ops.mamba_fused import conv_plan, gate_plan
             from ..ops.ssd import heads_per_step, largest_intermediate_bytes
 
-            rows = max(  # a device's rows of the global batch
-                1, self.data.batch_size * jax.process_count() // local_batch_slice(self.mesh)
-            )
-            seq = int(self.data.meta.get("seq_len") or cfg.seq_len)
             shape = (rows, seq, cfg.mamba_chunk_size)
             inner = cfg.mamba_n_heads * cfg.mamba_d_head
             conv_width = inner + 2 * cfg.mamba_n_groups * cfg.mamba_d_state
@@ -1064,6 +1068,24 @@ class Trainer:
             }
             get_tracer().event("model.ssm", **{**ssm, "fused": json.dumps(ssm["fused"])})
             self._event("model_ssm", ssm)
+        kda_layers = [(i, layer) for i, layer in enumerate(layers) if layer["mixer"] == "kda"]
+        if kda_layers:
+            from ..ops import kda as kda_ops
+            from ..ops.mamba_fused import conv_plan
+
+            heads, width = kda_layers[0][1]["heads"], kda_layers[0][1]["key_width"]  # of all alike
+            shape = (rows, seq, cfg.kda_chunk_size, heads, width,
+                     jnp.dtype(self.compute_dtype).itemsize)
+            conv = conv_plan(seq, heads * width, self.compute_dtype, 0, cfg.kda_conv)
+            kda = {
+                "rows": rows, "seq_len": seq, "chunk": cfg.kda_chunk_size,
+                "sub_block": kda_ops.SUB, "chunks": seq // cfg.kda_chunk_size,
+                "heads_per_step": kda_ops.heads_per_step(*shape),
+                "largest_intermediate_bytes": kda_ops.largest_intermediate_bytes(*shape),
+                "layers": [{"layer": i, "conv_silu": conv} for i, _ in kda_layers],
+            }
+            get_tracer().event("model.kda", **{**kda, "layers": json.dumps(kda["layers"])})
+            self._event("model_kda", kda)
         self._report_flash_tiles(cfg)
 
     def _report_flash_tiles(self, cfg):
@@ -1086,16 +1108,21 @@ class Trainer:
             backend = resolve_auto_backend(seq, cfg.attention_block, cfg.head_size)
         if backend != "flash":
             return
+        specs = [cfg.layer(i) for i in range(cfg.n_layers)]
+        # (score width, value width, GQA group, window) of each distinct call:
+        # a latent-attention layer's heads each have keys of their own
         shapes = sorted(
-            {(s.n_heads // cfg.n_kv_heads, s.window)
-             for s in map(cfg.layer, range(cfg.n_layers)) if s.mixer == "attention"}
+            {(cfg.head_size, cfg.head_size, s.n_heads // cfg.n_kv_heads, s.window)
+             for s in specs if s.mixer == "attention"}
+            | {(cfg.mla_nope_dim + cfg.mla_rope_dim, cfg.mla_value_dim, 1, 0)
+               for s in specs if s.mixer == "mla"}
         )
         calls = [
             call
-            for group, window in shapes
+            for width, value, group, window in shapes
             for call in tile_report(
-                seq, cfg.head_size, group, window or None, self.compute_dtype,
-                block_kv=cfg.attention_block,
+                seq, width, group, window or None, self.compute_dtype,
+                block_kv=cfg.attention_block, value_dim=value,
             )
         ]
         get_tracer().event("kernels.flash_tiles", n_calls=len(calls), calls=json.dumps(calls))

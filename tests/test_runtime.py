@@ -205,7 +205,7 @@ class TestTracking:
 class TestCli:
     @pytest.mark.parametrize(
         "example,batch",
-        [("mnist", 16), ("granite_hybrid_lora", 8)],  # 8: a row a virtual device
+        [("mnist", 16), ("granite_hybrid_lora", 8), ("ling_hybrid_lora", 8)],  # 8: a row a virtual device
     )
     def test_run_and_ops(self, tmp_home, example, batch):
         from click.testing import CliRunner

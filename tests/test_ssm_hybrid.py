@@ -317,6 +317,7 @@ def test_trainer_reports_mixers_the_scan_and_its_decays():
     assert layers[0] == {
         "mixer": "mamba", "heads": 8, "head_width": 16, "state": 16, "conv": 4,
         "chunk": 8, "groups": 1, "mlp": "routed", "experts_held": 2, "experts_published": 8,
+        "score": "softmax", "router_groups": 1, "router_groups_kept": 1,
     }
     assert layers[1]["rope"] == "none" and layers[1]["heads"] == 4
     # the rehearsal's widths: `xBC` of 128 + 2 x 16 columns is no multiple of
