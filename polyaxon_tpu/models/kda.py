@@ -11,7 +11,11 @@ published config gives: `no_kda_lora`, `kda_safe_gate`, `linear_silu`,
 
 `conv` is a depthwise causal convolution over time without bias, and the
 SiLU after it is fused into it (`ops/mamba_fused.conv_silu`, the kernels of
-the Mamba mixer: a Pallas pair where the shape allows). No rotation: a
+the Mamba mixer: a Pallas pair where the shape allows). q, k, v and g reach
+the scan with heads and widths merged, `[B, S, H x P]`, as the convs and
+`f_proj` leave them (on the chip `[B, S, H, P]` is another tiling, and the
+scan's kernels read the merged one), and the scan normalises q and k
+(`kda_scan(..., unit_scales=)`). No rotation: a
 delta-rule layer takes positions from its recurrence. `f_proj` and `g_proj`
 are one full matrix each (`no_kda_lora`; Kimi Linear has a low-rank pair).
 
@@ -41,28 +45,20 @@ from ..ops.kda import kda_scan
 from ..ops.mamba_fused import conv_silu
 from .ssm import _a_log_init, _dt_bias_init  # fla draws both as Mamba-2 does
 
-_L2_EPS = 1e-6
 
-
-# The mixer's three elementwise chains, each under a `jax.checkpoint`: the
-# backward keeps a chain's inputs in the activations' type and builds its
-# float32 `[tokens, heads x width]` values again (a KDA block kept a dozen of
-# them, 256 MB each at 16,384 tokens: more than the chip has beside the
-# weights).
-@functools.partial(jax.checkpoint, static_argnums=(1,))
-def _unit(x, scale: float):
-    """x / sqrt(sum x^2 + eps) * scale over the last axis, in x's type."""
-    x32 = x.astype(jnp.float32)
-    unit = x32 * jax.lax.rsqrt(jnp.sum(x32 * x32, -1, keepdims=True) + _L2_EPS)
-    return (unit * scale).astype(x.dtype)
-
-
+# The mixer's elementwise chains, each under a `jax.checkpoint` (the l2 norm
+# of q and k is `ops/kda.l2_unit`, applied by the scan): the backward keeps a
+# chain's inputs in the activations' type and builds its float32 `[tokens,
+# heads x width]` values again (a KDA block kept a dozen of them, 256 MB each
+# at 16,384 tokens: more than the chip has beside the weights).
 @functools.partial(jax.checkpoint, static_argnums=(3,))
 def _log_decay(f, a_log, dt_bias, bound: float):
-    """bound * sigmoid(exp(A_log_h) * (f + dt_bias)): f [B, S, H, P], a_log
-    [H], dt_bias [H, P] -> float32 in (bound, 0)."""
+    """bound * sigmoid(exp(A_log_h) * (f + dt_bias)): f [B, S, H x P], a_log
+    [H], dt_bias [H x P] -> float32 in (bound, 0), heads and widths merged as
+    `f_proj` gives them (elementwise: nothing here needs a head's axis, and
+    the scan's kernels read the merged form)."""
     f32 = jnp.float32
-    rate = jnp.exp(a_log.astype(f32))[:, None]
+    rate = jnp.repeat(jnp.exp(a_log.astype(f32)), dt_bias.shape[0] // a_log.shape[0])
     return bound * jax.nn.sigmoid(rate * (f.astype(f32) + dt_bias.astype(f32)))
 
 
@@ -104,23 +100,23 @@ class KimiDeltaAttention(nn.Module):
             kernel = self.param(
                 f"{name}_conv_kernel", nn.initializers.normal(1.0 / np.sqrt(taps)), (taps, inner)
             )
-            return conv_silu(x, kernel, jnp.zeros((inner,), kernel.dtype)).reshape(
-                bsz, seq, heads, p
-            )
+            return conv_silu(x, kernel, jnp.zeros((inner,), kernel.dtype))
 
-        q = _unit(short_conv("q"), p**-0.5)
-        k = _unit(short_conv("k"), 1.0)
-        v = short_conv("v")
+        # heads and widths merged, as the scan's kernels read them; the scan
+        # normalises a head's queries and keys (`ops/kda.l2_unit`)
+        q, k, v = short_conv("q"), short_conv("k"), short_conv("v")
 
         a_log = self.param("A_log", _a_log_init, (heads,))
         dt_bias = self.param("dt_bias", _dt_bias_init, (inner,))
-        f = nn.Dense(inner, use_bias=False, name="f_proj")(u).reshape(bsz, seq, heads, p)
-        g = _log_decay(f, a_log, dt_bias.reshape(heads, p), float(cfg.kda_gate_bound))
+        f = nn.Dense(inner, use_bias=False, name="f_proj")(u)
+        g = _log_decay(f, a_log, dt_bias, float(cfg.kda_gate_bound))  # merged as v
         beta = jax.nn.sigmoid(nn.Dense(heads, use_bias=False, name="b_proj")(u).astype(f32))
 
         chunk = cfg.kda_chunk_size
-        o = kda_scan(q, k, v, g, beta, chunk=chunk)  # refuses a sequence off the chunk
-        sums = g.reshape(bsz, seq // chunk, chunk, heads, p).sum(axis=2)
+        # refuses a sequence off the chunk; o merged as v came
+        o = kda_scan(q, k, v, g, beta, chunk=chunk, unit_scales=(p**-0.5, 1.0)).reshape(
+            bsz, seq, heads, p)
+        sums = g.reshape(bsz, seq // chunk, chunk, inner).sum(axis=2)
         self.sow("kda_stats", "log_decay_min", jnp.min(sums))
         self.sow("kda_stats", "beta_max", jnp.max(beta))
 

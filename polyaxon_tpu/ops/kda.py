@@ -1,5 +1,11 @@
 """The gated delta rule of a Kimi-Delta-Attention layer (arXiv:2510.26692) in
-its chunked form, in `jax.numpy`, differentiable by autodiff.
+its chunked form. `kda_scan` runs it as the Pallas kernels of `kda_fused.py`
+where the shape allows (`kda_fused.scan_plan`: key = value width a multiple
+of 128, a chunk that is a multiple of `SUB` and divides a tile of 128
+positions, a sequence of whole tiles and runs, float32 or bf16) and in
+`jax.numpy`, differentiable by
+autodiff, everywhere else (`_scan_xla`, which the kernels are checked
+against). The algebra and the numbers below are both paths'.
 
 Per head, with a state `S` in R^(K x V), `S_0 = 0`, a log-decay `g_t <= 0` a
 KEY CHANNEL and a step size `beta_t` in (0, 1):
@@ -38,13 +44,16 @@ positive where its partner could be infinite, and what a mask removes is
 finite.
 
 **The inverse.** `(I + A)` is unit lower triangular: a forward substitution
-in float32 (`solve_triangular`), with a hand-written backward of two
-float32 products at `Precision.HIGHEST` (`-X^T dX X^T`) in place of the
-substitution's own transpose.
+in float32 (`solve_triangular` here; in the kernels the sub-blocks by
+substitution and their merges by block products at `HIGHEST`), with a
+hand-written backward of two float32 products at `Precision.HIGHEST`
+(`-X^T dX X^T`) in place of the substitution's own transpose.
 
-**Memory.** The largest intermediate is a head block's keys scaled for each
-of the sub-blocks that meet them (`[B, S, heads, chunk / 16, K]`: four times
-a head block's keys at chunk 64, in `q`'s type); the heads are walked in
+**Memory, on the `jax.numpy` path** (the kernels keep a chunk's values in
+VMEM and one state a run in HBM: `kda_fused.py`). The largest intermediate
+is a head block's keys scaled for each of the sub-blocks that meet them
+(`[B, S, heads, chunk / 16, K]`: four times a head block's keys at chunk 64,
+in `q`'s type); the heads are walked in
 blocks (`lax.map`, each block's body a `jax.checkpoint`, as `ops/ssd.py`
 walks its own) so that it stays under `_BLOCK_BYTES`. The walk over the chunks that
 carries the state takes every head at once (its steps are latency, not
@@ -56,10 +65,14 @@ A sequence that is no multiple of the chunk is refused, not padded.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
-SUB = 16  # positions of a sub-block: SUB * |gate bound| must stay under float32's 88
+from . import kda_fused
+
+SUB = kda_fused.SUB  # positions of a sub-block
 _BLOCK_BYTES = 128 * 1024 * 1024  # one head block's largest intermediate
 _RUN = 16  # chunks the carried walk takes under one checkpoint
 _HI = jax.lax.Precision.HIGHEST
@@ -153,12 +166,34 @@ def _chunk_operands(q, k, v, g, beta):
     return w, u, scores, q_in, k_out, jnp.exp(run[..., -1, :])
 
 
-def kda_scan(q, k, v, g, beta, *, chunk: int = 64, block_heads: int | None = None):
+@functools.partial(jax.checkpoint, static_argnums=(1,))
+def l2_unit(x, scale: float):
+    """x / sqrt(sum x^2 + eps) * scale over the last axis, in x's type: how a
+    KDA layer normalises a head's queries and keys. Under a checkpoint: the
+    backward keeps x and builds the float32 values again."""
+    x32 = x.astype(jnp.float32)
+    unit = x32 * jax.lax.rsqrt(jnp.sum(x32 * x32, -1, keepdims=True) + kda_fused.L2_EPS)
+    return (unit * scale).astype(x.dtype)
+
+
+def kda_scan(q, k, v, g, beta, *, chunk: int = 64, block_heads: int | None = None,
+             unit_scales: tuple[float, float] | None = None):
     """q, k [B, S, H, K]; v [B, S, H, V]; g [B, S, H, K] (log-decay, in
     [-88 / SUB, 0]); beta [B, S, H]. Returns o [B, S, H, V] in q's type.
-    `block_heads` overrides how many heads are taken at a time."""
-    bsz, seq, heads, key = q.shape
-    val = v.shape[-1]
+    Each of q, k, v, g may also come with heads and width merged, `[B, S, H x
+    K]`, as a projection or a conv hands it over (on the chip that is another
+    tiling than `[B, S, H, K]`, and the kernels read the merged one: what
+    comes merged is not copied); o comes back as v came. With `unit_scales`
+    = (of q, of k) a head's queries and keys arrive as the convs leave them
+    and are normalised here (`l2_unit`; the kernels do it to the rows they
+    hold, so q and k too can come merged). The shape decides
+    what runs (`kda_fused.scan_plan`): the Pallas kernels, or the `jax.numpy`
+    form below, of which `block_heads` overrides how many heads are taken at
+    a time."""
+    bsz, seq, heads = beta.shape
+    merged = v.ndim == 3
+    key = q.shape[-1] // (heads if q.ndim == 3 else 1)
+    val = v.shape[-1] // (heads if merged else 1)
     if seq % chunk:
         raise ValueError(
             f"the delta-rule scan works on whole chunks: a sequence of {seq} "
@@ -167,56 +202,73 @@ def kda_scan(q, k, v, g, beta, *, chunk: int = 64, block_heads: int | None = Non
         )
     if chunk % SUB:
         raise ValueError(f"the chunk {chunk} is no multiple of the sub-block {SUB}")
+    plan = kda_fused.scan_plan(bsz, seq, chunk, heads, key, val, q.dtype)
+    with jax.named_scope("kda"):
+        if plan["path"] == "pallas":
+            o = kda_fused.scan(q, k, v, g, beta, chunk=chunk, run=plan["run"],
+                               unit_scales=unit_scales)
+        else:
+            q, k, v, g = (x.reshape(bsz, seq, heads, -1) for x in (q, k, v, g))
+            if unit_scales is not None:
+                q, k = l2_unit(q, float(unit_scales[0])), l2_unit(k, float(unit_scales[1]))
+            o = _scan_xla(q, k, v, g, beta, chunk=chunk, block_heads=block_heads)
+        return o.reshape(bsz, seq, heads * val) if merged else o.reshape(bsz, seq, heads, val)
+
+
+def _scan_xla(q, k, v, g, beta, *, chunk: int = 64, block_heads: int | None = None):
+    """The scan in `jax.numpy`, differentiable by autodiff: every shape the
+    kernels refuse, and what they are checked against."""
+    bsz, seq, heads, key = q.shape
+    val = v.shape[-1]
     hb = block_heads or heads_per_step(bsz, seq, chunk, heads, key, q.dtype.itemsize)
     if heads % hb:
         raise ValueError(f"block_heads {hb} does not divide {heads} heads")
     nc, nb = seq // chunk, heads // hb
     dtype, f32 = q.dtype, jnp.float32
 
-    with jax.named_scope("kda"):
-        def blocks(x):  # [B, S, H, ...] -> [nb, B, nc, C, hb, ...]
-            x = x.reshape(bsz, nc, chunk, nb, hb, *x.shape[3:])
-            return jnp.moveaxis(x, 3, 0)
+    def blocks(x):  # [B, S, H, ...] -> [nb, B, nc, C, hb, ...]
+        x = x.reshape(bsz, nc, chunk, nb, hb, *x.shape[3:])
+        return jnp.moveaxis(x, 3, 0)
 
-        parts = jax.lax.map(
-            lambda t: jax.checkpoint(_chunk_operands)(*t),
-            (blocks(q), blocks(k), blocks(v), blocks(g.astype(f32)), blocks(beta.astype(f32))),
-        )
-        # [nb, B, nc, hb, ...] -> [nc, B, H, ...]: the walk's leading axis
-        w, u, scores, q_in, k_out, whole = (
-            jnp.moveaxis(x, 0, 2).reshape(bsz, nc, heads, *x.shape[4:]).swapaxes(0, 1)
-            for x in parts
-        )
+    parts = jax.lax.map(
+        lambda t: jax.checkpoint(_chunk_operands)(*t),
+        (blocks(q), blocks(k), blocks(v), blocks(g.astype(f32)), blocks(beta.astype(f32))),
+    )
+    # [nb, B, nc, hb, ...] -> [nc, B, H, ...]: the walk's leading axis
+    w, u, scores, q_in, k_out, whole = (
+        jnp.moveaxis(x, 0, 2).reshape(bsz, nc, heads, *x.shape[4:]).swapaxes(0, 1)
+        for x in parts
+    )
 
-        def carry(state, inp):
-            w_c, u_c, k_c, whole_c, q_c, s_c = inp
-            before = state.astype(dtype)
-            new = (u_c.astype(f32) - jnp.einsum(
-                "bhik,bhkv->bhiv", w_c, before, preferred_element_type=f32
-            )).astype(dtype)  # U - W S
-            out = jnp.einsum("bhik,bhkv->bhiv", q_c, before, preferred_element_type=f32)
-            out = out + jnp.einsum("bhij,bhjv->bhiv", s_c, new, preferred_element_type=f32)
-            state = whole_c[..., None] * state + jnp.einsum(
-                "bhik,bhiv->bhkv", k_c, new, preferred_element_type=f32
-            )
-            return state, out.astype(dtype)
+    def carry(state, inp):
+        w_c, u_c, k_c, whole_c, q_c, s_c = inp
+        before = state.astype(dtype)
+        new = (u_c.astype(f32) - jnp.einsum(
+            "bhik,bhkv->bhiv", w_c, before, preferred_element_type=f32
+        )).astype(dtype)  # U - W S
+        out = jnp.einsum("bhik,bhkv->bhiv", q_c, before, preferred_element_type=f32)
+        out = out + jnp.einsum("bhij,bhjv->bhiv", s_c, new, preferred_element_type=f32)
+        state = whole_c[..., None] * state + jnp.einsum(
+            "bhik,bhiv->bhkv", k_c, new, preferred_element_type=f32
+        )
+        return state, out.astype(dtype)
 
-        # the chunks in runs of `_RUN`, a checkpoint a run: the backward keeps
-        # the state each run starts from and walks the run again, not the
-        # state of every chunk (`[S / chunk, H, K, V]` float32: 0.5 GB at
-        # 16,384 positions and 32 heads of 128)
-        run_len = max(d for d in range(1, min(_RUN, nc) + 1) if nc % d == 0)
-        runs = jax.tree.map(
-            lambda x: x.reshape(nc // run_len, run_len, *x.shape[1:]),
-            (w, u, k_out, whole, q_in, scores),
-        )
-        state0 = jnp.zeros((bsz, heads, key, val), f32)
-        _, out = jax.lax.scan(
-            jax.checkpoint(lambda state, run: jax.lax.scan(carry, state, run)), state0, runs
-        )
-        # [nc / run, run, B, H, C, V] -> [B, S, H, V]
-        out = out.reshape(nc, bsz, heads, chunk, val)
-        return out.transpose(1, 0, 3, 2, 4).reshape(bsz, seq, heads, val)
+    # the chunks in runs of `_RUN`, a checkpoint a run: the backward keeps
+    # the state each run starts from and walks the run again, not the
+    # state of every chunk (`[S / chunk, H, K, V]` float32: 0.5 GB at
+    # 16,384 positions and 32 heads of 128)
+    run_len = max(d for d in range(1, min(_RUN, nc) + 1) if nc % d == 0)
+    runs = jax.tree.map(
+        lambda x: x.reshape(nc // run_len, run_len, *x.shape[1:]),
+        (w, u, k_out, whole, q_in, scores),
+    )
+    state0 = jnp.zeros((bsz, heads, key, val), f32)
+    _, out = jax.lax.scan(
+        jax.checkpoint(lambda state, run: jax.lax.scan(carry, state, run)), state0, runs
+    )
+    # [nc / run, run, B, H, C, V] -> [B, S, H, V]
+    out = out.reshape(nc, bsz, heads, chunk, val)
+    return out.transpose(1, 0, 3, 2, 4).reshape(bsz, seq, heads, val)
 
 
 def kda_recurrence(q, k, v, g, beta):

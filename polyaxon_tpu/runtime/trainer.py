@@ -1071,18 +1071,22 @@ class Trainer:
         kda_layers = [(i, layer) for i, layer in enumerate(layers) if layer["mixer"] == "kda"]
         if kda_layers:
             from ..ops import kda as kda_ops
+            from ..ops.kda_fused import scan_plan
             from ..ops.mamba_fused import conv_plan
 
             heads, width = kda_layers[0][1]["heads"], kda_layers[0][1]["key_width"]  # of all alike
             shape = (rows, seq, cfg.kda_chunk_size, heads, width,
                      jnp.dtype(self.compute_dtype).itemsize)
             conv = conv_plan(seq, heads * width, self.compute_dtype, 0, cfg.kda_conv)
+            scan = scan_plan(rows, seq, cfg.kda_chunk_size, heads, width, width,
+                             self.compute_dtype)
             kda = {
                 "rows": rows, "seq_len": seq, "chunk": cfg.kda_chunk_size,
                 "sub_block": kda_ops.SUB, "chunks": seq // cfg.kda_chunk_size,
+                # what the `xla` path walks; the kernels keep a state a run
                 "heads_per_step": kda_ops.heads_per_step(*shape),
                 "largest_intermediate_bytes": kda_ops.largest_intermediate_bytes(*shape),
-                "layers": [{"layer": i, "conv_silu": conv} for i, _ in kda_layers],
+                "layers": [{"layer": i, "conv_silu": conv, "scan": scan} for i, _ in kda_layers],
             }
             get_tracer().event("model.kda", **{**kda, "layers": json.dumps(kda["layers"])})
             self._event("model_kda", kda)

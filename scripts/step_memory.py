@@ -20,6 +20,7 @@ the chip's traced runs print; `PERF.md` section 4 has the table read with it.
 """
 
 import argparse
+import importlib
 import json
 import os
 import sys
@@ -50,10 +51,11 @@ def main() -> None:
     from polyaxon_tpu.ops import flash_attention
 
     flash_attention._interpret = lambda: False  # lower the kernels for Mosaic
-    try:  # wraps its kernels in `jax.jit` as it is imported: before the stand-in below
-        from polyaxon_tpu.ops import mamba_fused  # noqa: F401
-    except ImportError:  # a `--repo` from before PR 34
-        pass
+    for kernels in ("mamba_fused", "kda_fused"):
+        try:  # wraps its kernels in `jax.jit` as it is imported: before the stand-in below
+            importlib.import_module(f"polyaxon_tpu.ops.{kernels}")
+        except ImportError:  # a `--repo` from before PR 34 or PR 36
+            pass
     from cellbench.drivers import train as driver
     from polyaxon_tpu.runtime.trainer import Trainer
     from polyaxon_tpu.schemas.run_kinds import V1Program

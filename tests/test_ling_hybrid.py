@@ -129,10 +129,12 @@ def test_trainer_reports_the_layers_the_scan_and_its_gates():
         "router_groups_kept": 2}
     # the rehearsal's width of 64 is no multiple of 128: the jax.numpy conv
     conv = {"path": "xla", "why": "width 64 is no multiple of 128"}
+    # and a head's width of 16 is none either: the jax.numpy scan, which says why
+    scan = {"path": "xla", "why": "width 16 is no multiple of 128"}
     assert by_kind["model_kda"] == {
         "rows": 1, "seq_len": 64, "chunk": 16, "sub_block": 16, "chunks": 4, "heads_per_step": 4,
         "largest_intermediate_bytes": 4 * 64 * 1 * 16 * 4,
-        "layers": [{"layer": i, "conv_silu": conv} for i in (0, 1, 2, 3, 5, 6)],
+        "layers": [{"layer": i, "conv_silu": conv, "scan": scan} for i in (0, 1, 2, 3, 5, 6)],
     }
     tiles = by_kind["flash_tiles"]["calls"]
     assert [(c["head_dim"], c["value_dim"], c["group"]) for c in tiles] == [(24, 16, 1)] * 3

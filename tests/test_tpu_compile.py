@@ -5,10 +5,12 @@ Nothing executes, so these say nothing about results or speed.
 
 The shapes are those of `chip_smoke.py` (Llama-3.2-1B widths: 32 query / 8
 kv heads of 64, batch 4, seq 2048) plus head width 128, and the benchmark's
-three call shapes with the blocks the kernels choose for them, and the two
-fused ops of the Granite cell's Mamba mixer at its widths.
+three call shapes with the blocks the kernels choose for them, the two
+fused ops of the Granite cell's Mamba mixer at its widths, and the Ling
+cell's delta-rule scan.
 """
 
+import functools
 import os
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or libtpu logs under /tmp
@@ -200,6 +202,44 @@ def test_fused_mamba_kernels_compile_for_v5e(chip, mixer, op):
     columns = {"columns": (inner, conv)} if op == "conv_silu" else {"z_columns": (0, inner)}
     text = jax.jit(lambda *a: forward(*a, **columns)).lower(*args).compile().as_text()
     assert names[0] in text
+
+
+def test_kda_scan_kernels_compile_for_v5e(chip):
+    """Forward and gradient of the delta-rule scan at the Ling cell's shape
+    (1 x 16,384, 32 heads of 128, bf16 with float32 `g` and `beta`): the
+    chip's compiler takes the in-place blocks of one head's columns, the
+    state scratch, the inverse's substitution and block products, and the
+    backward's transposed products; the kernels keep the names
+    `kda_scan_roofline.train` finds them by, and nothing of the `jax.numpy`
+    form is left under the `kda` scope (its walks, the compiler's triangular
+    solver)."""
+    from polyaxon_tpu.ops import kda, kda_fused
+
+    rows, seq, heads, width = 1, 16384, 32, 128
+    assert kda_fused.scan_plan(rows, seq, 64, heads, width, width, jnp.bfloat16)["path"] == "pallas"
+
+    def sds(*shape, of=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, of, sharding=chip)
+
+    # as `models/kda.py` calls it: q, k, v, g with heads and widths merged
+    # (what the convs and `f_proj` hand over), q and k normalised by the kernels
+    wide = sds(rows, seq, heads * width)
+    args = (wide, wide, wide, sds(rows, seq, heads * width, of=jnp.float32),
+            sds(rows, seq, heads, of=jnp.float32))
+    scan = functools.partial(kda.kda_scan, chunk=64, unit_scales=(width**-0.5, 1.0))
+
+    def grads(*a):
+        return jax.grad(lambda *x: scan(*x).astype(jnp.float32).sum(), argnums=(0, 1, 2, 3, 4))(*a)
+
+    text = jax.jit(grads).lower(*args).compile().as_text()
+    assert "kda_scan_fwd" in text and "kda_scan_bwd" in text
+    assert "InvertDiagBlocksLowerTriangular" not in text
+    assert not [line for line in text.splitlines() if " while(" in line and "kda" in line]
+    # merged operands are read where they lie: no copy or re-layout of a wide array
+    assert not [line for line in text.splitlines()
+                if (" copy(" in line or " reshape(" in line) and "16384,4096" in line]
+    forward = jax.jit(scan).lower(*args).compile().as_text()
+    assert "kda_scan_fwd" in forward and "kda_scan_bwd" not in forward
 
 
 def _dense_decode(chip, batch, cache_len=8192, n_layers=1):
