@@ -14,6 +14,10 @@ in user containers behind Kubeflow CRDs). TPU-first design decisions:
 - `train.remat: true` recomputes as little as the device allows: the step
   is compiled on a short ladder of rungs (`_RematLadder`), most kept first,
   and the first that the device's compiler accepts runs.
+- Time to the first step is told by the program, from reads of the clock on
+  calls set-up makes anyway: nothing stands around the calls that trace,
+  lower and compile the step, and when its executable exists the Trainer
+  writes the record of the finished set-up (`Trainer._report_startup`).
 """
 
 from __future__ import annotations
@@ -43,7 +47,8 @@ from ..parallel.sharding import (
 )
 from ..retry import Preempted
 from ..schemas.run_kinds import V1Program
-from ..telemetry import MetricsRegistry, SpanTracer, compiles, now as _now
+from ..telemetry import MetricsRegistry, SpanTracer, compiles, get_registry, now as _now
+from ..telemetry import process_age
 from ..telemetry import mfu as _mfu_of
 from ..telemetry import required_train_step_flops
 from . import preemption
@@ -126,34 +131,72 @@ class _RematLadder:
     raised as it is, and when every rung is refused, the last refusal.
 
     Every process of a multi-host job compiles the same program for the same
-    kind of device, so all land on the same rung without a collective."""
+    kind of device, so all land on the same rung without a collective.
+
+    The choice is timed by reads of the clock alone (`began`, `clock`): no
+    span, frame or wait stands around `.lower()` and `.compile()`, which do
+    here what a plain `jax.jit`'s first call does."""
 
     def __init__(self, steps: dict, report: Callable[[dict], None]):
         self.steps = steps  # rung -> the `jax.jit` of its step
         self.rung: Optional[str] = None
         self.tried: list[dict] = []
+        self.began: Optional[float] = None  # `_now()` when the choice began
+        self.clock: dict = {}  # rung -> [`_now()` before `lower`, between, after `compile`]
         self._report = report
         self._compiled = None
 
     def attempt(self, rung: str, state, batch):
         """(what the compiler answered, the executable or its refusal):
-        `fits` with the compiler's bytes, or `refused` with its first line
-        and the seconds the refused compile cost."""
+        `fits` with the compiler's bytes, or `refused` with its first line.
+        Every answer has `lower_seconds` (the step traced to a jaxpr and
+        lowered to StableHLO: paid once a rung, cache or no cache),
+        `compile_seconds` (the device's compiler, or the load from the
+        persistent cache: `cache` says `hit`, `miss` or `off`) and `seconds`,
+        their sum: a refused rung's `seconds` is its lowering AND its
+        refused compile, not the compile alone.
+
+        This frame is live while the step is traced, and its size (locals
+        + stack, 15 words: `tests/test_runtime.py` holds it there) is part
+        of how long that takes: CPython keeps frames on a data stack of 16
+        KiB chunks, a frame that grows moves every frame under it, and a
+        hot call that then crosses a chunk's end maps and unmaps a chunk
+        each time. Five words more here read 0.8 s of 10.4 in InternLM2's
+        lowering (PERF.md section 6, PR 38), so two of the instants live in
+        `clock` and not in locals."""
         t0 = _now()
         lowered = self.steps[rung].lower(state, batch)
+        self.clock[rung] = [t0, _now()]
+        mark = compiles.own()
         try:
             compiled = lowered.compile()
         except jax.errors.JaxRuntimeError as e:
+            self.clock[rung].append(_now())
             if "RESOURCE_EXHAUSTED" not in str(e):
                 raise
-            return {
-                "rung": rung, "result": "refused",
-                "seconds": round(_now() - t0, 3),
-                "compiler": str(e).strip().splitlines()[0][:400],
-            }, e
-        return {"rung": rung, "result": "fits", "bytes": _step_bytes(compiled)}, compiled
+            return self._answer(rung, mark, e)
+        self.clock[rung].append(_now())
+        return self._answer(rung, mark, compiled)
+
+    def _answer(self, rung: str, mark: dict, outcome):
+        """(the answer with its share of the clock, `outcome`), once `compile`
+        has returned the executable or raised the refusal."""
+        t0, t1, t2 = self.clock[rung]
+        lower, compile_ = round(t1 - t0, 3), round(t2 - t1, 3)
+        if isinstance(outcome, Exception):
+            said = {"result": "refused", "compiler": str(outcome).strip().splitlines()[0][:400]}
+        else:
+            said = {"result": "fits", "bytes": _step_bytes(outcome)}
+        return {
+            "rung": rung, **said,
+            "seconds": round(lower + compile_, 3),
+            "lower_seconds": lower,
+            "compile_seconds": compile_,
+            "cache": compiles.cache_since(mark),
+        }, outcome
 
     def _choose(self, state, batch):
+        self.began = _now()
         for rung in self.steps:
             answer, outcome = self.attempt(rung, state, batch)
             self.tried.append(answer)
@@ -191,6 +234,14 @@ def _step_bytes(compiled) -> Optional[int]:
     )
 
 
+# the `startup` record's numbers that are `train.startup.*` gauges too, each
+# read by the benchmark's per-layer metric of its name (PERF.md section 3)
+_STARTUP_GAUGES = (
+    "before_trainer_seconds", "build_seconds", "step_lower_seconds",
+    "step_compile_seconds", "refused_compile_seconds",
+)
+
+
 class Trainer:
     """Drives one program on one mesh. Multi-host setup (jax.distributed)
     happens in the executor before this class is built."""
@@ -209,6 +260,11 @@ class Trainer:
         artifacts_dir: Optional[str] = None,
         registry: Optional[MetricsRegistry] = None,
     ):
+        # the process's age here (the interpreter, the imports, JAX's look
+        # for the chip, under `polyaxon run` the CLI and the executor), and
+        # the clock at the entry: `_report_startup` reads both
+        self._startup: dict = {"age": process_age(), "entered": _now()}
+        self._startup_log: dict = {}
         self.artifacts_dir = artifacts_dir
         self.event_fn = event_fn
         self.program = program
@@ -292,6 +348,10 @@ class Trainer:
         self.compute_dtype = _compute_dtype(tspec.precision)
         self.param_dtype = param_dtype_for(tspec.precision)
         self._build_step()
+        self._startup["built"] = _now()
+        if not isinstance(self.train_step, _RematLadder):
+            # a plain `jax.jit`: its lowering and compile are `xla.*_seconds`'
+            self._report_startup()
 
     def _validate_mesh_fit(self):
         """Friendly config errors instead of opaque XLA sharding failures:
@@ -405,10 +465,13 @@ class Trainer:
         self.p_shard = param_shardings(abstract_params, bundle.sharding_rules, mesh)
         e_shard = param_shardings(abstract_extra, bundle.sharding_rules, mesh)
         o_shard = _opt_state_shardings(self.tx, abstract_params, self.p_shard, mesh)
+        t_init = _now()
         params, extra = jax.jit(init_fn, out_shardings=(self.p_shard, e_shard))(
             init_rng
         )
         opt_state = jax.jit(self.tx.init, out_shardings=o_shard)(params)
+        # traced, compiled or loaded, and dispatched: not waited for
+        self._startup["init"] = (t_init, _now())
         rep = replicated(mesh)
         self.state = TrainState(
             # placed like every later step's: an array that is not on the
@@ -993,7 +1056,10 @@ class Trainer:
         """Which rung of the remat ladder runs, and what each rung tried
         cost: event `polyaxon.train.remat` (run store `remat`), gauges
         `train.remat.rung` (0 = `all`; -1 = every rung refused) and
-        `train.remat.refused_compiles`. A set-up that grew reads from here."""
+        `train.remat.refused_compiles`. A refused answer's `seconds`, and
+        `refused_seconds` over all of them, hold the refused rungs' lowering
+        AND refused compile (`_RematLadder.attempt`); the `startup` record
+        made next (`_report_startup`) is the one place that splits them."""
         import json
 
         refused = [t for t in choice["tried"] if t["result"] == "refused"]
@@ -1008,6 +1074,81 @@ class Trainer:
             refused_seconds=round(sum(t["seconds"] for t in refused), 3),
         )
         self._event("remat", choice)
+        self._report_startup(choice)
+
+    def _report_startup(self, choice: Optional[dict] = None):
+        """The record of a finished set-up, made once: when the ladder has
+        chosen (the step's executable exists), or for a plain `jax.jit`
+        when `__init__` returns, with `before_trainer`, `build` and `init`
+        alone (its lowering and compile are `xla.*_seconds`'). Every number
+        is a difference of clock reads taken on calls set-up makes anyway.
+
+        - gauges `train.startup.*_seconds` of the PROCESS-GLOBAL registry
+          (as `xla.*`: `/metricsz` serves them and they outlive the
+          Trainer): `before_trainer` the process's age at the entry of
+          `__init__` (not set off Linux), `build` the whole of `__init__`,
+          `step_lower` every rung tried traced and lowered, `step_compile`
+          the compile, or the load from the persistent cache, of the rung
+          that runs, `refused_compile` the compiles of the rungs refused
+          (0.0 where none was);
+        - run-store event `startup`, tracer event `polyaxon.train.startup`:
+          the same numbers under the gauges' names, `init_seconds` (the
+          host's time to trace, compile or load, and dispatch the two
+          programs that make the state: not waited for), `cache` and `rung`
+          of the step that runs, and `total_s`, the process's age now;
+        - the spans, written after the fact (`SpanTracer.record_span`; no
+          annotations): `build` > `init`, and `first_step` > one `rung` a
+          rung tried (`rung`, `result`, `bytes` or the compiler's line) >
+          `lower`, `compile` (`cache`);
+        - `startup_*` at the Trainer's first log point, once."""
+        import time
+
+        started = self._startup
+        wall = time.time() - _now()  # the metrics clock -> wall clock
+        entered, built, (init0, init1) = started["entered"], started["built"], started["init"]
+        record = {
+            "before_trainer_seconds": started["age"],
+            "build_seconds": round(built - entered, 3),
+            "init_seconds": round(init1 - init0, 3),
+        }
+        span = self.tracer.record_span
+        build = span("build", wall + entered, built - entered)
+        span("init", wall + init0, init1 - init0, build)
+        if choice is not None:
+            ladder, tried = self.train_step, choice["tried"]
+            runs = next((t for t in tried if t["result"] == "fits"), {})
+            refused = [t["compile_seconds"] for t in tried if t["result"] == "refused"]
+            record.update(
+                step_lower_seconds=round(sum(t["lower_seconds"] for t in tried), 3),
+                step_compile_seconds=runs.get("compile_seconds"),
+                refused_compile_seconds=round(sum(refused, 0.0), 3),
+                cache=runs.get("cache"),
+                rung=choice["rung"],
+            )
+            first = span(
+                "first_step", wall + ladder.began, _now() - ladder.began,
+                rung=choice["rung"] or "", rungs_tried=len(tried),
+            )
+            for t in tried:
+                t0, t1, t2 = ladder.clock[t["rung"]]
+                rung_id = span(
+                    "rung", wall + t0, t2 - t0, first,
+                    **{k: t[k] for k in ("rung", "result", "bytes", "compiler")
+                       if t.get(k) is not None},
+                )
+                span("lower", wall + t0, t1 - t0, rung_id)
+                span("compile", wall + t1, t2 - t1, rung_id, cache=t["cache"])
+        record["total_s"] = process_age()
+        record = {k: v for k, v in record.items() if v is not None}
+        gauges = get_registry()
+        for k in _STARTUP_GAUGES:
+            if k in record:
+                gauges.gauge(f"train.startup.{k}").set(record[k])
+        self.tracer.event("startup", **record)
+        self._event("startup", record)
+        self._startup_log = {
+            f"startup_{k}": float(v) for k, v in record.items() if isinstance(v, (int, float))
+        }
 
     def _report_layers(self):
         """What each layer of a decoder is (mixer: attention or mamba; kind,
@@ -1248,6 +1389,8 @@ class Trainer:
         if xla != self._xla_logged:
             self._xla_logged = xla
             vals.update((f"xla_{k}", float(v)) for k, v in xla.items())
+        vals.update(self._startup_log)  # the finished set-up, once
+        self._startup_log = {}
         self._hbm_gauges()
         history.append({"step": step, **vals})
         self.log_fn(step, vals)

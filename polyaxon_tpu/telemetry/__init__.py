@@ -63,6 +63,7 @@ from .registry import (
     MetricsRegistry,
     get_registry,
     now,
+    process_age,
 )
 from . import compiles
 from .slo import (
@@ -106,6 +107,7 @@ __all__ = [
     "get_tracer",
     "new_trace_id",
     "parse_prometheus_text",
+    "process_age",
     "queue_wait_delta_ms",
     "queryz_payload",
     "tracez_payload",

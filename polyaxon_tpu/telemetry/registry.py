@@ -16,6 +16,7 @@ O(buckets) no matter how many observations land.
 
 from __future__ import annotations
 
+import os
 import threading
 import time
 from typing import Optional, Sequence
@@ -32,6 +33,23 @@ def now() -> float:
     measurement goes through here so the no-raw-perf_counter lint can
     hold everywhere else."""
     return time.perf_counter()
+
+
+def process_age() -> Optional[float]:
+    """Seconds since the kernel started this process: `/proc/uptime` less
+    field 22 of `/proc/self/stat` (the start, in clock ticks since boot),
+    so in steps of 10 ms. What ran before Python's first line (the
+    interpreter's own start, an executor's `exec`) is in it. None where
+    `/proc` does not say (off Linux)."""
+    try:
+        with open("/proc/uptime") as f:
+            up = float(f.read().split()[0])
+        with open("/proc/self/stat") as f:
+            # the command's name (field 2) may hold spaces and brackets
+            started = int(f.read().rsplit(")", 1)[1].split()[19])
+        return round(up - started / os.sysconf("SC_CLK_TCK"), 2)
+    except (OSError, ValueError, IndexError):
+        return None
 
 
 class Counter:

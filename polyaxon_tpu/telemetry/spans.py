@@ -26,6 +26,15 @@ capture running an annotation costs one atomic load. This module never
 imports jax: a process that has not imported it cannot be profiling, and
 its spans skip the annotation.
 
+A span can also be written once it is over (`SpanTracer.record_span`: name,
+wall-clock start, duration, parent, attrs), for a region that no context
+manager may stand around: the Trainer reads the clock before and after the
+calls that trace and compile its step and writes `build` and `first_step`
+with their children from those instants when the executable exists. Such
+a span reaches the ring and `spans.jsonl` like any other and is NOT an
+annotation (one cannot be opened in the past): in a capture the
+`polyaxon.compile` marks of `compiles.py` lie at each compile's end.
+
 Export schema per line:
     {"kind": "span"|"event", "name": str, "span_id": int,
      "parent_id": int|null, "ts": float (unix), "dur_s": float,
@@ -165,6 +174,28 @@ class SpanTracer:
 
     def span(self, name: str, **attrs) -> _SpanHandle:
         return _SpanHandle(self, name, attrs)
+
+    def record_span(
+        self, name: str, ts: float, dur_s: float,
+        parent_id: Optional[int] = None, **attrs,
+    ) -> int:
+        """A span that is already over: the record `span()` writes at its
+        exit, from a wall-clock start and a duration read elsewhere. Returns
+        its id, for its children's `parent_id`. No annotation, and the
+        calling thread's open spans are left alone."""
+        span_id = next(self._ids)
+        self._record(
+            {
+                "kind": "span",
+                "name": name,
+                "span_id": span_id,
+                "parent_id": parent_id,
+                "ts": ts,
+                "dur_s": dur_s,
+                "attrs": attrs,
+            }
+        )
+        return span_id
 
     def event(self, name: str, **attrs) -> None:
         """Instant (zero-duration) record; a mark in a profiler capture."""

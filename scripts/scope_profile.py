@@ -19,7 +19,7 @@ of them; what carries no module's name (the grouped products' custom call,
 the fused head+loss, casts of the masters) is listed under `other`.
 
 Also prints what the Trainer reported while it built and chose its rung
-(`remat`, `model_ssm`, `model_kda`, `differentiated`), and the step's sown metrics.
+(`remat`, `startup`, `model_ssm`, `model_kda`, `differentiated`), and the step's sown metrics.
 Writes the table to `chiprun_out/scope_profile.<cell>.json`. Not a benchmark
 metric: a builder's reading for PERF.md section 5.
 """
@@ -80,7 +80,7 @@ def main() -> None:
     jax.block_until_ready(trainer.state)
     print(f"built, seeded and three steps in {time.time() - t0:.1f} s", flush=True)
     for kind, body in events:
-        if kind in ("remat", "model_ssm", "model_kda", "differentiated"):
+        if kind in ("remat", "startup", "model_ssm", "model_kda", "differentiated"):
             print(kind, json.dumps(body), flush=True)
     print("step metrics", {k: float(v) for k, v in jax.device_get(metrics).items()}, flush=True)
 

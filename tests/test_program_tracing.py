@@ -1,4 +1,6 @@
-"""ISSUE 27: the program names its own work on the device trace's clock.
+"""ISSUE 27: the program names its own work on the device trace's clock
+(and, ISSUE 38, tells its time to the first step: the finished-interval
+record, compiled or loaded, the process's age, the first log point).
 
   * a real profiler capture of a tiny Trainer and a tiny step-engine
     server: every span the program opens is a `polyaxon.*` event on the
@@ -26,6 +28,14 @@ pytestmark = pytest.mark.telemetry
 
 TRAIN_SPANS = ("step", "data_wait", "compute", "dispatch", "emit")
 STEP_SPANS = ("sched.intake", "step.prepare", "step.dispatch", "step.fetch", "step.emit")
+# written once they are over (ISSUE 38): in the ring and `spans.jsonl`, and
+# no annotations, since none can be opened in the past
+AFTER_THE_FACT = {"build", "init", "first_step", "rung", "lower", "compile"}
+
+
+def _opened(ring):
+    """The ring's spans that a `with tracer.span(...)` opened."""
+    return [r for r in ring if r["kind"] == "span" and r["name"] not in AFTER_THE_FACT]
 
 
 # ------------------------------------------------------------ real capture
@@ -83,9 +93,12 @@ def test_every_span_is_an_annotation_in_the_capture(capture):
         assert f"polyaxon.{what}" in names
     # one event a span: the ring and the capture count alike
     for ring, prefix in ((capture["train"], "polyaxon.train."), (capture["serve"], "polyaxon.")):
-        for what in {r["name"] for r in ring if r["kind"] == "span"}:
-            in_ring = sum(1 for r in ring if r["name"] == what and r["kind"] == "span")
+        for what in {r["name"] for r in _opened(ring)}:
+            in_ring = sum(1 for r in _opened(ring) if r["name"] == what)
             assert len(_events(capture["host"], prefix + what)) == in_ring, what
+    # the Trainer's record of its own set-up is in its ring all the same
+    assert {"build", "init"} <= {r["name"] for r in capture["train"] if r["kind"] == "span"}
+    assert not _events(capture["host"], "polyaxon.train.build")
 
 
 def test_children_lie_inside_parents_in_the_capture(capture):
@@ -130,7 +143,7 @@ def test_ring_and_capture_agree_on_durations(capture):
     host = capture["host"]
     pairs = []
     for ring, prefix in ((capture["train"], "polyaxon.train."), (capture["serve"], "polyaxon.")):
-        spans = [r for r in ring if r["kind"] == "span"]
+        spans = _opened(ring)
         for what in {r["name"] for r in spans}:
             mine = [r["dur_s"] for r in spans if r["name"] == what]  # in start order per name
             theirs = [(e - s) * 1e-9 for s, e in _events(host, prefix + what)]
@@ -364,6 +377,134 @@ def test_trainer_logs_xla_counts_where_they_moved():
     assert snap["xla.programs"] >= history[0]["xla_programs"]
 
 
+def test_trainer_logs_the_finished_set_up_once():
+    """The first log point carries `startup_*` beside `xla_*` (ISSUE 38); no
+    later one repeats them, and they are no `train.*` gauges."""
+    import jax
+
+    from polyaxon_tpu.runtime.trainer import Trainer
+
+    events = []
+    trainer = Trainer(
+        _mlp_program(steps=6, logEvery=2), mesh_axes={"data": 1},
+        devices=jax.devices()[:1], event_fn=lambda k, b: events.append((k, b)),
+    )
+    try:
+        history = trainer.run().history
+    finally:
+        trainer.close()
+    ((_, record),) = [e for e in events if e[0] == "startup"]
+    logged = {k: v for k, v in history[0].items() if k.startswith("startup_")}
+    assert logged == {
+        f"startup_{k}": float(v) for k, v in record.items() if isinstance(v, (int, float))
+    }
+    assert {"startup_build_seconds", "startup_init_seconds"} <= set(logged)
+    assert "xla_programs" in history[0]
+    assert not any(k.startswith("startup_") for h in history[1:] for k in h)
+    assert not any("startup_" in k for k in trainer.telemetry.snapshot())
+
+
+# ------------------------------------------------------- compiled or loaded
+def _cache_counts():
+    from polyaxon_tpu.telemetry import compiles
+
+    snap = compiles.snapshot()
+    return {k: snap[k] for k in (
+        "cache_hits", "cache_misses", "cache_retrieval_seconds", "compile_seconds_saved")}
+
+
+@pytest.mark.parametrize("how", ["backend", "sent"])
+def test_cache_hits_and_misses_reach_counters_events_and_answers(how, tmp_path):
+    """Compiled, or loaded from the persistent cache: `xla.cache_hits` /
+    `xla.cache_misses` and the two durations count JAX's own events, the
+    `polyaxon.compile` event of a program and the answer of a rung say
+    `hit`, `miss` or `off`. `backend`: a real compile cache directory, the
+    same step built again after `jax.clear_caches()` (where the CPU backend
+    does not cache, JAX's events are sent as in `sent`). `sent`: the events
+    alone, through `jax.monitoring`."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from polyaxon_tpu.runtime.trainer import _RematLadder
+    from polyaxon_tpu.telemetry import MetricsRegistry, compiles, get_registry
+
+    compiles.install()
+
+    def step_fn(state, batch):
+        return state + jnp.tanh(batch @ batch.T).sum(), {"loss": batch.mean()}
+
+    def build():
+        """The step lowered and compiled as a rung is; what its answer and
+        the program's `polyaxon.compile` event say of the cache."""
+        jax.clear_caches()
+        ladder = _RematLadder({"all": jax.jit(step_fn)}, lambda choice: None)
+        answer, _ = ladder.attempt("all", jnp.zeros(()), jnp.ones((8, 8)))
+        return answer["cache"], compiles.recent(1, mine=True)[0]
+
+    def send(hit):
+        record = jax.monitoring.record_event
+        record("/jax/compilation_cache/compile_requests_use_cache")
+        if hit:
+            record("/jax/compilation_cache/cache_hits")
+            jax.monitoring.record_event_duration_secs(
+                "/jax/compilation_cache/compile_time_saved_sec", 2.5)
+            jax.monitoring.record_event_duration_secs(
+                "/jax/compilation_cache/cache_retrieval_time_sec", 0.25)
+        else:
+            record("/jax/compilation_cache/cache_misses")
+
+    assert build()[0] == "off"  # no cache directory: asked or not, `off`
+    keep = {
+        k: getattr(jax.config, k) for k in (
+            "jax_compilation_cache_dir", "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")
+    }
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path / "cache"))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    compilation_cache.reset_cache()
+    try:
+        start, mine = _cache_counts(), compiles.own()
+        backend_caches = False
+        if how == "backend":
+            cold, cold_event = build()
+            after_cold = _cache_counts()
+            warm, warm_event = build()
+            backend_caches = _cache_counts()["cache_hits"] > after_cold["cache_hits"]
+        if not backend_caches:
+            send(hit=False)
+            cold = compiles.cache_since(mine)
+            after_cold, mine = _cache_counts(), compiles.own()
+            send(hit=True)
+            warm = compiles.cache_since(mine)
+        else:
+            assert cold_event["program"] == warm_event["program"] == "jit(step_fn)"
+            assert (cold_event["cache"], warm_event["cache"]) == ("miss", "hit")
+        end = _cache_counts()
+    finally:
+        for k, v in keep.items():
+            jax.config.update(k, v)
+        compilation_cache.reset_cache()
+    assert (cold, warm) == ("miss", "hit")
+    assert after_cold["cache_misses"] > start["cache_misses"]
+    assert after_cold["cache_hits"] == start["cache_hits"]
+    assert end["cache_hits"] > after_cold["cache_hits"]
+    assert end["cache_retrieval_seconds"] > after_cold["cache_retrieval_seconds"]
+    assert end["compile_seconds_saved"] >= after_cold["compile_seconds_saved"]
+    if how == "sent":
+        assert end["cache_hits"] - start["cache_hits"] == 1
+        assert end["compile_seconds_saved"] - start["compile_seconds_saved"] == pytest.approx(2.5)
+        assert compiles.own()["cache_retrieval_seconds"] - mine.get(
+            "cache_retrieval_seconds", 0) == pytest.approx(0.25)
+    # the process's registry holds the counters, a component's own mirrors them
+    snap = get_registry().snapshot()
+    assert all(snap[f"xla.{k}"] == pytest.approx(v, abs=1e-5) for k, v in end.items())
+    own = MetricsRegistry()
+    compiles.mirror(own)
+    assert {f"xla.{k}" for k in end} <= set(own.snapshot())
+
+
 # ------------------------------------------------------------- spans.jsonl
 def test_spans_jsonl_is_written_in_batches_and_whole_after_close(tmp_path):
     from polyaxon_tpu.telemetry.spans import _BATCH
@@ -399,6 +540,34 @@ def test_spans_jsonl_is_written_in_batches_and_whole_after_close(tmp_path):
     assert bad._broken and len(bad.recent()) == 5
 
 
+def test_a_finished_interval_is_the_record_a_span_writes(tmp_path):
+    """`record_span` writes what `span()` writes at its exit (same keys), to
+    the ring and to `spans.jsonl`, from a start and a duration read
+    elsewhere; it returns its id for its children and leaves the thread's
+    open spans alone."""
+    import time
+
+    path = tmp_path / "spans.jsonl"
+    tr = SpanTracer(path=str(path))
+    with tr.span("live", a=1):
+        pass
+    t0 = time.time() - 5.0
+    with tr.span("open") as still_open:
+        parent = tr.record_span("first_step", t0, 3.0, rung="all")
+        child = tr.record_span("lower", t0 + 0.5, 2.0, parent)
+    live, first, lower, opened = tr.recent(4)
+    assert set(first) == set(live) == set(lower)
+    assert first == {
+        "kind": "span", "name": "first_step", "span_id": parent, "parent_id": None,
+        "ts": t0, "dur_s": 3.0, "attrs": {"rung": "all"},
+    }
+    assert (lower["span_id"], lower["parent_id"], lower["attrs"]) == (child, parent, {})
+    assert opened["span_id"] == still_open.span_id and opened["parent_id"] is None
+    assert len({live["span_id"], parent, child, opened["span_id"]}) == 4
+    tr.close()
+    assert [json.loads(line) for line in path.read_text().splitlines()] == tr.recent(4)
+
+
 def test_telemetry_imports_without_jax():
     import subprocess
     import sys
@@ -413,6 +582,56 @@ def test_telemetry_imports_without_jax():
 
 
 # --------------------------------------------------------------------- mfu
+def test_process_age_and_a_threads_own_counts_without_jax():
+    """What the `startup` record reads needs no jax either: the process's
+    age from `/proc` (None off Linux), a thread's own counts, `off`."""
+    import subprocess
+    import sys
+
+    code = (
+        "import sys, time; import polyaxon_tpu.telemetry as t; "
+        "a = t.process_age(); time.sleep(0.05); b = t.process_age(); "
+        "assert a is None or 0 < a < b < 60, (a, b); "
+        "assert t.compiles.own() == {} and t.compiles.mine() == 0; "
+        "assert t.compiles.cache_since({}) == 'off' and t.compiles.snapshot() == {}; "
+        "assert 'jax' not in sys.modules; print('ok')"
+    )
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True, text=True)
+    assert out.stdout.strip() == "ok", out.stderr
+
+
+def test_process_age_grows_with_the_clock():
+    import os
+    import time
+
+    from polyaxon_tpu.telemetry import process_age
+
+    if not os.path.exists("/proc/self/stat"):
+        pytest.skip("no /proc: the process's age is not told")
+    a, t0 = process_age(), time.monotonic()
+    time.sleep(1.5)
+    b, waited = process_age(), time.monotonic() - t0
+    assert 0 < a < b
+    assert abs((b - a) - waited) < 1.0  # about the sleep; no tolerance under a second
+
+
+@pytest.mark.parametrize("broken", ["/proc/self/stat", "/proc/uptime"])
+def test_process_age_is_none_where_proc_does_not_say(broken, monkeypatch):
+    import builtins
+
+    from polyaxon_tpu.telemetry import process_age
+
+    real = builtins.open
+
+    def no_proc(path, *a, **k):
+        if path == broken:
+            raise FileNotFoundError(path)
+        return real(path, *a, **k)
+
+    monkeypatch.setattr(builtins, "open", no_proc)
+    assert process_age() is None
+
+
 @pytest.mark.parametrize("stacking", [{}, {"scan_layers": True}, {"pipeline_stages": 2}])
 def test_trainer_mfu_counts_required_work_as_the_benchmark_does(stacking):
     """At the training cell's `rehearse` size the Trainer's operations a

@@ -809,6 +809,258 @@ def test_remat_ladder_lets_any_other_error_through(error):
     assert steps["block"].lowerings == 0 and reports == []
 
 
+# --------------------------------------------------------------------------
+# Time to the first step, told by the program (PR 38): reads of the clock on
+# calls set-up makes anyway, and when the step's executable exists one
+# record of the finished set-up: five `train.startup.*` gauges of the
+# process-global registry, a `startup` event, and the spans `build` > `init`
+# and `first_step` > `rung` > `lower`, `compile`, written after the fact.
+
+
+@pytest.mark.parametrize("refused", [0, 1, 2, 3])
+def test_every_answer_of_a_ladder_splits_its_seconds(refused):
+    """`seconds` stays what it was, a rung's lowering and compile together,
+    on every answer now; `lower_seconds` and `compile_seconds` split it,
+    and the instants they come from are kept for the spans."""
+    from polyaxon_tpu.runtime.trainer import _RematLadder
+
+    rungs = ("all", "block", "apply")
+    steps = {
+        r: _StandInStep(r, _refusal(r) if i < refused else None)
+        for i, r in enumerate(rungs)
+    }
+    ladder = _RematLadder(steps, lambda choice: None)
+    if refused == len(rungs):
+        with pytest.raises(jax.errors.JaxRuntimeError):
+            ladder("state", "batch")
+    else:
+        ladder("state", "batch")
+    assert len(ladder.tried) == min(refused + 1, len(rungs))
+    assert list(ladder.clock) == [t["rung"] for t in ladder.tried]
+    at = ladder.began
+    for t in ladder.tried:
+        assert t["seconds"] == pytest.approx(t["lower_seconds"] + t["compile_seconds"], abs=1e-9)
+        assert t["lower_seconds"] >= 0 and t["compile_seconds"] >= 0
+        assert t["cache"] == "off"  # a stand-in asks no cache for anything
+        t0, t1, t2 = ladder.clock[t["rung"]]
+        assert at <= t0 <= t1 <= t2  # one rung after another
+        assert t["lower_seconds"] == round(t1 - t0, 3)
+        assert t["compile_seconds"] == round(t2 - t1, 3)
+        at = t2
+
+
+def test_the_ladders_live_frames_keep_their_size():
+    """The frames that are live while the step is traced are as large as at
+    1cb1803 (locals + stack, in words). CPython keeps frames on a data stack
+    of 16 KiB chunks: a live frame that grows moves every frame under it,
+    and on the chip's host five words more in `attempt` read 0.8 s of 10.4
+    in InternLM2's lowering (PERF.md section 6, PR 38). A PR that changes a
+    number here pairs `.lower()` on the chip before it hands in."""
+    import sys
+
+    from polyaxon_tpu.runtime.trainer import _RematLadder
+
+    if sys.version_info[:2] != (3, 12):
+        pytest.skip("the compiler's stack depths are CPython 3.12's")
+    sizes = {
+        name: (code.co_nlocals + len(code.co_cellvars) + len(code.co_freevars)
+               + code.co_stacksize)
+        for name in ("attempt", "_choose", "__call__")
+        for code in [getattr(_RematLadder, name).__code__]
+    }
+    assert sizes == {"attempt": 15, "_choose": 12, "__call__": 7}
+
+
+class _RefusingStep:
+    """A rung's own `jax.jit`, really traced, lowered and compiled, and then
+    refused as the device's compiler refuses (the CPU's never does)."""
+
+    def __init__(self, step, rung):
+        self.step, self.rung, self.lowered = step, rung, None
+
+    def lower(self, state, batch):
+        self.lowered = self.step.lower(state, batch)
+        return self
+
+    def compile(self):
+        self.lowered.compile()
+        raise _refusal(self.rung)
+
+
+def _startup_trainer(kind, events):
+    """A program without a ladder; a ladder whose first rung fits; one whose
+    first rung is refused by the stand-in, or after a real compile."""
+    if kind == "no-ladder":
+        return Trainer(
+            make_program(steps=2, logEvery=1), mesh_axes={"data": 1},
+            devices=jax.devices()[:1], event_fn=lambda k, b: events.append((k, b)),
+        )
+    t = _lora_trainer(remat=True, events=events)
+    if kind == "first-refused":
+        t.train_step.steps["all"] = _StandInStep("all", _refusal("all"))
+    elif kind == "first-compiled-and-refused":
+        t.train_step.steps["all"] = _RefusingStep(t.train_step.steps["all"], "all")
+    return t
+
+
+def _startup_gauges():
+    from polyaxon_tpu.telemetry import get_registry
+
+    return {
+        k.removeprefix("train.startup.").removesuffix("_seconds"): v
+        for k, v in get_registry().snapshot().items() if k.startswith("train.startup.")
+    }
+
+
+def _spans(trainer):
+    return [r for r in trainer.tracer.recent(512) if r["kind"] == "span"]
+
+
+def _holds(parent, child):
+    return (
+        child["parent_id"] == parent["span_id"]
+        and parent["ts"] <= child["ts"] + 1e-6
+        and child["ts"] + child["dur_s"] <= parent["ts"] + parent["dur_s"] + 1e-6
+    )
+
+
+@pytest.mark.parametrize("kind", ["first-fits", "first-refused"])
+def test_startup_record_of_a_ladder(kind):
+    """Nothing is reported before the step's executable exists; then the
+    five gauges, one `startup` event and the spans, all from the same reads
+    of the clock; a later step or `lower()` leaves them alone."""
+    from polyaxon_tpu.telemetry import process_age
+
+    events = []
+    t = _startup_trainer(kind, events)
+    assert not [e for e in events if e[0] == "startup"]
+    assert not [r for r in _spans(t) if r["name"] in ("build", "first_step")]
+    batches = _batches(t, 3)
+    _run_steps(t, batches[:1])
+
+    ((_, record),) = [e for e in events if e[0] == "startup"]
+    remat = next(b for k, b in events if k == "remat")
+    tried = remat["tried"]
+    refused = [a for a in tried if a["result"] == "refused"]
+    assert len(refused) == (kind == "first-refused")
+    assert record["rung"] == t.train_step.rung == ("block" if refused else "all")
+    assert record["cache"] == "off"  # the CPU backend keeps no persistent cache
+    assert record["step_lower_seconds"] == pytest.approx(
+        sum(a["lower_seconds"] for a in tried), abs=1e-9)
+    assert record["step_compile_seconds"] == tried[-1]["compile_seconds"]
+    # 0.0 where none was refused: the PR that stops paying it reads 43 -> 0
+    assert record["refused_compile_seconds"] == (
+        refused[0]["compile_seconds"] if refused else 0.0)
+    assert isinstance(record["refused_compile_seconds"], float)
+    assert 0 < record["init_seconds"] <= record["build_seconds"]
+    gauges = _startup_gauges()
+    for name in ("build", "step_lower", "step_compile", "refused_compile"):
+        assert gauges[name] == record[f"{name}_seconds"], name
+    assert "init" not in gauges  # a builder's reading: no gauge, no reader
+    if process_age() is not None:  # Linux
+        assert 0 < record["before_trainer_seconds"] == gauges["before_trainer"]
+        assert record["total_s"] >= record["before_trainer_seconds"]  # the age now
+    (mark,) = [r for r in t.tracer.recent(512) if r["name"] == "startup"]
+    assert mark["kind"] == "event" and mark["attrs"] == record
+
+    spans = _spans(t)
+    (build,), (init,), (first,) = (
+        [r for r in spans if r["name"] == n] for n in ("build", "init", "first_step"))
+    assert build["parent_id"] is None and first["parent_id"] is None
+    assert _holds(build, init)
+    assert round(build["dur_s"], 3) == record["build_seconds"]
+    assert round(init["dur_s"], 3) == record["init_seconds"]
+    assert build["ts"] + build["dur_s"] <= first["ts"] + 1e-6
+    assert first["attrs"] == {"rung": record["rung"], "rungs_tried": len(tried)}
+    rungs = [r for r in spans if r["name"] == "rung"]
+    assert [r["attrs"]["rung"] for r in rungs] == [a["rung"] for a in tried]
+    for rung, answer in zip(rungs, tried):
+        assert _holds(first, rung)
+        assert rung["attrs"]["result"] == answer["result"]
+        assert ("compiler" in rung["attrs"]) == (answer["result"] == "refused")
+        lower, compile_ = (
+            next(r for r in spans if r["parent_id"] == rung["span_id"] and r["name"] == n)
+            for n in ("lower", "compile")
+        )
+        assert _holds(rung, lower) and _holds(rung, compile_)
+        assert lower["ts"] + lower["dur_s"] <= compile_["ts"] + 1e-6
+        assert round(lower["dur_s"], 3) == answer["lower_seconds"]
+        assert round(compile_["dur_s"], 3) == answer["compile_seconds"]
+        assert compile_["attrs"] == {"cache": answer["cache"]}
+
+    _run_steps(t, batches[1:])
+    t.train_step.lower(t.state, batches[0])
+    assert len([e for e in events if e[0] == "startup"]) == 1
+    assert _spans(t) == spans
+    assert _startup_gauges() == gauges
+    t.close()
+
+
+def test_startup_record_without_a_ladder():
+    """`remat: false` keeps its plain `jax.jit`: `before_trainer` and `build`
+    when `__init__` returns, lowering and compile left to `xla.*_seconds`."""
+    from polyaxon_tpu.runtime.trainer import _RematLadder
+    from polyaxon_tpu.telemetry import process_age
+
+    before = _startup_gauges()
+    events = []
+    t = _startup_trainer("no-ladder", events)
+    assert not isinstance(t.train_step, _RematLadder)
+    assert type(t.train_step).__module__.startswith("jax")  # no wrapper before the jit
+    ((_, record),) = [e for e in events if e[0] == "startup"]
+    want = {"build_seconds", "init_seconds"}
+    if process_age() is not None:
+        want |= {"before_trainer_seconds", "total_s"}
+    assert set(record) == want
+    gauges = _startup_gauges()
+    assert gauges["build"] == record["build_seconds"]
+    assert gauges.get("before_trainer") == record.get("before_trainer_seconds")
+    for name in ("step_lower", "step_compile", "refused_compile"):
+        assert gauges.get(name) == before.get(name), name  # not this Trainer's to set
+    spans = _spans(t)
+    assert [r["name"] for r in spans] == ["build", "init"] and _holds(*spans)
+    _run_steps(t, _batches(t, 2))
+    assert len([e for e in events if e[0] == "startup"]) == 1
+    assert [r["name"] for r in _spans(t) if r["name"] in ("build", "init", "first_step")] == [
+        "build", "init"]
+    assert _startup_gauges() == gauges
+    t.close()
+
+
+@pytest.mark.parametrize(
+    "kind,built", [("first-fits", 1), ("first-compiled-and-refused", 2)]
+)
+def test_first_step_builds_the_step_once_a_rung(kind, built):
+    """A guard on counts: with a listener of the test's own on JAX's three
+    duration events, a Trainer's first step traces, lowers and compiles
+    `step_fn` once where rung `all` fits and twice where it is refused (no
+    second `.trace()`, `.lower()` or `eval_shape` of the step for a timing's
+    sake), and later steps build nothing."""
+    import jax.monitoring
+
+    seen, listening = [], [True]
+
+    def listener(event, duration, **kw):
+        if listening[0] and "step_fn" in str(kw.get("fun_name")):
+            seen.append(event.rsplit("/", 1)[1])
+
+    jax.monitoring.register_event_duration_secs_listener(listener)
+    try:
+        t = _startup_trainer(kind, [])
+        batches = _batches(t, 3)
+        assert seen == []  # `__init__` builds the wrappers only
+        _run_steps(t, batches[:1])
+        assert sorted(seen) == sorted(
+            ["jaxpr_trace_duration", "jaxpr_to_mlir_module_duration",
+             "backend_compile_duration"] * built
+        )
+        _run_steps(t, batches[1:])
+        assert len(seen) == 3 * built
+        t.close()
+    finally:
+        listening[0] = False  # a listener cannot be taken off again
+
+
 def test_trainer_reports_the_rung_that_runs():
     import json
 
