@@ -6,8 +6,9 @@ in user containers behind Kubeflow CRDs). TPU-first design decisions:
 - ONE jit-compiled `train_step` (params donated, static shapes) — the Python
   loop only feeds batches and reads metrics on log steps, so steps between
   logs run back-to-back on device with no host sync.
-- Mixed precision the TPU way: params in f32, compute in bf16 (MXU-native);
-  no loss scaling — bf16 keeps f32's exponent range.
+- Mixed precision the TPU way: trained params in f32, compute in bf16
+  (MXU-native); a LoRA step's frozen half in the compute type, since no
+  step writes it; no loss scaling — bf16 keeps f32's exponent range.
 - Sharding via NamedShardings from model-declared logical rules
   (parallel/sharding.py); init runs under jit with `out_shardings`, so params
   materialize directly on their devices — no host-side full copy.
@@ -91,7 +92,26 @@ def param_dtype_for(precision: str):
     return jnp.bfloat16 if precision == "bfloat16" else jnp.float32
 
 
-def make_param_init(bundle, param_dtype, example):
+def param_labels(bundle, params):
+    """`train` for a leaf the optimizer updates, `freeze` for one it never
+    does: every leaf trains unless the bundle names `trainable_patterns`
+    (LoRA), and then only the leaves whose path one of them matches."""
+    if not bundle.trainable_patterns:
+        return jax.tree.map(lambda _: "train", params)
+    import re as _re
+
+    from ..parallel.sharding import _path_str
+
+    pats = tuple(_re.compile(p) for p in bundle.trainable_patterns)
+    return jax.tree_util.tree_map_with_path(
+        lambda path, _: "train"
+        if any(p.search(_path_str(path)) for p in pats)
+        else "freeze",
+        params,
+    )
+
+
+def make_param_init(bundle, param_dtype, example, frozen_dtype=None):
     """The init-and-cast recipe for a bundle's params + mutable collections.
 
     Shared between training setup (_build_step) and the serving restore
@@ -99,17 +119,35 @@ def make_param_init(bundle, param_dtype, example):
     from the stored spec to partial-restore a checkpoint, and the two code
     paths must produce identical trees or the restore breaks — one
     function, no drift. Params do not depend on the example's batch dim,
-    so any batch size works for shape inference."""
+    so any batch size works for shape inference.
 
+    `frozen_dtype` is the type the step reads its params in: a leaf the
+    optimizer never updates (`freeze` in `param_labels`) is stored in it,
+    cast in this same program, so the step casts it never. None stores
+    every float leaf in `param_dtype`, as serving does: its tree has the
+    trainer's paths and shapes, and the restore casts a checkpoint's
+    frozen leaf up to it, so a served model computes in its own type."""
+
+    def stored(params):
+        if param_dtype != jnp.float32:
+            params = _cast_floats(params, param_dtype)
+        if frozen_dtype is not None and frozen_dtype != param_dtype:
+            params = jax.tree.map(
+                lambda label, x: x if label == "train" else _cast_floats(x, frozen_dtype),
+                param_labels(bundle, params), params,
+            )
+        return params
+
+    # `init_fn`'s frame is live while the model's init traces: kept at the
+    # size it had before `stored` (a live frame that grows moves every
+    # frame under it, and tracing under it slows; PERF.md section 6)
     def init_fn(rng):
         variables = bundle.module.init(
             {"params": rng, **{k: rng for k in bundle.rngs}},
             example,
             train=False,
         )
-        params = variables["params"]
-        if param_dtype != jnp.float32:
-            params = _cast_floats(params, param_dtype)
+        params = stored(variables["params"])
         extra = {k: variables[k] for k in tuple(bundle.mutable)}
         return params, extra
 
@@ -437,25 +475,16 @@ class Trainer:
         init_rng = jax.random.PRNGKey(int(tspec.seed))
 
         mutable = tuple(bundle.mutable)
-        init_fn = make_param_init(bundle, self.param_dtype, example)
+        init_fn = make_param_init(
+            bundle, self.param_dtype, example, self.compute_dtype
+        )
         abstract_params, abstract_extra = jax.eval_shape(init_fn, init_rng)
         self._train_labels = None  # all parameters train
-        labels = jax.tree.map(lambda _: "train", abstract_params)
+        labels = param_labels(bundle, abstract_params)
         if bundle.trainable_patterns:
             # LoRA-style fine-tune: non-matching params get zero updates.
             # multi_transform (not optax.masked — masked passes raw grads
             # through as updates for the frozen side).
-            import re as _re
-
-            from ..parallel.sharding import _path_str
-
-            pats = tuple(_re.compile(p) for p in bundle.trainable_patterns)
-            labels = jax.tree_util.tree_map_with_path(
-                lambda path, _: "train"
-                if any(p.search(_path_str(path)) for p in pats)
-                else "freeze",
-                abstract_params,
-            )
             self.tx = optax.multi_transform(
                 {"train": self.tx, "freeze": optax.set_to_zero()}, labels
             )
@@ -1039,17 +1068,46 @@ class Trainer:
 
     def _report_differentiated(self, abstract_params, labels):
         """How many parameters the step takes a gradient of, and how many
-        enter it as values only: an event and two gauges, at build."""
-        sizes = [int(x.size) for x in jax.tree.leaves(abstract_params)]
-        trainable = sum(
-            n for n, label in zip(sizes, jax.tree.leaves(labels)) if label == "train"
+        enter it as values only, the type the frozen half is stored in, and
+        how many of its bytes are stored in the compute type where that is
+        not the masters' (a LoRA step under `precision: mixed`; 0 else): an
+        event and three gauges, at build."""
+        leaves = jax.tree.leaves(abstract_params)
+        frozen_leaves = [
+            x for x, label in zip(leaves, jax.tree.leaves(labels)) if label != "train"
+        ]
+        frozen = sum(int(x.size) for x in frozen_leaves)
+        trainable = sum(int(x.size) for x in leaves) - frozen
+        frozen_type = next(
+            (
+                jnp.dtype(x.dtype).name
+                for x in frozen_leaves
+                if jnp.issubdtype(x.dtype, jnp.floating)
+            ),
+            None,
         )
-        frozen = sum(sizes) - trainable
+        in_compute_type = (
+            sum(
+                int(x.size) * jnp.dtype(x.dtype).itemsize
+                for x in frozen_leaves
+                if x.dtype == self.compute_dtype
+            )
+            if self.compute_dtype != self.param_dtype
+            else 0
+        )
         self.telemetry.gauge("train.params_differentiated").set(trainable)
         self.telemetry.gauge("train.params_frozen").set(frozen)
+        self.telemetry.gauge("train.params_frozen_compute_type_bytes").set(
+            in_compute_type
+        )
         self._event(
             "differentiated",
-            {"trainable_params": trainable, "frozen_params": frozen},
+            {
+                "trainable_params": trainable,
+                "frozen_params": frozen,
+                "frozen_dtype": frozen_type,
+                "frozen_compute_type_bytes": in_compute_type,
+            },
         )
 
     def _report_remat(self, choice: dict):

@@ -6,6 +6,7 @@ Multi-device behavior runs on the virtual 8-device CPU mesh from conftest
 import json
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -656,6 +657,8 @@ def test_lora_grad_norm_is_the_adapters_norm():
     assert ("differentiated", {
         "trainable_params": n_train,
         "frozen_params": sum(sizes.values()) - n_train,
+        "frozen_dtype": "float32",
+        "frozen_compute_type_bytes": 0,
     }) in events
     snap = t.telemetry.snapshot()
     assert snap["train.params_differentiated"] == n_train
@@ -688,7 +691,10 @@ def test_full_training_differentiates_every_leaf():
     for path, m in moments.items():
         assert np.abs(m).max() > 0, path
     n = sum(x.size for x in start.values())
-    assert ("differentiated", {"trainable_params": n, "frozen_params": 0}) in events
+    assert ("differentiated", {
+        "trainable_params": n, "frozen_params": 0, "frozen_dtype": None,
+        "frozen_compute_type_bytes": 0,
+    }) in events
 
 
 def test_lora_grad_accum_matches_the_doubled_batch():
@@ -715,6 +721,235 @@ def test_lora_grad_accum_matches_the_doubled_batch():
     # the accumulated gradient holds adapters only: no buffer of a frozen
     # kernel's shape is carried through the microbatch loop
     assert not _product_shapes(halves, batches[0]) & {(64, 64), (64, 96), (96, 64)}
+
+
+# A leaf the optimizer never updates is stored in the type the step reads it
+# in: under `precision: mixed` a LoRA step's frozen half is bf16, cast once
+# in the init program, where the step cast f32 masters on every call before.
+
+
+def _converted_arguments(trainer, batch):
+    """Shapes of the step's arguments that the lowered step converts to
+    another float type, as (shape, from, to)."""
+    import re
+
+    text = trainer.train_step.lower(trainer.state, batch).as_text()
+    return {
+        (tuple(int(d) for d in dims.split("x")[:-1]), src, dst)
+        for dims, src, dst in re.findall(
+            r"stablehlo.convert %arg\d+ : \(tensor<((?:\d+x)+)(\w+)>\) -> tensor<[\dx]+(\w+)>",
+            text,
+        )
+    }
+
+
+def _masters_in_f32(trainer):
+    """The params as the init recipe made them before the frozen half was
+    stored in the compute type: every float leaf in the masters' type."""
+    from polyaxon_tpu.runtime.trainer import make_param_init
+
+    bundle = trainer.bundle
+    init_fn = make_param_init(
+        bundle, trainer.param_dtype, bundle.example_inputs(trainer.data.batch_size)
+    )
+    params, _ = jax.jit(init_fn)(jax.random.PRNGKey(int(trainer.tspec.seed)))
+    return params
+
+
+def _stores_its_frozen_half_in_the_type_it_reads(tmp_path, lora, precision, frozen_type):
+    events = []
+    t = _lora_trainer(lora=lora, precision=precision, events=events)
+    stored = _leaves_by_path(t.state.params)
+    before = _leaves_by_path(_masters_in_f32(t))
+    assert list(stored) == list(before)
+    moved = 0
+    for path, x in stored.items():
+        if lora and precision == "mixed" and not _is_adapter(path):
+            assert x.dtype == np.dtype(jnp.bfloat16), path
+            assert before[path].dtype == np.float32, path
+            # the same values the step read before: the masters' cast
+            np.testing.assert_array_equal(
+                x, before[path].astype(jnp.bfloat16), err_msg=path
+            )
+            moved += x.nbytes
+        else:  # adapters, a full fine-tune, float32 or bfloat16: as before
+            assert x.dtype == before[path].dtype, path
+            np.testing.assert_array_equal(x, before[path], err_msg=path)
+    assert (moved > 0) == (lora and precision == "mixed")
+    (body,) = [b for kind, b in events if kind == "differentiated"]
+    assert body["frozen_dtype"] == frozen_type
+    assert body["frozen_compute_type_bytes"] == moved
+    assert t.telemetry.snapshot()["train.params_frozen_compute_type_bytes"] == moved
+    # the lowered step converts no frozen kernel: what is stored is what it reads
+    converted = _converted_arguments(t, _batches(t, 1)[0])
+    kernels = {x.shape for p, x in stored.items() if x.ndim == 2 and not _is_adapter(p)}
+    frozen_converted = {c for c in converted if c[0] in kernels}
+    if precision == "mixed" and not lora:
+        assert frozen_converted  # the control: trained masters are cast
+    else:
+        assert not frozen_converted, sorted(frozen_converted)
+
+
+def _reads_the_operands_f32_masters_gave(tmp_path, remat):
+    """One step on the stored bf16 frozen half against the same step on the
+    f32 masters the init made before, cast inside the step: the same loss,
+    gradient norm, first moments and adapters, bit for bit."""
+    t = _lora_trainer(precision="mixed", remat=remat)
+    (batch,) = _batches(t, 1)
+    step = t.train_step.steps["all"] if remat else t.train_step
+    copy = lambda tree: jax.tree.map(lambda x: x.copy(), tree)  # noqa: E731
+    masters = _masters_in_f32(t)
+    assert {x.dtype for x in jax.tree.leaves(masters)} == {np.dtype(np.float32)}
+    cast_in_step = copy(t.state).replace(params=masters)
+    now = copy(t.state)
+    cast_in_step, m_cast = step(cast_in_step, batch)
+    now, m_now = step(now, batch)
+    for key in ("loss", "grad_norm"):
+        np.testing.assert_array_equal(np.asarray(m_now[key]), np.asarray(m_cast[key]))
+    for tree in ("params", "opt_state"):
+        want = _leaves_by_path(getattr(cast_in_step, tree))
+        got = _leaves_by_path(getattr(now, tree))
+        assert list(got) == list(want)
+        for path in want:
+            if tree == "opt_state" or _is_adapter(path):
+                np.testing.assert_array_equal(got[path], want[path], err_msg=path)
+
+
+_LORA_RUN = {
+    "version": 1.1,
+    "kind": "operation",
+    "name": "lora-mixed",
+    "component": {
+        "kind": "component",
+        "name": "lora-mixed",
+        "run": {
+            "kind": "jaxjob",
+            "program": {
+                "model": {
+                    "name": "transformer_lm",
+                    "config": {
+                        "dim": 64, "n_layers": 2, "n_heads": 4, "n_kv_heads": 2,
+                        "hidden_dim": 96, "vocab_size": 160, "seq_len": 24,
+                        "lora": {"rank": 4, "alpha": 8,
+                                 "targets": ["q_proj", "k_proj", "v_proj", "o_proj"]},
+                    },
+                },
+                "data": {
+                    "name": "synthetic_text", "batchSize": 2,
+                    "config": {"seq_len": 24, "vocab_size": 160},
+                },
+                "optimizer": {"name": "adamw", "learningRate": 0.01},
+                "train": {"steps": 2, "logEvery": 1, "precision": "mixed",
+                          "checkpointEvery": 2, "seed": 0},
+            },
+        },
+    },
+}
+
+
+def _checkpoint_resumes_and_serves(tmp_path, saved):
+    """A mixed LoRA run's checkpoint, as written before its frozen half was
+    stored in bf16 (f32 masters) or as written now, resumes into the bf16
+    frozen half, and is served in the masters' type, as before: serving
+    reads the checkpoint into f32 params and computes in f32."""
+    import yaml
+
+    from polyaxon_tpu.models.generate import make_paged_cache
+    from polyaxon_tpu.models.kv_pages import PagedKVLayout
+    from polyaxon_tpu.parallel.sharding import _path_str
+    from polyaxon_tpu.runtime.checkpoint import close_all, save_checkpoint
+    from polyaxon_tpu.serving import ModelServer
+
+    p = tmp_path / "lora.yaml"
+    p.write_text(yaml.safe_dump(_LORA_RUN))
+    store = RunStore()
+    compiled = compile_operation(read_polyaxonfile(str(p)))
+    assert Executor(store, devices=jax.devices()[:1]).execute(compiled) == "succeeded"
+    close_all()
+    ckdir = str((store.outputs_dir(compiled.run_uuid) / "checkpoints").resolve())
+    program = V1Program.model_validate(_LORA_RUN["component"]["run"]["program"])
+    resumed = Trainer(
+        program.model_copy(update={"train": program.train.model_copy(
+            update={"steps": 4, "resume": True})}),
+        devices=jax.devices()[:1], checkpoint_dir=ckdir,
+    )
+    assert resumed.restore() == 2
+    step = 2
+    if saved == "f32-frozen":
+        # the same run's state with the f32 masters the init made before
+        masters = _leaves_by_path(_masters_in_f32(resumed))
+        flat, treedef = jax.tree_util.tree_flatten_with_path(resumed.state.params)
+        old = jax.tree_util.tree_unflatten(treedef, [
+            x if _is_adapter(_path_str(path)) else jnp.asarray(masters[_path_str(path)])
+            for path, x in flat
+        ])
+        step = 3
+        save_checkpoint(ckdir, step, resumed.state.replace(params=old), wait=True)
+        close_all()
+        assert {x.dtype for x in jax.tree.leaves(old)} == {np.dtype(np.float32)}
+    written = _leaves_by_path(
+        old if saved == "f32-frozen" else resumed.state.params
+    )
+
+    def holds(params, frozen_type):
+        """`params` hold what was written, each frozen leaf in `frozen_type`
+        (a cast that loses nothing: the values are bf16's or f32's own)."""
+        got = _leaves_by_path(params)
+        assert list(got) == list(written)
+        for path, x in written.items():
+            want = x if _is_adapter(path) else x.astype(frozen_type)
+            assert got[path].dtype == want.dtype, path
+            np.testing.assert_array_equal(got[path], want, err_msg=path)
+
+    again = Trainer(resumed.program, devices=jax.devices()[:1], checkpoint_dir=ckdir)
+    assert again.restore() == step
+    holds(again.state.params, jnp.bfloat16)
+    (metrics,) = _run_steps(again, _batches(again, 1))
+    assert np.isfinite(metrics["loss"])
+    close_all()
+    server = ModelServer.from_run(compiled.run_uuid, store=store)
+    assert server.step == step
+    # the trainer's paths and shapes; every float leaf in the masters' type
+    served = _leaves_by_path(server.params)
+    assert {p: x.shape for p, x in served.items()} == {
+        p: x.shape for p, x in _leaves_by_path(again.state.params).items()
+    }
+    holds(server.params, jnp.float32)
+    pool = make_paged_cache(
+        server.module, server.params, PagedKVLayout(page_tokens=8, pool_pages=4)
+    )
+    assert {x.dtype for x in jax.tree.leaves(pool)} == {np.dtype(np.float32)}
+    out = server.generate({"tokens": [[1, 2, 3]], "maxNewTokens": 2})
+    assert len(out["tokens"][0]) == 5
+
+
+@pytest.mark.parametrize(
+    "check, kw",
+    [
+        pytest.param(_stores_its_frozen_half_in_the_type_it_reads,
+                     dict(lora=True, precision="mixed", frozen_type="bfloat16"),
+                     id="store-lora-mixed"),
+        pytest.param(_stores_its_frozen_half_in_the_type_it_reads,
+                     dict(lora=False, precision="mixed", frozen_type=None),
+                     id="store-full-mixed"),
+        pytest.param(_stores_its_frozen_half_in_the_type_it_reads,
+                     dict(lora=True, precision="float32", frozen_type="float32"),
+                     id="store-lora-float32"),
+        pytest.param(_stores_its_frozen_half_in_the_type_it_reads,
+                     dict(lora=True, precision="bfloat16", frozen_type="bfloat16"),
+                     id="store-lora-bfloat16"),
+        pytest.param(_reads_the_operands_f32_masters_gave, dict(remat=False),
+                     id="step-plain"),
+        pytest.param(_reads_the_operands_f32_masters_gave, dict(remat=True),
+                     id="step-remat"),
+        pytest.param(_checkpoint_resumes_and_serves, dict(saved="f32-frozen"),
+                     id="checkpoint-f32-frozen"),
+        pytest.param(_checkpoint_resumes_and_serves, dict(saved="compute-type"),
+                     id="checkpoint-compute-type"),
+    ],
+)
+def test_a_lora_steps_frozen_half_in_the_type_it_reads(check, kw, tmp_home, tmp_path):
+    check(tmp_path, **kw)
 
 
 # --------------------------------------------------------------------------
