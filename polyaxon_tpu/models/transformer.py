@@ -36,6 +36,10 @@ model is in-repo and TPU-shaped:
   the flash kernels take the two widths); a router that scores by a sigmoid,
   chooses with a frozen bias and within the best groups (models/moe.py).
   They train; neither has a decode path.
+- A fifth mixer by `layer_types`: `power_retention`, degree-2 power
+  retention (models/retention.py, ops/power_retention.py): gated linear
+  attention whose scores are the square of q . k, read out normalised. It
+  trains; it has no decode path.
 - Optional LoRA (`lora_rank > 0`): frozen base kernels + trainable A/B
   adapters on all projections; the trainer masks the optimizer to adapter
   params via `ModelBundle.trainable_patterns`.
@@ -76,17 +80,17 @@ class RopeSpec:
 @dataclasses.dataclass(frozen=True)
 class LayerSpec:
     """What one layer is, where layers differ: its mixer (`attention`,
-    `mamba`, `kda` or `mla`), and for attention its query heads, its window
-    (0 = causal attention over the whole sequence) and its rotary table (a
-    `rotary_factor` of 0 rotates nothing); `kda` and `mla` read their heads
-    (and `mla` its rotary base) from here too; whether its MLP is routed or
-    dense."""
+    `mamba`, `kda`, `mla` or `power_retention`), and for attention its query
+    heads, its window (0 = causal attention over the whole sequence) and its
+    rotary table (a `rotary_factor` of 0 rotates nothing); `kda`, `mla` and
+    `power_retention` read their heads (and `mla` and `power_retention` their
+    rotary table) from here too; whether its MLP is routed or dense."""
 
     n_heads: int
     window: int = 0
     rope: RopeSpec = RopeSpec()
     routed: bool = False
-    mixer: str = "attention"  # attention | mamba | kda | mla
+    mixer: str = "attention"  # attention | mamba | kda | mla | power_retention
 
     def describe(self, cfg: "TransformerConfig") -> dict:
         mlp = {
@@ -108,6 +112,25 @@ class LayerSpec:
                 "conv": cfg.kda_conv,
                 "chunk": cfg.kda_chunk_size,
                 "gate_bound": cfg.kda_gate_bound,
+                **mlp,
+            }
+        if self.mixer == "power_retention":
+            from ..ops.power_retention import DEGREE, EPS
+            from .retention import GATE_BIAS
+
+            p = cfg.head_size
+            return {
+                "mixer": "power_retention",
+                "heads": self.n_heads,
+                "kv_heads": cfg.n_kv_heads,
+                "head_width": p,
+                "degree": DEGREE,
+                "feature_width": p * (p + 1) // 2,  # the symmetric square's
+                "qk_norm": True,  # Qwen3's q_norm and k_norm, always
+                "gate_width": self.n_heads,  # one log-gate a query head
+                "gate_bias": GATE_BIAS,
+                "eps": EPS,
+                "rope_theta": self.rope.theta,
                 **mlp,
             }
         if self.mixer == "mla":
@@ -230,6 +253,10 @@ class TransformerConfig:
     mla_rope_dim: int = 64
     mla_value_dim: int = 128
     mla_qk_norm: bool = False
+    # the chunk of the power-retention scan of the layers whose entry is
+    # "power_retention" (models/retention.py, ops/power_retention.py): a
+    # choice of the scan's, the readout is the same at every chunk
+    retention_chunk_size: int = 256
     # MoE (models/moe.py): the router's width (the PUBLISHED count of
     # experts); 0 = dense MLPs. This process holds experts
     # [expert_offset, expert_offset + experts_held) of each routed layer
@@ -891,6 +918,12 @@ class Block(nn.Module):
             h = KimiDeltaAttention(cfg, spec.n_heads, name="kda")(
                 normed, decode=self.decode, adapter_ix=adapter_ix
             )
+        elif spec.mixer == "power_retention":
+            from .retention import PowerRetention
+
+            h = PowerRetention(cfg, spec, name="retention")(
+                normed, decode=self.decode, adapter_ix=adapter_ix
+            )
         elif spec.mixer == "mla":
             from .mla import LatentAttention
 
@@ -1281,7 +1314,7 @@ _LAYER_KEYS = (
     "rope_parameters", "mlp_only_layers", "position_embedding_type",
 )
 _ATTENTION_KINDS = ("full_attention", "sliding_attention", "attention")
-_OTHER_MIXERS = ("mamba", "kda", "mla")  # a `layer_types` entry that names its mixer
+_OTHER_MIXERS = ("mamba", "kda", "mla", "power_retention")  # an entry that names its mixer
 
 
 def _layer_specs(pub: dict, base: dict) -> tuple:
@@ -1375,7 +1408,8 @@ def _make_config(config: dict) -> TransformerConfig:
         raise ValueError(
             "scan_layers and pipeline_stages stack one block's parameters "
             "along a layer axis and cannot hold layers that differ (heads, "
-            "window, rope, attention, Mamba, KDA or MLA mixer, dense/routed MLP by layer: "
+            "window, rope, attention, Mamba, KDA, MLA or power-retention mixer, "
+            "dense/routed MLP by layer: "
             "layer_types, num_attention_heads_per_layer, rope_parameters, "
             "mlp_only_layers, position_embedding_type)"
         )
@@ -1425,6 +1459,7 @@ _STEP_STATS = {  # collection -> how each sown name is reduced over the layers
                   "overflow": jnp.sum},
     "ssm_stats": {"dt_max": jnp.max, "chunk_decay_min": jnp.min},
     "kda_stats": {"log_decay_min": jnp.min, "beta_max": jnp.max},
+    "retention_stats": {"log_gate_min": jnp.min, "denominator_min": jnp.min},
 }
 
 
@@ -1437,7 +1472,10 @@ def step_metrics(sown: dict) -> dict:
     size and the most negative in-chunk running sum of `dt A` (the worst
     layer's: how far the in-chunk decays underflow). KDA layers (`kda_stats`
     -> `kda.*`): the most negative in-chunk running sum of the log-decay and
-    the largest step size, the worst layer's."""
+    the largest step size, the worst layer's. Power-retention layers
+    (`retention_stats` -> `retention.*`): the most negative in-chunk running
+    sum of the log-gate and the smallest denominator of the readout, the
+    worst layer's."""
     from flax.traverse_util import flatten_dict
 
     out = {}
@@ -1498,6 +1536,7 @@ def build_transformer(config: dict) -> ModelBundle:
             ("moe_stats", cfg.n_experts > 0),
             ("ssm_stats", any(spec.mixer == "mamba" for spec in cfg.layers)),
             ("kda_stats", any(spec.mixer == "kda" for spec in cfg.layers)),
+            ("retention_stats", any(spec.mixer == "power_retention" for spec in cfg.layers)),
         ) if has
     )
     fused = None

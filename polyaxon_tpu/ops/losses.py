@@ -78,10 +78,16 @@ def accuracy(logits, batch) -> jnp.ndarray:
 
 
 # ---------------------------------------------------------- fused lm head
+# Bytes of float32 logits, positions x vocabulary, above which the fused loss
+# walks its positions in blocks: the compiler keeps every vocab chunk's logits
+# of one call alive at once (see `fused_linear_masked_lm`)
+LOGITS_BUDGET_BYTES = 2 << 30
+
+
 def fused_linear_masked_lm(features, kernel, labels, *, chunk_size=8192):
     """Masked LM cross-entropy computed straight from pre-head FEATURES —
     the lm-head matmul and the softmax are fused over vocab chunks so the
-    [B, S, V] logit tensor never materializes.
+    [B, S, V] logit tensor never materializes as one array.
 
     Why: at llama vocab sizes the logits dominate activation memory
     (b8 x s1024 x v128k f32 = 4 GB forward + the same again for the
@@ -89,8 +95,15 @@ def fused_linear_masked_lm(features, kernel, labels, *, chunk_size=8192):
     loss only needs one scalar per token. Chunking runs the head as
     n_chunks MXU matmuls of [N, D] @ [D, C] with an online logsumexp
     (same recurrence as flash attention's softmax), and the custom VJP
-    recomputes each chunk's logits instead of saving them. Peak extra
-    memory is one [N, C] block instead of [N, V].
+    recomputes each chunk's logits instead of saving them.
+
+    Memory: the chunks are a Python loop, so the compiler sees them all at
+    once and may keep every chunk's [N, C] logits alive together, [N, V]
+    float32 in all, whatever `chunk_size` is (32,768 positions over 151,936
+    rows: 19.9 GB). Where N x V x 4 bytes passes `LOGITS_BUDGET_BYTES`, the
+    positions are walked in blocks, one block at a time under a checkpoint
+    (`lax.map`), the block halved until its logits fit; below it the call
+    is one block, as it always was.
 
     Sharding note: intended for meshes where the vocab dim is NOT sharded
     (single chip, DP/FSDP). Under tensor parallelism the regular path's
@@ -111,7 +124,22 @@ def fused_linear_masked_lm(features, kernel, labels, *, chunk_size=8192):
     V = kernel.shape[1]
     x = features.reshape(B * S, D)
     flat = labels.reshape(B * S)
-    return _fused_lm(x, kernel, flat, int(chunk_size), V)
+    block = B * S
+    while block * V * 4 > LOGITS_BUDGET_BYTES and block % 2 == 0:
+        block //= 2
+    if block == B * S:
+        return _fused_lm(x, kernel, flat, int(chunk_size), V)
+
+    @jax.checkpoint
+    def one(args):
+        xb, lb = args
+        count = jnp.sum(lb != -100).astype(jnp.float32)
+        return _fused_lm(xb, kernel, lb, int(chunk_size), V) * jnp.maximum(count, 1.0), count
+
+    sums, counts = jax.lax.map(
+        one, (x.reshape(-1, block, D), flat.reshape(-1, block))
+    )
+    return jnp.sum(sums) / jnp.maximum(jnp.sum(counts), 1.0)
 
 
 def _chunks(V, chunk_size):

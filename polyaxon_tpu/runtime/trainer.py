@@ -1221,7 +1221,11 @@ class Trainer:
         model with KDA layers adds `polyaxon.model.kda` (run store
         `model_kda`): the delta-rule scan's chunk, sub-block, chunk count,
         heads walked at a time and largest intermediate, and per KDA layer
-        the path of its three short convolutions."""
+        the path of its three short convolutions. A model with
+        power-retention layers adds `polyaxon.model.retention` (run store
+        `model_retention`): the scan's chunk, chunks and run, query heads a
+        walk step, one layer's carried state and the largest intermediate in
+        bytes, the feature map's form and width, and the path."""
         cfg = getattr(self.bundle.module, "cfg", None)
         if cfg is None or not hasattr(cfg, "layer"):
             return
@@ -1289,6 +1293,30 @@ class Trainer:
             }
             get_tracer().event("model.kda", **{**kda, "layers": json.dumps(kda["layers"])})
             self._event("model_kda", kda)
+        held = [i for i, layer in enumerate(layers) if layer["mixer"] == "power_retention"]
+        if held:
+            from ..ops import power_retention as pr
+
+            heads, p, chunk = cfg.layer(held[0]).n_heads, cfg.head_size, cfg.retention_chunk_size
+            chunks = -(-seq // chunk)
+            retention = {
+                "rows": rows, "seq_len": seq, "chunk": chunk, "chunks": chunks,
+                "run": pr.run_length(chunks),
+                "heads_per_step": heads // cfg.n_kv_heads,  # a key-value group's
+                "state_bytes_per_layer": rows * pr.state_bytes(heads, p),
+                "largest_intermediate_bytes": pr.largest_intermediate_bytes(
+                    chunk, heads // cfg.n_kv_heads, p, jnp.dtype(self.compute_dtype).itemsize
+                ),
+                # the symmetric square in blocks of `phi_block` channels
+                "phi": "symmetric", "phi_block": pr.FEATURE_BLOCK,
+                "feature_width": pr.feature_width(p),
+                "path": "xla",
+                "layers": held,
+            }
+            get_tracer().event(
+                "model.retention", **{**retention, "layers": json.dumps(held)}
+            )
+            self._event("model_retention", retention)
         self._report_flash_tiles(cfg)
 
     def _report_flash_tiles(self, cfg):

@@ -322,6 +322,38 @@ def test_fused_linear_masked_lm_matches_reference():
         np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-6, err_msg=n)
 
 
+def test_fused_linear_masked_lm_in_blocks_matches_one_call(monkeypatch):
+    """Where positions x vocabulary pass the logits' budget, the positions
+    are walked in blocks: the same mean and gradients as one call, with a
+    block whose positions are all masked."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from polyaxon_tpu.ops import losses
+
+    rng = jax.random.PRNGKey(3)
+    B, S, D, V = 2, 8, 16, 50
+    f = jax.random.normal(rng, (B, S, D), jnp.float32)
+    k = jax.random.normal(jax.random.fold_in(rng, 1), (D, V)) * 0.1
+    labels = jax.random.randint(jax.random.fold_in(rng, 2), (B, S), 0, V)
+    labels = labels.at[0, :5].set(-100)
+
+    def loss(f, k):
+        return losses.fused_linear_masked_lm(f, k, labels, chunk_size=16)
+
+    whole = jax.value_and_grad(loss, argnums=(0, 1))(f, k)
+    # 4 positions' logits fit: 16 positions walked in 4 blocks, the first
+    # of them masked whole
+    monkeypatch.setattr(losses, "LOGITS_BUDGET_BYTES", 4 * V * 4)
+    jaxpr = str(jax.make_jaxpr(loss)(f, k))
+    blocked = jax.value_and_grad(loss, argnums=(0, 1))(f, k)
+    assert "scan" in jaxpr and "length=4" in jaxpr
+    np.testing.assert_allclose(blocked[0], whole[0], rtol=1e-6)
+    for a, b, n in zip(blocked[1], whole[1], ("dfeatures", "dkernel")):
+        np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-6, err_msg=n)
+
+
 @pytest.mark.slow
 def test_fused_lm_loss_tied_embeddings_matches_regular():
     """fused_lm_loss with tie_embeddings: kernel = embedding.T — same
