@@ -7,6 +7,11 @@ G key-value groups of width P; query head h reads group h // (H / G):
     y_t = sum_{j<=t} exp(sum_{j<l<=t} log g_l) (q_t . k_j / sqrt(P))^2 v_j / (the same sum without v_j + EPS)
     out = o_proj y                                  (ops/power_retention.py)
 
+The shape decides the scan (`ops/power_retention_fused.scan_plan`): the
+Pallas kernels read q, k and v merged, `[B, S, heads x P]` as the
+projections and the norms hand them over; every other shape runs the
+`jax.numpy` scan on `[B, S, heads, P]`.
+
 The norm of a head's queries and keys (Qwen3's `q_norm` and `k_norm`) and
 the rotation at the layer's `rope_theta` come before the square, as one
 checkpointed module (`mla._NormedRotated`: the backward keeps the
@@ -35,6 +40,8 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.power_retention import retention_scan
+from ..ops.power_retention_fused import scan as fused_scan
+from ..ops.power_retention_fused import scan_plan
 
 GATE_BIAS = 7.0  # added to the gate's logit: sigmoid(7) = 1 - 9.1e-4
 
@@ -61,7 +68,7 @@ class PowerRetention(nn.Module):
         bsz, seq, _ = u.shape
         q = _run_proj(cfg, heads * p, "q_proj", u, adapter_ix).reshape(bsz, seq, heads, p)
         k = _run_proj(cfg, groups * p, "k_proj", u, adapter_ix).reshape(bsz, seq, groups, p)
-        v = _run_proj(cfg, groups * p, "v_proj", u, adapter_ix).reshape(bsz, seq, groups, p)
+        v = _run_proj(cfg, groups * p, "v_proj", u, adapter_ix)
         cos_np, sin_np = rope_table_for(cfg.seq_len, p, self.spec.rope)
         cos = jnp.asarray(cos_np)[None, :seq, None, :]
         sin = jnp.asarray(sin_np)[None, :seq, None, :]
@@ -71,7 +78,14 @@ class PowerRetention(nn.Module):
             nn.Dense(heads, use_bias=False, name="gate_proj")(u).astype(jnp.float32) + GATE_BIAS
         )
         chunk = cfg.retention_chunk_size
-        y, denominator_min = retention_scan(q, k, v, log_g, chunk=chunk)
+        plan = scan_plan(bsz, seq, chunk, heads, groups, p, q.dtype)
+        if plan["path"] == "pallas":  # q, k, v merged, as the kernels read them
+            y, denominator_min = fused_scan(
+                q.reshape(bsz, seq, heads * p), k.reshape(bsz, seq, groups * p), v, log_g,
+                chunk=chunk, run=plan["run"])
+        else:
+            y, denominator_min = retention_scan(
+                q, k, v.reshape(bsz, seq, groups, p), log_g, chunk=chunk)
         # the log-gate is <= 0: a chunk's most negative running sum is its whole sum
         whole = jnp.pad(log_g, [(0, 0), (0, -seq % chunk), (0, 0)])
         whole = whole.reshape(bsz, -1, chunk, heads).sum(axis=2)

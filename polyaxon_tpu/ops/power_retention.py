@@ -1,5 +1,9 @@
 """Degree-2 power retention (Manifest AI's *Symmetric Power Transformers*,
-2024) in its chunked form, in `jax.numpy`, differentiable by autodiff.
+2024) in its chunked form, in `jax.numpy`, differentiable by autodiff. Where
+`power_retention_fused.scan_plan` takes the shape (heads of 128, a chunk of
+whole lanes, whole runs, float32 or bf16, no live mesh) `models/retention.py`
+runs the Pallas kernels of `power_retention_fused.py` in its place: the same
+algebra and numbers, checked against `retention_scan` below.
 
 Per query head, with `s_tj = (scale * q_t . k_j)^2`, a log-gate `log g_t <= 0`
 (one scalar a head and position) and the decay from `j` to `t` the product

@@ -1225,7 +1225,10 @@ class Trainer:
         power-retention layers adds `polyaxon.model.retention` (run store
         `model_retention`): the scan's chunk, chunks and run, query heads a
         walk step, one layer's carried state and the largest intermediate in
-        bytes, the feature map's form and width, and the path."""
+        bytes (of the `xla` path), the feature map's form and width, and per
+        retention layer the scan's path (`ops/power_retention_fused.py`:
+        `pallas` with its run, heads a grid step, saved states' bytes and
+        VMEM limit, or `xla` and why)."""
         cfg = getattr(self.bundle.module, "cfg", None)
         if cfg is None or not hasattr(cfg, "layer"):
             return
@@ -1296,11 +1299,14 @@ class Trainer:
         held = [i for i, layer in enumerate(layers) if layer["mixer"] == "power_retention"]
         if held:
             from ..ops import power_retention as pr
+            from ..ops.power_retention_fused import scan_plan as retention_plan
 
             heads, p, chunk = cfg.layer(held[0]).n_heads, cfg.head_size, cfg.retention_chunk_size
             chunks = -(-seq // chunk)
+            scan = retention_plan(rows, seq, chunk, heads, cfg.n_kv_heads, p, self.compute_dtype)
             retention = {
                 "rows": rows, "seq_len": seq, "chunk": chunk, "chunks": chunks,
+                # the `xla` path's walk; the kernels' own are each layer's `scan`
                 "run": pr.run_length(chunks),
                 "heads_per_step": heads // cfg.n_kv_heads,  # a key-value group's
                 "state_bytes_per_layer": rows * pr.state_bytes(heads, p),
@@ -1310,11 +1316,10 @@ class Trainer:
                 # the symmetric square in blocks of `phi_block` channels
                 "phi": "symmetric", "phi_block": pr.FEATURE_BLOCK,
                 "feature_width": pr.feature_width(p),
-                "path": "xla",
-                "layers": held,
+                "layers": [{"layer": i, "scan": scan} for i in held],
             }
             get_tracer().event(
-                "model.retention", **{**retention, "layers": json.dumps(held)}
+                "model.retention", **{**retention, "layers": json.dumps(retention["layers"])}
             )
             self._event("model_retention", retention)
         self._report_flash_tiles(cfg)

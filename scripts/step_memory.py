@@ -51,10 +51,10 @@ def main() -> None:
     from polyaxon_tpu.ops import flash_attention
 
     flash_attention._interpret = lambda: False  # lower the kernels for Mosaic
-    for kernels in ("mamba_fused", "kda_fused"):
+    for kernels in ("mamba_fused", "kda_fused", "power_retention_fused"):
         try:  # wraps its kernels in `jax.jit` as it is imported: before the stand-in below
             importlib.import_module(f"polyaxon_tpu.ops.{kernels}")
-        except ImportError:  # a `--repo` from before PR 34 or PR 36
+        except ImportError:  # a `--repo` older than the module
             pass
     from cellbench.drivers import train as driver
     from polyaxon_tpu.runtime.trainer import Trainer

@@ -190,8 +190,10 @@ def test_trainer_reports_the_layers_the_scan_and_its_gauges():
         "rows": 1, "seq_len": 64, "chunk": 16, "chunks": 4, "run": 2, "heads_per_step": 2,
         "state_bytes_per_layer": 4 * (768 * 32 + 32 * 32) * 4,
         "largest_intermediate_bytes": 16 * 2 * 768 * 4,  # float32 activations
-        "phi": "symmetric", "phi_block": 16, "feature_width": 768, "path": "xla",
-        "layers": [0, 1, 2, 3],
+        "phi": "symmetric", "phi_block": 16, "feature_width": 768,
+        # a head of 32 runs the `jax.numpy` scan: the kernels take 128
+        "layers": [{"layer": i, "scan": {"path": "xla", "why": "width 32 is no multiple of 128"}}
+                   for i in range(4)],
     }
     assert "model.layers" in marks and "model.retention" in marks
 

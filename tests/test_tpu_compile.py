@@ -6,8 +6,8 @@ Nothing executes, so these say nothing about results or speed.
 The shapes are those of `chip_smoke.py` (Llama-3.2-1B widths: 32 query / 8
 kv heads of 64, batch 4, seq 2048) plus head width 128, and the benchmark's
 three call shapes with the blocks the kernels choose for them, the two
-fused ops of the Granite cell's Mamba mixer at its widths, and the Ling
-cell's delta-rule scan.
+fused ops of the Granite cell's Mamba mixer at its widths, the Ling
+cell's delta-rule scan, and the Brumby cell's power-retention scan.
 """
 
 import functools
@@ -240,6 +240,38 @@ def test_kda_scan_kernels_compile_for_v5e(chip):
                 if (" copy(" in line or " reshape(" in line) and "16384,4096" in line]
     forward = jax.jit(scan).lower(*args).compile().as_text()
     assert "kda_scan_fwd" in forward and "kda_scan_bwd" not in forward
+
+
+def test_retention_scan_kernels_compile_for_v5e(chip):
+    """Forward and gradient of the power-retention scan at the Brumby cell's
+    shape (1 x 32,768, 40 query heads over 8 groups of 128, chunk 256, bf16
+    with a float32 log-gate): the chip's compiler takes the merged blocks,
+    the state scratch and the backward's scratch of a run's states in VMEM;
+    the kernels keep the names `retention_scan_roofline.train` finds them
+    by, and no wide operand is copied at their border."""
+    from polyaxon_tpu.ops import power_retention_fused as prf
+
+    rows, seq, heads, groups, width = 1, 32768, 40, 8, 128
+    plan = prf.scan_plan(rows, seq, 256, heads, groups, width, jnp.bfloat16)
+    assert plan["path"] == "pallas" and plan["run"] == 8
+
+    def sds(*shape, of=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, of, sharding=chip)
+
+    # as `models/retention.py` calls it: q, k, v with heads and widths merged
+    args = (sds(rows, seq, heads * width), sds(rows, seq, groups * width),
+            sds(rows, seq, groups * width), sds(rows, seq, heads, of=jnp.float32))
+    scan = functools.partial(prf.scan, chunk=256, run=plan["run"])
+
+    def grads(*a):
+        return jax.grad(lambda *x: scan(*x)[0].astype(jnp.float32).sum(), argnums=(0, 1, 2, 3))(*a)
+
+    text = jax.jit(grads).lower(*args).compile().as_text()
+    assert "retention_scan_fwd" in text and "retention_scan_bwd" in text
+    assert not [line for line in text.splitlines()
+                if " copy(" in line and ("32768,5120" in line or "32768,1024" in line)]
+    forward = jax.jit(scan).lower(*args).compile().as_text()
+    assert "retention_scan_fwd" in forward and "retention_scan_bwd" not in forward
 
 
 def _dense_decode(chip, batch, cache_len=8192, n_layers=1):
